@@ -61,6 +61,8 @@ def main() -> None:
     if args.summary_only:
         write_summary(args.label)
         sys.exit(0)
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     chosen = [s.strip() for s in args.only.split(",") if s.strip()] or SUITES
     print("name,us_per_call,derived")
     failures = 0

@@ -2,10 +2,10 @@
 
 On mobile the paper swaps activations between GPU and CPU memory; the TPU
 analogue is HBM ↔ host offload.  JAX exposes this through sharding memory
-kinds ("device" vs "pinned_host"); on the CPU-only container the transfer
-is *modeled* — the Swapper tracks bytes moved and charges them at the
-host-link bandwidth so the middleware optimizer sees honest costs either
-way.
+kinds ("device" vs "pinned_host"), which both the TPU and the CPU
+backend provide.  With ``use_memory_kinds`` off the transfer is only
+*modeled*; either way the Swapper tracks bytes moved and charges them at
+the host-link bandwidth so the middleware optimizer sees honest costs.
 """
 from __future__ import annotations
 
@@ -19,6 +19,15 @@ import jax.numpy as jnp
 HOST_LINK_BW = 32e9   # bytes/s PCIe-class host link (v5e host DMA)
 
 
+def _to_memory(x: jax.Array, kind: str) -> jax.Array:
+    """Move ``x`` to memory ``kind`` of its own device.  A failed transfer
+    raises: keeping the array where it was would report an offload that
+    never happened."""
+    dev = x.devices().pop()
+    return jax.device_put(x, jax.sharding.SingleDeviceSharding(
+        dev, memory_kind=kind))
+
+
 @dataclass
 class SwapRecord:
     name: str
@@ -28,21 +37,16 @@ class SwapRecord:
 
 @dataclass
 class Swapper:
-    """Tracks (and when supported, performs) HBM<->host transfers."""
-    use_memory_kinds: bool = False      # real host offload (TPU runtime)
+    """Tracks (and with ``use_memory_kinds``, performs) HBM<->host
+    transfers."""
+    use_memory_kinds: bool = False      # real host offload
     records: List[SwapRecord] = field(default_factory=list)
     resident_host: Dict[str, Any] = field(default_factory=dict)
 
     def offload(self, name: str, x: jax.Array) -> jax.Array:
         self.records.append(SwapRecord(name, x.size * x.dtype.itemsize, "out"))
         if self.use_memory_kinds:
-            try:
-                dev = x.devices().pop()
-                host = jax.sharding.SingleDeviceSharding(
-                    dev, memory_kind="pinned_host")
-                x = jax.device_put(x, host)
-            except Exception:
-                pass  # backend without pinned_host: keep on device
+            x = _to_memory(x, "pinned_host")
         self.resident_host[name] = x
         return x
 
@@ -50,13 +54,7 @@ class Swapper:
         x = self.resident_host.pop(name)
         self.records.append(SwapRecord(name, x.size * x.dtype.itemsize, "in"))
         if self.use_memory_kinds:
-            try:
-                dev = x.devices().pop()
-                dsh = jax.sharding.SingleDeviceSharding(dev,
-                                                        memory_kind="device")
-                x = jax.device_put(x, dsh)
-            except Exception:
-                pass
+            x = _to_memory(x, "device")
         return x
 
     def total_bytes(self) -> int:

@@ -1,8 +1,9 @@
-"""Jit'd wrappers around the Pallas kernels with automatic CPU fallback.
+"""Jit'd wrappers around the Pallas kernels, each beside its ``ref.py``
+oracle, so the same model code runs everywhere.
 
-The engine flips ``RuntimeOptions.use_pallas``; every op here dispatches to
-the Pallas kernel on TPU (or in interpret mode when forced) and to the
-``ref.py`` oracle otherwise, so the same model code runs everywhere.
+``paged_attention`` — the only op on the served path — picks the kernel
+by the platform it is lowered for.  The others still take ``use_pallas``
+(kernel on TPU, or in interpret mode when forced; oracle otherwise).
 """
 from __future__ import annotations
 
@@ -73,28 +74,31 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
                               kv_len=kv_len)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "use_pallas",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_attention(q: jax.Array, k_blocks: jax.Array, v_blocks: jax.Array,
                     tables: jax.Array, pos: jax.Array, k_new: jax.Array,
                     v_new: jax.Array, k_scale: jax.Array | None = None,
                     v_scale: jax.Array | None = None, window: int = 0,
-                    use_pallas: bool = False,
                     interpret: bool = False) -> jax.Array:
     """Single-query decode attention straight off a BlockPool table.
 
     q: (slots, H, hd); k/v_blocks: (num_blocks, bs, kvh, hd);
     tables: (slots, mb) int32 runtime data; pos: (slots,) resident tokens;
     k/v_new: (slots, kvh, hd) current-token KV (not yet scattered);
-    k/v_scale: optional (num_blocks, bs) per-row int8 scales."""
-    if use_pallas and (_on_tpu() or interpret):
-        return paged_decode_attention(
-            q, k_blocks, v_blocks, tables, pos, k_new, v_new,
-            k_scale=k_scale, v_scale=v_scale, window=window,
-            interpret=not _on_tpu())
-    return ref.paged_decode_attn_ref(
-        q, k_blocks, v_blocks, tables, pos, k_new, v_new,
-        k_scale=k_scale, v_scale=v_scale, window=window)
+    k/v_scale: optional (num_blocks, bs) per-row int8 scales.
+
+    Chosen by the platform the program is lowered for, with no flag that
+    could pick the oracle on a chip: a TPU program gets the Pallas
+    kernel, any other gets the ``ref.py`` oracle.  ``interpret=True``
+    (tests only) runs the kernel in the Pallas interpreter anywhere."""
+    args = (q, k_blocks, v_blocks, tables, pos, k_new, v_new)
+    kw = dict(k_scale=k_scale, v_scale=v_scale, window=window)
+    if interpret:
+        return paged_decode_attention(*args, interpret=True, **kw)
+    return jax.lax.platform_dependent(
+        *args,
+        tpu=lambda *a: paged_decode_attention(*a, **kw),
+        default=lambda *a: ref.paged_decode_attn_ref(*a, **kw))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "use_pallas",

@@ -22,9 +22,10 @@ as an always-valid extra key, which keeps the append-then-attend ordering
 out of the kernel entirely.
 
 int8 KV: when per-row scales are passed, blocks are stored int8 and
-dequantized inside the block loop (one f32 multiply per row) — the pool
-holds ~4x more resident slots for one extra VMEM operand of ``bs``
-floats per block.
+dequantized inside the block loop — a row's scale factors out of its dot
+products, so K's scales multiply the ``(.., bs)`` scores and V's the
+probabilities, both lane-aligned — the pool holds ~4x more resident
+slots for one extra VMEM operand of ``bs`` floats per block.
 """
 from __future__ import annotations
 
@@ -61,14 +62,15 @@ def _paged_decode_kernel(*args, has_scales: bool, kvh: int, group: int,
     qg = (q_ref[0].astype(jnp.float32) * scale).reshape(kvh, group, -1)
     k = k_ref[0].astype(jnp.float32)                 # (bs, kvh, hd)
     v = v_ref[0].astype(jnp.float32)
-    if has_scales:
-        k = k * ks_ref[0][:, None, None]
-        v = v * vs_ref[0][:, None, None]
 
     # scores (kvh, group, bs); pool col c is valid iff c < pos (and inside
     # the sliding window when one is set — the new token is position pos)
     s = jnp.einsum("kgh,ckh->kgc", qg, k,
                    preferred_element_type=jnp.float32)
+    if has_scales:
+        # a row scale factors out of its dot product: scale the (1, bs)
+        # lane vector into the scores instead of relayouting it onto K
+        s = s * ks_ref[0][None]
     pos = pos_ref[s_id]
     cols = j * block_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, 1, block_size), 2)
@@ -85,8 +87,9 @@ def _paged_decode_kernel(*args, has_scales: bool, kvh: int, group: int,
                   jnp.exp(s - m_new[..., None]))
     corr = jnp.exp(m_prev - m_new)                   # 0 when m_prev==NEG_INF
     l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
+    pv = p * vs_ref[0][None] if has_scales else p    # V's row scales likewise
     acc_scr[...] = (acc_scr[...] * corr[..., None]
-                    + jnp.einsum("kgc,ckh->kgh", p, v,
+                    + jnp.einsum("kgc,ckh->kgh", pv, v,
                                  preferred_element_type=jnp.float32))
     m_scr[...] = m_new
 
@@ -155,11 +158,15 @@ def paged_decode_attention(q: jax.Array, k_blocks: jax.Array,
     ]
     operands = [q, k_blocks, v_blocks, k_new, v_new]
     if has_scales:
+        # scale planes ride as (num_blocks, 1, bs) so a block's last two
+        # dims equal the array's — Mosaic refuses a (1, bs) block over
+        # (num_blocks, bs), whose second-minor dim is neither 8-aligned
+        # nor whole
         in_specs += [
-            pl.BlockSpec((1, bs), lambda s, j, tbl, ps: (tbl[s, j], 0)),
-            pl.BlockSpec((1, bs), lambda s, j, tbl, ps: (tbl[s, j], 0)),
+            pl.BlockSpec((1, 1, bs), lambda s, j, tbl, ps: (tbl[s, j], 0, 0)),
+            pl.BlockSpec((1, 1, bs), lambda s, j, tbl, ps: (tbl[s, j], 0, 0)),
         ]
-        operands += [k_scale, v_scale]
+        operands += [k_scale.reshape(nb, 1, bs), v_scale.reshape(nb, 1, bs)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

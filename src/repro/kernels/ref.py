@@ -1,8 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel (the ``ref.py`` contract).
 
 Each ``*_ref`` function is the semantic ground truth the kernels are
-allclose-validated against in interpret mode, and the CPU execution path
-when ``RuntimeOptions.use_pallas`` is off.
+allclose-validated against in interpret mode, and the execution path of
+every program lowered for a platform other than TPU.
 """
 from __future__ import annotations
 

@@ -25,6 +25,7 @@ from repro.models.configs import InputShape, ModelConfig
 from repro.models.model import init_params
 from repro.optim import adamw
 
+from .cache import enable_compile_cache
 from .mesh import make_debug_mesh
 from .steps import make_train_step, options_for
 
@@ -73,6 +74,7 @@ def main() -> None:
     ap.add_argument("--remat", default="none")
     ap.add_argument("--checkpoint-dir", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     kw = {}

@@ -404,9 +404,8 @@ def _attn_decode_paged(layer: Params, x: jax.Array, kb, vb, ks, vs,
     q = _apply_rot1(q, sin, cos)
     k = _apply_rot1(k, sin, cos)
     w = window or opts.decode_window
-    out = kernel_ops.paged_attention(
-        q, kb, vb, tables, pos, k, v, ks, vs, window=w,
-        use_pallas=opts.use_pallas)
+    out = kernel_ops.paged_attention(q, kb, vb, tables, pos, k, v, ks, vs,
+                                     window=w)
     x = x + matmul_w(out.reshape(b, cfg.num_heads * hd), a["wo"]).astype(x.dtype)
 
     if cross_kv is not None and "cross" in layer:
@@ -435,13 +434,35 @@ def paged_kernel_sample_batched_step(params: Params, cfg: ModelConfig,
     """One sampling decode step over paged KV — no gather-to-dense detour.
 
     Drop-in twin of :func:`paged_sample_batched_step` (same signature,
-    same return contract) selected by ``opts.paged_kernel``: instead of
-    materializing a dense ``(mb * bs)`` view per slot, every layer's
-    attention reads its pool blocks *through the block table* via
+    same return contract) selected by ``opts.paged_kernel``:
+    :func:`paged_kernel_decode_logits`, then per-slot sampling."""
+    logits, new_pool = paged_kernel_decode_logits(params, cfg, slot_cache,
+                                                  pool, tokens, tables, opts)
+    s = slot_cache["sample"]
+    nxt, new_keys = jax.vmap(
+        lambda lg, ky, t, tk: sample_logits(lg, ky, t, tk, cfg.vocab_size)
+    )(logits, s["key"], s["temp"], s["top_k"])
+    new_cache = dict(slot_cache)
+    new_cache["sample"] = {"key": new_keys, "temp": s["temp"],
+                           "top_k": s["top_k"]}
+    new_cache["pos"] = slot_cache["pos"] + 1
+    return nxt, new_cache["pos"], new_cache, new_pool
+
+
+def paged_kernel_decode_logits(params: Params, cfg: ModelConfig,
+                               slot_cache: Cache, pool: Cache,
+                               tokens: jax.Array, tables: jax.Array,
+                               opts: RuntimeOptions = DEFAULT_OPTIONS):
+    """The forward half of :func:`paged_kernel_sample_batched_step`:
+    ``(slots, vocab)`` logits for each slot's next token, and the pool
+    with every slot's new KV row scattered into its tail block.
+
+    Instead of materializing a dense ``(mb * bs)`` view per slot, every
+    layer's attention reads its pool blocks *through the block table* via
     :func:`kernel_ops.paged_attention` (the Pallas decode kernel on TPU,
     its ``ref.py`` oracle elsewhere).  The whole step is slot-batched
-    directly — q/k/v projections, FFN and sampling run at batch = slots
-    with per-slot rotary phases — rather than ``vmap`` of a batch-1 step.
+    directly — q/k/v projections and FFN run at batch = slots with
+    per-slot rotary phases — rather than ``vmap`` of a batch-1 step.
     Tables and positions stay runtime data, so occupancy/fragmentation
     never recompiles; int8 pools pass their per-row scales straight into
     the kernel's block loop (dequant on chip, never in HBM).
@@ -471,7 +492,6 @@ def paged_kernel_sample_batched_step(params: Params, cfg: ModelConfig,
     has_cross = cfg.is_encoder_decoder
     n = cfg.num_layers
     n_full = (n // period) * period
-    new_cache = dict(slot_cache)
 
     def run_layer(x, layer, j_kind, kb, vb, ksb, vsb, ckv):
         w = cfg.sliding_window if j_kind == LOCAL else 0
@@ -535,13 +555,6 @@ def paged_kernel_sample_batched_step(params: Params, cfg: ModelConfig,
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params["embed"], x)
     logits = mask_padded_logits_raw(logits, cfg.vocab_size)
-    s = slot_cache["sample"]
-    nxt, new_keys = jax.vmap(
-        lambda lg, ky, t, tk: sample_logits(lg, ky, t, tk, cfg.vocab_size)
-    )(logits, s["key"], s["temp"], s["top_k"])
-    new_cache["sample"] = {"key": new_keys, "temp": s["temp"],
-                           "top_k": s["top_k"]}
-    new_cache["pos"] = pos + 1
 
     # one batched scatter of every layer's new row into each slot's tail
     # block (same collision-freedom argument as the gather step)
@@ -549,8 +562,7 @@ def paged_kernel_sample_batched_step(params: Params, cfg: ModelConfig,
     rv = jnp.moveaxis(row_v, 0, 1)
     blks = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
     offs = pos % bs
-    new_pool = _scatter_kv_rows(pool, rk, rv, blks, offs)
-    return nxt, new_cache["pos"], new_cache, new_pool
+    return logits, _scatter_kv_rows(pool, rk, rv, blks, offs)
 
 
 def paged_prefill_admit(params: Params, cfg: ModelConfig, slot_cache: Cache,

@@ -36,10 +36,12 @@ class RuntimeOptions:
     # device).  Only read by decode_mode="paged"; dense caches keep
     # kv_cache_dtype.
     kv_dtype: str = "auto"
-    # Paged decode reads KV straight from block tables via the Pallas
-    # decode-attention op instead of gathering the pool to dense first.
-    # Tables stay runtime data either way, so flipping this only changes
-    # which program the CompileCache builds — never how it is keyed.
+    # Paged decode reads KV straight from block tables via the
+    # decode-attention op instead of gathering the pool to dense first:
+    # the Pallas kernel in a TPU program, the ref.py oracle in any other
+    # (``use_pallas`` plays no part).  Tables stay runtime data either
+    # way, so flipping this only changes which program the CompileCache
+    # builds — never how it is keyed.
     paged_kernel: bool = False
 
     def replace(self, **kw) -> "RuntimeOptions":

@@ -955,6 +955,17 @@ class ServingEngine:
             jnp.asarray(self._blocks.tables))
         return self._bookkeep_decode(nxt, pos)
 
+    def lower_decode(self) -> "jax.stages.Lowered":
+        """The paged decode program of the current binding, lowered for
+        this engine's state shapes; ``.compile().as_text()`` shows what
+        the device runs (the Pallas kernel appears as a custom call)."""
+        if self.decode_mode != "paged":
+            raise ValueError("lower_decode covers decode_mode='paged'")
+        return self._paged_decode_fn().lower(
+            self.params, self._cache, self._pool,
+            jnp.zeros(self.slots, jnp.int32),
+            jnp.asarray(self._blocks.tables))
+
     def _decode_per_slot(self) -> int:
         emitted = 0
         rec = self.recorder
@@ -1292,6 +1303,17 @@ class ServingEngine:
         self.stats.requeues += len(pending)
         return len(pending)
 
+    def with_pool_options(self, opts: RuntimeOptions) -> RuntimeOptions:
+        """``opts`` with this engine's paged-pool options (``paged_kernel``,
+        ``kv_dtype``) carried over wherever ``opts`` leaves them at their
+        defaults.  The middleware's variant options know nothing of the
+        pool, so without this a swap would quietly drop a kernel engine
+        back to the gather step."""
+        return replace(
+            opts, paged_kernel=opts.paged_kernel or self.opts.paged_kernel,
+            kv_dtype=(self.opts.kv_dtype if opts.kv_dtype == "auto"
+                      else opts.kv_dtype))
+
     def swap_model(self, cfg: ModelConfig, params: Params,
                    opts: RuntimeOptions,
                    params_version: Optional[int] = None) -> None:
@@ -1302,7 +1324,10 @@ class ServingEngine:
         and fall back to re-prefilling their generated prefix when the
         variant really changed (retraining-free variant switching).
         Programs come from the compile cache, so swapping back to an
-        already-served variant costs zero compiles."""
+        already-served variant costs zero compiles.  Paged-pool options
+        left at their defaults in ``opts`` keep their current values
+        (:meth:`with_pool_options`)."""
+        opts = self.with_pool_options(opts)
         requeued = self.requeue_active(reason="swap_requeue")
         if self.recorder.enabled:
             self.recorder.instant(

@@ -1,0 +1,112 @@
+"""Compile the served path's Pallas kernel for a described TPU v5e chip.
+
+Nothing runs: the TPU compiler, installed beside the CPU backend,
+compiles for a chip that is described and not attached, and refuses
+what the chip would refuse (block shapes off the tiling, too much fast
+memory) — which interpret mode cannot show.  The topology is described
+inside a fixture, never at import, so every test worker collects the
+same tests and only the one given this file loads the TPU library.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+from repro.kernels.paged_decode_attn import paged_decode_attention
+from repro.models.model import (init_paged_pool, init_paged_slot_cache,
+                                init_params)
+from repro.models.runtime import DEFAULT_OPTIONS
+from repro.serving.compile_cache import ServePrograms
+
+PAPER = get_config("paper-backbone")
+BLOCK = 16
+SLOTS = 8
+MARK = "tpu_custom_call"
+
+# (heads, kv heads, head dim, blocks per slot): paper-backbone at its
+# registered max_seq_len, and one GQA geometry of a larger served model
+GEOMETRIES = {
+    "paper": (PAPER.num_heads, PAPER.num_kv_heads, PAPER.resolved_head_dim,
+              PAPER.max_seq_len // BLOCK),
+    "gqa": (32, 8, 128, 64),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:               # no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel_args(one_chip, geometry, kv):
+    h, kvh, hd, mb = GEOMETRIES[geometry]
+    nb = SLOTS * mb + 1
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    kv_dt = jnp.int8 if kv == "int8" else jnp.bfloat16
+    args = [s((SLOTS, h, hd), jnp.bfloat16),
+            s((nb, BLOCK, kvh, hd), kv_dt), s((nb, BLOCK, kvh, hd), kv_dt),
+            s((SLOTS, mb), jnp.int32), s((SLOTS,), jnp.int32),
+            s((SLOTS, kvh, hd), jnp.bfloat16),
+            s((SLOTS, kvh, hd), jnp.bfloat16)]
+    scales = ([s((nb, BLOCK), jnp.float32)] * 2 if kv == "int8"
+              else [None, None])
+    return args + scales
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+def test_paged_decode_kernel_compiles(one_chip, geometry, kv):
+    def kernel(q, kb, vb, tb, pos, kn, vn, ks, vs):
+        return paged_decode_attention(q, kb, vb, tb, pos, kn, vn,
+                                      k_scale=ks, v_scale=vs)
+
+    compiled = jax.jit(kernel).lower(
+        *_kernel_args(one_chip, geometry, kv)).compile()
+    assert MARK in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_attention_op_picks_kernel_for_tpu(one_chip, kv):
+    """The served path's op lowers to the kernel for a TPU — no flag."""
+    compiled = ops.paged_attention.lower(
+        *_kernel_args(one_chip, "paper", kv)).compile()
+    assert MARK in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv_dtype", ["auto", "int8"])
+def test_paged_decode_step_compiles(one_chip, kv_dtype):
+    """The engine's own paged decode program with the kernel step, at
+    paper-backbone's full width over a 2048-token slot."""
+    opts = DEFAULT_OPTIONS.replace(paged_kernel=True, kv_dtype=kv_dtype)
+    max_seq = PAPER.max_seq_len
+    nb = SLOTS * (max_seq // BLOCK) + 1
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(PAPER, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_paged_slot_cache(PAPER, SLOTS, max_seq, opts)))
+    pool = on_chip(jax.eval_shape(
+        lambda: init_paged_pool(PAPER, nb, BLOCK, opts)))
+    tokens = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((SLOTS, max_seq // BLOCK), jnp.int32,
+                                  sharding=one_chip)
+    step, _ = ServePrograms(PAPER, opts, max_seq).paged_decode(nb, BLOCK)
+    compiled = step.lower(params, cache, pool, tokens, tables).compile()
+    assert MARK in compiled.as_text()

@@ -76,12 +76,12 @@ SUBPROCESS_PROG = textwrap.dedent("""
     import jax
     from repro.configs import get_config
     from repro.launch.dryrun import build_args
+    from repro.launch.mesh import make_mesh
     from repro.launch.sharding import to_shardings
     from repro.launch.steps import make_step, options_for
     from repro.models.configs import InputShape
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         devices=jax.devices()[:8])
+    mesh = make_mesh((2, 4), ("data", "model"), jax.devices()[:8])
     cfg = get_config("{arch}").reduced(num_layers=2, d_model=256)
     cfg = cfg.with_updates(vocab_size=1024)
     shape = InputShape("mini", {seq}, {batch}, "{kind}")

@@ -15,7 +15,8 @@ from repro.engine import (POLICY_LADDER, activation_bytes, choose_policy,
                           peak_live_bytes, plan_memory, plan_parallelism,
                           quantize_int4, quantize_int8, dequantize_int8,
                           dequantize_int4, sub_batch_split, swap_plan,
-                          backprop_reorder_savings)
+                          backprop_reorder_savings, Swapper)
+from repro.engine.swap import _to_memory
 from repro.offload import Graph, OpNode, build_model_graph
 
 CFG = get_config("paper-backbone")
@@ -126,6 +127,22 @@ def test_parallel_plan_bounds():
 def test_backprop_reorder_savings():
     full, reordered = backprop_reorder_savings(24, 10_000_000)
     assert full == 24 * reordered
+
+
+def test_swapper_moves_through_host_memory():
+    sw = Swapper(use_memory_kinds=True)
+    x = jnp.arange(8.0)
+    assert sw.offload("x", x).sharding.memory_kind == "pinned_host"
+    y = sw.fetch("x")
+    assert y.sharding.memory_kind == "device"
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
+    assert sw.total_bytes() == 2 * x.nbytes
+
+
+def test_swapper_transfer_failure_raises():
+    """A transfer that cannot happen is an error, not a silent stay."""
+    with pytest.raises(ValueError):
+        _to_memory(jnp.ones(4), "no_such_memory")
 
 
 def test_swap_plan_meets_budget():

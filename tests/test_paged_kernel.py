@@ -24,10 +24,12 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.serve import build, make_requests, serve
 from repro.models.model import init_params
 from repro.models.runtime import DEFAULT_OPTIONS
 from repro.serving import (CompileCache, Request, SamplingOpts,
                            ServingEngine)
+from repro.serving import compile_cache as cc_mod
 from repro.serving.paging import TRASH_BLOCK
 
 CFG = get_config("paper-backbone").with_updates(
@@ -217,3 +219,39 @@ def test_cross_kv_dtype_migration_zero_reprefill(dst_opts):
     for r, (_, budget, _, _) in zip(reqs, mix):
         assert tuple(r.generated)[:len(earned[r.rid])] == earned[r.rid]
         assert len(r.generated) == budget           # full budget, no loss
+
+
+# ------------------------------------------------- options through a swap --
+@pytest.mark.parametrize("opts", [KERNEL, INT8, KERNEL_INT8],
+                         ids=["kernel", "int8", "kernel_int8"])
+def test_swap_keeps_pool_options_left_at_defaults(opts):
+    """Options that know nothing of the pool (the middleware's) keep the
+    engine's ``paged_kernel``/``kv_dtype``; explicit ones still win."""
+    eng = _engine(decode_mode="paged", opts=opts)
+    eng.swap_model(CFG, PARAMS, DEFAULT_OPTIONS.replace(remat="dots"))
+    assert eng.opts == opts.replace(remat="dots")
+    eng.swap_model(CFG, PARAMS, KERNEL_INT8)
+    assert eng.opts == KERNEL_INT8
+
+
+def test_kernel_step_survives_middleware_swap(monkeypatch):
+    """The serving driver's adaptation loop swaps in a variant whose
+    options come from the middleware; the swapped-in variant's decode
+    program must still be the kernel step."""
+    traced = []
+    real = cc_mod.paged_kernel_sample_batched_step
+
+    def spy(params, cfg, *args):
+        traced.append(cfg)
+        return real(params, cfg, *args)
+
+    monkeypatch.setattr(cc_mod, "paged_kernel_sample_batched_step", spy)
+    engine, mw = build(CFG, slots=2, max_seq=MAX_SEQ, decode_mode="paged",
+                       compile_cache=CompileCache())
+    reqs = make_requests(CFG.vocab_size, 6, prompt_lens=(8, 30),
+                         max_new_tokens=8)
+    serve(engine, mw, reqs, adapt_every=4, log=lambda _: None)
+    assert engine.generation >= 1 and engine.cfg != CFG
+    assert engine.opts.paged_kernel
+    assert traced[0] == CFG and traced[-1] == engine.cfg
+    assert sum(len(r.generated) for r in reqs) == 6 * 8

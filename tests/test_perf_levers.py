@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import (RuntimeOptions, decode_step, forward, init_cache,
                           init_params, prefill)
 from repro.models import moe as moe_mod
@@ -85,7 +86,7 @@ def test_seq_shard_noop_without_mesh_axis():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                 cfg.vocab_size)
     lg1, _ = forward(params, cfg, tokens, RuntimeOptions())
-    mesh = jax.make_mesh((1,), ("model",), devices=jax.devices()[:1])
+    mesh = make_mesh((1,), ("model",), jax.devices()[:1])
     with mesh:
         lg2, _ = forward(params, cfg, tokens,
                          RuntimeOptions(seq_shard_axis="model"))
