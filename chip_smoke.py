@@ -47,12 +47,15 @@ from repro.models.model import (decode_step, init_paged_pool,  # noqa: E402
                                 paged_kernel_decode_logits)
 from repro.models.runtime import DEFAULT_OPTIONS  # noqa: E402
 from repro.serving import CompileCache, ServingEngine  # noqa: E402
+from repro.serving.paging import TRASH_BLOCK  # noqa: E402
 
 # what a compiled program holds when the Pallas kernel is in it
 KERNEL_MARK = "tpu_custom_call"
-# kernel vs the f32 oracle on the same pool, outputs of magnitude <~3:
-# bf16 q/KV/output rounding plus MXU accumulation order
-KERNEL_ATOL = 4e-2
+# kernel vs the f32 oracle, per slot as a share of the slot's largest
+# output (slot_rel_err): bf16 q/KV/output rounding plus MXU accumulation
+# order.  Dropping one block of a full slot misses by >= 0.05 at SEED
+# (tests/test_chip_smoke.py plants it)
+KERNEL_RTOL = 2e-2
 # first-step logits, kernel engine vs dense engine, as a share of the
 # largest dense logit: two bf16 attention implementations over 8 layers
 LOGIT_RTOL = 5e-2
@@ -82,10 +85,12 @@ def check_device() -> dict:
             "count": len(devs)}
 
 
-def kernel_phase(cfg: ModelConfig, *, slots: int = 8, max_seq: int = 2048,
-                 block_size: int = 16) -> None:
-    """``ops.paged_attention`` at ``cfg``'s attention geometry over ragged
-    slots (empty to full), bf16 and int8 pools, vs the f32 oracle."""
+def kernel_cases(cfg: ModelConfig, *, slots: int = 8, max_seq: int = 2048,
+                 block_size: int = 16) -> dict:
+    """Random operands of ``ops.paged_attention`` at ``cfg``'s attention
+    geometry: slots ragged from empty to full (pos ``0 .. max_seq``), each
+    over blocks of its own.  ``{"bf16": args, "int8": args}``, one per
+    pool dtype, ``args`` in the op's order."""
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     mb = max_seq // block_size
     nb = slots * mb + 1
@@ -103,47 +108,80 @@ def kernel_phase(cfg: ModelConfig, *, slots: int = 8, max_seq: int = 2048,
     pos = jnp.asarray(np.linspace(0, max_seq, slots), jnp.int32)
     kq, ks = kv_quant_rows(kf)
     vq, vs = kv_quant_rows(vf)
-    pools = {"bf16": (kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16),
-                      None, None),
-             "int8": (kq, vq, ks, vs)}
+    return {"bf16": (q, kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16),
+                     tables, pos, kn, vn, None, None),
+            "int8": (q, kq, vq, tables, pos, kn, vn, ks, vs)}
+
+
+def oracle(args) -> np.ndarray:
+    """The f32 ``ref.py`` answer for ``ops.paged_attention``'s operands."""
+    q, kb, vb, tables, pos, kn, vn, ksc, vsc = args
+    f32 = jnp.float32
+    with jax.default_matmul_precision("float32"):
+        return np.asarray(ref.paged_decode_attn_ref(
+            q.astype(f32), kb, vb, tables, pos, kn.astype(f32),
+            vn.astype(f32), k_scale=ksc, v_scale=vsc))
+
+
+def slot_rel_err(out: np.ndarray, want: np.ndarray) -> float:
+    """Worst over slots of ``max|out - want| / max|want|`` in the slot:
+    a slot over thousands of keys averages its values down to ~0.1 while
+    one at pos 0 returns ``v_new`` (~3), so one absolute limit would
+    either fail the empty slot or pass a full one missing blocks."""
+    n = len(want)
+    err = np.abs(out - want).reshape(n, -1).max(axis=1)
+    return float(np.max(err / np.abs(want).reshape(n, -1).max(axis=1)))
+
+
+def kernel_phase(cfg: ModelConfig, **geometry) -> None:
+    """``ops.paged_attention`` on :func:`kernel_cases`' bf16 and int8
+    pools vs the f32 oracle; its program must hold the kernel."""
     parts = []
-    for name, (kb, vb, ksc, vsc) in pools.items():
-        args = (q, kb, vb, tables, pos, kn, vn, ksc, vsc)
+    for name, args in kernel_cases(cfg, **geometry).items():
         compiled = ops.paged_attention.lower(*args).compile()
         _require(KERNEL_MARK in compiled.as_text(),
                  f"{name} pool: paged attention program holds no kernel")
         out = np.asarray(compiled(*args), np.float32)
-        with jax.default_matmul_precision("float32"):
-            want = np.asarray(ref.paged_decode_attn_ref(
-                q.astype(jnp.float32), kb, vb, tables, pos,
-                kn.astype(jnp.float32), vn.astype(jnp.float32),
-                k_scale=ksc, v_scale=vsc))
+        want = oracle(args)
         _require(np.isfinite(out).all(), f"{name} pool: non-finite output")
-        err = float(np.max(np.abs(out - want)))
-        _require(err <= KERNEL_ATOL,
-                 f"{name} pool: max|kernel - oracle| {err} > {KERNEL_ATOL}")
-        parts.append(f"{name} max|err| {err:.3g}")
-    print(f"kernel: slots={slots} heads={h} kv_heads={kvh} head_dim={hd} "
-          f"block={block_size} blocks/slot={mb}; {', '.join(parts)} "
-          f"(tol {KERNEL_ATOL}); {KERNEL_MARK} in program", flush=True)
+        rel = slot_rel_err(out, want)
+        _require(rel <= KERNEL_RTOL, f"{name} pool: kernel vs oracle, worst "
+                 f"slot max|err|/max|oracle| {rel} > {KERNEL_RTOL}")
+        parts.append(f"{name} max|err| {np.max(np.abs(out - want)):.3g}, "
+                     f"worst slot rel {rel:.3g}")
+    q, _, _, tables, *_ = args
+    print(f"kernel: slots={q.shape[0]} heads={cfg.num_heads} "
+          f"kv_heads={cfg.num_kv_heads} head_dim={q.shape[2]} "
+          f"blocks/slot={tables.shape[1]}; {'; '.join(parts)} "
+          f"(tol {KERNEL_RTOL}); {KERNEL_MARK} in program", flush=True)
 
 
 def _decode_has_kernel(engine: ServingEngine) -> bool:
     return KERNEL_MARK in engine.lower_decode().compile().as_text()
 
 
-def _steady_decode_s(engine: ServingEngine, steps: int) -> float:
+def _steady_decode_s(engine: ServingEngine, steps: int, *,
+                     full: bool) -> float:
     """Median seconds of the engine's bound paged decode program, each
-    call closed by ``block_until_ready``, on fresh state of its shapes
-    (the kernel sweeps every block of every slot, so cost does not
-    depend on occupancy)."""
+    call closed by ``block_until_ready``.  ``full``: every slot holds
+    ``max_seq - steps - 1`` tokens in blocks of its own, so the kernel
+    fetches a distinct pool block at every grid step (the served worst
+    case).  Otherwise every slot is empty and every table entry is the
+    trash block, which the kernel's pipeline fetches once."""
     compiled = engine.lower_decode().compile()
     cache = init_paged_slot_cache(engine.cfg, engine.slots, engine.max_seq,
                                   engine.opts)
     pool = init_paged_pool(engine.cfg, engine.pool_blocks,
                            engine.block_size, engine.opts)
+    mb = engine.max_seq // engine.block_size
+    tables = np.full((engine.slots, mb), TRASH_BLOCK, np.int32)
+    if full:
+        cache["pos"] = jnp.full_like(cache["pos"], engine.max_seq - steps - 1)
+        ids = np.random.default_rng(SEED).permutation(
+            np.arange(1, engine.pool_blocks))
+        tables = ids[:engine.slots * mb].reshape(engine.slots, mb)
     tokens = jnp.zeros(engine.slots, jnp.int32)
-    tables = jnp.asarray(engine.block_pool.tables)
+    tables = jnp.asarray(tables, jnp.int32)
     out = jax.block_until_ready(
         compiled(engine.params, cache, pool, tokens, tables))
     times = []
@@ -191,7 +229,8 @@ def serve_phase(cfg: ModelConfig, *, slots: int = 8, max_seq: int = 2048,
     _require(engine.opts.paged_kernel, "the swap dropped the kernel step")
     _require(_decode_has_kernel(engine),
              "decode program after the swap holds no kernel")
-    step_s = _steady_decode_s(engine, timed_steps)
+    full_s = _steady_decode_s(engine, timed_steps, full=True)
+    empty_s = _steady_decode_s(engine, timed_steps, full=False)
     print(f"serve: {cfg.name} d_model={cfg.d_model} layers={cfg.num_layers} "
           f"slots={slots} max_seq={max_seq}; {requests} requests, "
           f"{served} tokens = {requests} x {max_new_tokens}; "
@@ -199,8 +238,10 @@ def serve_phase(cfg: ModelConfig, *, slots: int = 8, max_seq: int = 2048,
           f"program before and after; wall {wall:.2f} s, compile "
           f"{sum(compiles):.2f} s over {len(compiles)} programs, "
           f"engine recompiles {engine.stats.recompiles}; steady decode "
-          f"{step_s * 1e3:.3f} ms/step (median of {timed_steps}, "
-          "block_until_ready)", flush=True)
+          f"of the swapped-in variant ({engine.cfg.num_layers} layers, "
+          f"median of {timed_steps}, block_until_ready) "
+          f"{full_s * 1e3:.3f} ms/step with every slot full, "
+          f"{empty_s * 1e3:.3f} ms/step on an empty pool", flush=True)
 
 
 def _first_step_logits(engine: ServingEngine) -> dict:

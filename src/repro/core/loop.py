@@ -17,6 +17,7 @@ import numpy as np
 from repro.elastic.operators import FULL_SPEC, VariantSpec
 from repro.elastic.supernet import ElasticSupernet
 from repro.models.configs import InputShape, ModelConfig
+from repro.models.runtime import DEFAULT_OPTIONS, RuntimeOptions
 
 from repro.engine.schedule import EngineConfig
 
@@ -211,11 +212,12 @@ class AdaptationLoop:
     def run_trace(self, trace) -> List[Decision]:
         return [self.tick(ctx) for ctx in trace]
 
-    def materialize(self):
+    def materialize(self, base: RuntimeOptions = DEFAULT_OPTIONS):
         """Return (variant_cfg, variant_params, runtime_options) for the
-        currently selected action (requires a supernet)."""
+        currently selected action (requires a supernet); the options are
+        ``base`` with the action's engine config applied."""
         if self.current is None or self.supernet is None:
             raise RuntimeError("no decision or no supernet attached")
         a = self.current.action
         vcfg, vparams = self.supernet.variant(a.variant)
-        return vcfg, vparams, a.engine.to_runtime_options()
+        return vcfg, vparams, a.engine.to_runtime_options(base)

@@ -20,7 +20,7 @@ from repro.elastic.tta import tta_step
 from repro.models.configs import InputShape, ModelConfig, TRAIN_4K
 from repro.models.layers import Params
 from repro.models.model import decode_step, forward, init_cache, prefill
-from repro.models.runtime import RuntimeOptions
+from repro.models.runtime import DEFAULT_OPTIONS, RuntimeOptions
 
 from .loop import AdaptationLoop, Decision
 from .monitor import ResourceContext
@@ -40,6 +40,9 @@ class Middleware:
     quan: bool = False              # the paper API's activation-quant flag
     tta_enabled: bool = True
     allow_offload: bool = True
+    # what a variant's engine config is applied over: options it does not
+    # govern (a paged engine's kernel and pool dtype) come from here
+    base_opts: RuntimeOptions = DEFAULT_OPTIONS
 
     def __post_init__(self):
         self.supernet = ElasticSupernet(self.cfg, self.params)
@@ -62,7 +65,7 @@ class Middleware:
     def current_runtime(self) -> Tuple[ModelConfig, Params, RuntimeOptions]:
         if self.loop.current is None:
             self.adapt(ResourceContext())
-        return self.loop.materialize()
+        return self.loop.materialize(self.base_opts)
 
     # ------------------------------------------------------------ serving --
     def infer(self, tokens: jax.Array, **fwd_kw) -> jax.Array:

@@ -18,7 +18,7 @@ import dataclasses
 from dataclasses import dataclass
 from typing import Dict, List, Tuple
 
-from repro.models.runtime import RuntimeOptions
+from repro.models.runtime import DEFAULT_OPTIONS, RuntimeOptions
 from repro.offload.graph_ir import Graph
 from repro.offload.partition import independent_flows
 
@@ -38,8 +38,11 @@ class EngineConfig:
     sub_batches: int = 1
     host_swap: bool = False
 
-    def to_runtime_options(self) -> RuntimeOptions:
-        return RuntimeOptions(
+    def to_runtime_options(self, base: RuntimeOptions = DEFAULT_OPTIONS
+                           ) -> RuntimeOptions:
+        """``base`` with the fields this config governs set from it; the
+        rest (an engine's paged-pool options, say) ride through."""
+        return base.replace(
             attn_impl=self.attn_impl, q_chunk=self.q_chunk,
             k_chunk=self.k_chunk, decode_window=self.decode_window,
             remat=self.remat_policy,

@@ -32,14 +32,15 @@ def build(cfg: ModelConfig, *, slots: int, max_seq: int,
           ) -> Tuple[ServingEngine, Middleware]:
     """An engine serving ``cfg`` with random weights from ``seed``, and
     the middleware that adapts it.  ``decode_mode="paged"`` binds the
-    paged decode-attention step."""
+    paged decode-attention step; the middleware's variant options are
+    built over the engine's, so a swap keeps it."""
     params = init_params(cfg, jax.random.PRNGKey(seed))
     shape = InputShape("serve", max_seq, slots, "decode")
-    mw = Middleware(cfg=cfg, params=params, shape=shape,
-                    budgets=Budgets(latency_s=1.0, memory_bytes=8e9),
-                    allow_offload=False)
     opts = (DEFAULT_OPTIONS.replace(paged_kernel=True)
             if decode_mode == "paged" else DEFAULT_OPTIONS)
+    mw = Middleware(cfg=cfg, params=params, shape=shape,
+                    budgets=Budgets(latency_s=1.0, memory_bytes=8e9),
+                    allow_offload=False, base_opts=opts)
     engine = ServingEngine(cfg, params, slots=slots, max_seq=max_seq,
                            decode_mode=decode_mode, opts=opts,
                            compile_cache=compile_cache)
@@ -80,7 +81,6 @@ def serve(engine: ServingEngine, mw: Middleware, requests: List[Request],
             d = mw.adapt(trace[ti])
             ti += 1
             vcfg, vparams, vopts = mw.current_runtime()
-            vopts = engine.with_pool_options(vopts)
             if vcfg != engine.cfg or vopts != engine.opts:
                 log(f"[adapt] {d.reason}: {d.action.describe()[:80]}")
                 engine.swap_model(vcfg, vparams, vopts)

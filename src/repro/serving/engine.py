@@ -1303,17 +1303,6 @@ class ServingEngine:
         self.stats.requeues += len(pending)
         return len(pending)
 
-    def with_pool_options(self, opts: RuntimeOptions) -> RuntimeOptions:
-        """``opts`` with this engine's paged-pool options (``paged_kernel``,
-        ``kv_dtype``) carried over wherever ``opts`` leaves them at their
-        defaults.  The middleware's variant options know nothing of the
-        pool, so without this a swap would quietly drop a kernel engine
-        back to the gather step."""
-        return replace(
-            opts, paged_kernel=opts.paged_kernel or self.opts.paged_kernel,
-            kv_dtype=(self.opts.kv_dtype if opts.kv_dtype == "auto"
-                      else opts.kv_dtype))
-
     def swap_model(self, cfg: ModelConfig, params: Params,
                    opts: RuntimeOptions,
                    params_version: Optional[int] = None) -> None:
@@ -1324,10 +1313,7 @@ class ServingEngine:
         and fall back to re-prefilling their generated prefix when the
         variant really changed (retraining-free variant switching).
         Programs come from the compile cache, so swapping back to an
-        already-served variant costs zero compiles.  Paged-pool options
-        left at their defaults in ``opts`` keep their current values
-        (:meth:`with_pool_options`)."""
-        opts = self.with_pool_options(opts)
+        already-served variant costs zero compiles."""
         requeued = self.requeue_active(reason="swap_requeue")
         if self.recorder.enabled:
             self.recorder.instant(

@@ -10,6 +10,8 @@ from pathlib import Path
 from types import SimpleNamespace
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -57,6 +59,50 @@ def test_kernel_phase(interpret_kernel, capsys):
     smoke.kernel_phase(CFG, slots=3, max_seq=64, block_size=16)
     out = capsys.readouterr().out
     assert "bf16 max|err|" in out and "int8 max|err|" in out
+
+
+@pytest.fixture(scope="module")
+def paper_cases():
+    """The kernel phase's operands at its own (full) geometry, with the
+    oracle's answer for each pool."""
+    cases = smoke.kernel_cases(get_config("paper-backbone"))
+    return {name: (args, smoke.oracle(args)) for name, args in cases.items()}
+
+
+def _drop_block(args, slot, j):
+    """``args`` as a kernel that skipped block ``j`` of ``slot`` reads
+    them: the slot's later blocks move up one, and it holds a block's
+    worth of tokens fewer."""
+    q, kb, vb, tables, pos, *rest = args
+    t, p = np.asarray(tables).copy(), np.asarray(pos).copy()
+    j %= t.shape[1]
+    t[slot, j:-1] = t[slot, j + 1:]
+    p[slot] -= kb.shape[1]
+    return (q, kb, vb, jnp.asarray(t), jnp.asarray(p), *rest)
+
+
+# (slot, block): the first and last block of the fullest slot, and the
+# block whose loss moves its slot's output least under the smoke's seed
+DROPS = {"first": (-1, 0), "last": (-1, -1), "least_felt": (-2, 95)}
+
+
+@pytest.mark.parametrize("drop", list(DROPS))
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_kernel_check_rejects_a_dropped_block(paper_cases, pool, drop):
+    args, want = paper_cases[pool]
+    assert smoke.slot_rel_err(want, want) == 0.0
+    broken = smoke.oracle(_drop_block(args, *DROPS[drop]))
+    assert smoke.slot_rel_err(broken, want) > smoke.KERNEL_RTOL
+
+
+def test_kernel_check_rejects_shifted_int8_scales(paper_cases):
+    """K's row scales of one block read one row off."""
+    args, want = paper_cases["int8"]
+    q, kb, vb, tables, pos, kn, vn, ks, vs = args
+    blk = int(tables[-1, 50])
+    ks = jnp.asarray(ks).at[blk].set(jnp.roll(ks[blk], 1))
+    broken = smoke.oracle((q, kb, vb, tables, pos, kn, vn, ks, vs))
+    assert smoke.slot_rel_err(broken, want) > smoke.KERNEL_RTOL
 
 
 def test_oracle_program_holds_no_kernel(monkeypatch):

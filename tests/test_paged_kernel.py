@@ -24,6 +24,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.engine.schedule import EngineConfig
 from repro.launch.serve import build, make_requests, serve
 from repro.models.model import init_params
 from repro.models.runtime import DEFAULT_OPTIONS
@@ -225,11 +226,16 @@ def test_cross_kv_dtype_migration_zero_reprefill(dst_opts):
 @pytest.mark.parametrize("opts", [KERNEL, INT8, KERNEL_INT8],
                          ids=["kernel", "int8", "kernel_int8"])
 def test_swap_keeps_pool_options_left_at_defaults(opts):
-    """Options that know nothing of the pool (the middleware's) keep the
-    engine's ``paged_kernel``/``kv_dtype``; explicit ones still win."""
+    """Variant options built over the engine's own, as the middleware
+    builds them, keep its ``paged_kernel``/``kv_dtype``, which a
+    variant's engine config leaves at their defaults; options passed
+    whole replace them, either way."""
     eng = _engine(decode_mode="paged", opts=opts)
-    eng.swap_model(CFG, PARAMS, DEFAULT_OPTIONS.replace(remat="dots"))
+    vopts = EngineConfig(remat_policy="dots").to_runtime_options(eng.opts)
+    eng.swap_model(CFG, PARAMS, vopts)
     assert eng.opts == opts.replace(remat="dots")
+    eng.swap_model(CFG, PARAMS, DEFAULT_OPTIONS)
+    assert eng.opts == DEFAULT_OPTIONS
     eng.swap_model(CFG, PARAMS, KERNEL_INT8)
     assert eng.opts == KERNEL_INT8
 
