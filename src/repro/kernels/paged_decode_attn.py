@@ -38,6 +38,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+KERNEL_NAME = "paged_decode_attn"
 
 
 def _paged_decode_kernel(*args, has_scales: bool, kvh: int, group: int,
@@ -179,9 +180,12 @@ def paged_decode_attention(q: jax.Array, k_blocks: jax.Array,
             pltpu.VMEM((kvh, group, hd), jnp.float32),          # acc
         ],
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, h, hd), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), pos.astype(jnp.int32), *operands)
+    # one stable name for the kernel in compiled text and device traces
+    with jax.named_scope(KERNEL_NAME):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((slots, h, hd), q.dtype),
+            interpret=interpret,
+            name=KERNEL_NAME,
+        )(tables.astype(jnp.int32), pos.astype(jnp.int32), *operands)

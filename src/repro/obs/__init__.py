@@ -7,7 +7,10 @@ record on a shared timebase.  This package provides:
   **dual timestamps** (wall ``perf_counter`` + the fleet's simulated
   clock), a :class:`TraceRecorder` that collects them, and the no-op
   :data:`NULL_RECORDER` default that keeps disabled hot paths at one
-  attribute load per tick;
+  attribute load per tick; ``span()`` also puts each span on the
+  profiler's clock while a profiler session records;
+* :mod:`~repro.obs.compiles` — the process's backend compiles, counted
+  against the engine tick open when each happens;
 * :mod:`~repro.obs.metrics` — a :class:`MetricsRegistry` of counters,
   gauges, EWMA gauges and P² streaming-quantile histograms that backs
   the legacy public stat surfaces (``ServeStats``,
@@ -34,6 +37,7 @@ traces in CI, and ``tools/check_perf.py`` gates committed
 from .analysis import (COMPONENT_LAYER, COMPONENTS, DeviceAttribution,
                        FleetAttribution, RequestAttribution,
                        attribute_fleet, attribute_requests)
+from .compiles import counting
 from .export import chrome_trace, write_trace
 from .flight import DEFAULT_TRIGGERS, FlightRecorder
 from .metrics import (Counter, EwmaGauge, Gauge, Histogram,
@@ -42,7 +46,8 @@ from .query import (PairingReport, Span, events, instants, pair_spans,
                     request_token_counts, request_tpot_s, request_ttft_s,
                     spans)
 from .recorder import (BEGIN, COUNTER, END, INSTANT, LAYERS,
-                       NULL_RECORDER, Event, NullRecorder, TraceRecorder)
+                       NULL_RECORDER, Event, NullRecorder, TraceRecorder,
+                       profiling)
 from .slo import SLOClass, SLOTracker
 
 __all__ = ["chrome_trace", "write_trace",
@@ -56,4 +61,5 @@ __all__ = ["chrome_trace", "write_trace",
            "SLOClass", "SLOTracker",
            "DEFAULT_TRIGGERS", "FlightRecorder",
            "BEGIN", "COUNTER", "END", "INSTANT", "LAYERS",
-           "NULL_RECORDER", "Event", "NullRecorder", "TraceRecorder"]
+           "NULL_RECORDER", "Event", "NullRecorder", "TraceRecorder",
+           "counting", "profiling"]

@@ -12,16 +12,25 @@ timeline: engine decode ticks measured in wall microseconds and fleet
 clock events measured in simulated seconds land on a shared timebase
 (the exporter picks the simulated clock when every event has it).
 
-Two recorders implement the same four-method surface:
+Two recorders implement the same surface:
 
 * :class:`NullRecorder` — the default everywhere.  ``enabled`` is
-  ``False`` and every method is a no-op ``pass``; hot paths guard arg
-  construction behind ``if recorder.enabled`` so a disabled engine pays
-  one attribute load per tick.
+  ``False`` and every event method is a no-op ``pass``; hot paths guard
+  arg construction behind ``if recorder.enabled`` so a disabled engine
+  pays one attribute load per tick.
 * :class:`TraceRecorder` — appends :class:`Event` rows to an in-memory
   list (bounded by ``capacity``), to be exported with
   :func:`repro.obs.export.write_trace` or queried with
   :mod:`repro.obs.query`.
+
+``span(name, ...)`` is the one context manager over both: it always
+opens a profiler annotation (``jax.profiler.TraceAnnotation``), which
+costs about a microsecond when no profiler session records and, while
+one does, puts the span on the device trace's clock.  On a
+:class:`TraceRecorder` the same call also emits the ``B``/``E`` pair.
+``args`` is a dict, or a function that builds one: it is called only
+where a sink records (an enabled recorder, or a profiler session), so a
+span on a disabled path builds no metadata.
 
 Span discipline: ``begin``/``end`` pairs must nest per ``(pid, tid)``
 track — pid is the device (or ``"fleet"`` for fleet-global events), tid
@@ -39,15 +48,36 @@ Layer categories (``cat``) — the four layers of the cross-level loop:
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Union
+
+import jax
+
+_Annotation = jax.profiler.TraceAnnotation
+# True while a profiler session records (a static check, ~0.2 µs)
+profiling = _Annotation.is_enabled
+
+Args = Union[None, Dict[str, object], Callable[[], Dict[str, object]]]
 
 # the four span layers; tools/check_trace.py can require all of them
 LAYERS = ("request", "engine", "fleet", "placement")
 
 # event phases (a subset of the Chrome trace-event phases)
 BEGIN, END, INSTANT, COUNTER = "B", "E", "i", "C"
+
+
+def _built(args: Args) -> Optional[Dict[str, object]]:
+    return args() if callable(args) else args
+
+
+def _annotation(name: str, args: Args = None) -> _Annotation:
+    """The profiler's side of a span: its metadata is built only while
+    a profiler session records."""
+    if args is not None and profiling():
+        return _Annotation(name, **_built(args))
+    return _Annotation(name)
 
 
 @dataclass(frozen=True)
@@ -73,6 +103,10 @@ class NullRecorder:
 
     enabled = False
     __slots__ = ()
+
+    def span(self, name: str, *, pid: str, tid: str, cat: str = "engine",
+             args: Args = None) -> _Annotation:
+        return _annotation(name, args)
 
     def begin(self, name: str, *, pid: str, tid: str, cat: str = "engine",
               wall_s: Optional[float] = None,
@@ -131,6 +165,17 @@ class TraceRecorder:
             wall_s=time.perf_counter() if wall_s is None else wall_s,
             sim_s=self.sim_clock() if self.sim_clock is not None else None,
             pid=pid, tid=tid, args=args))
+
+    @contextlib.contextmanager
+    def span(self, name: str, *, pid: str, tid: str, cat: str = "engine",
+             args: Args = None):
+        args = _built(args)
+        with _annotation(name, args):
+            self._emit(name, cat, BEGIN, pid, tid, None, args)
+            try:
+                yield
+            finally:
+                self._emit(name, cat, END, pid, tid, None, None)
 
     def begin(self, name: str, *, pid: str, tid: str, cat: str = "engine",
               wall_s: Optional[float] = None,

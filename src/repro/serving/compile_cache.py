@@ -84,6 +84,14 @@ from repro.models.runtime import RuntimeOptions
 Key = Tuple[ModelConfig, RuntimeOptions, int, int, str]
 
 
+def _jit(name: str, fn: Callable, **jit_kw) -> Callable:
+    """``jax.jit`` of ``fn`` under a stable name: the XLA module is
+    ``jit_<name>`` in compiled text and profiler traces, not
+    ``jit__lambda``."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kw)
+
+
 class ServePrograms:
     """The jitted callables for one (cfg, opts, slots, max_seq, domain)."""
 
@@ -92,22 +100,25 @@ class ServePrograms:
         self._cfg, self._opts, self._max_seq = cfg, opts, max_seq
         # donate the stacked cache: its buffers are rewritten every token,
         # so aliasing input→output storage avoids a full cache copy per step
-        self.decode: Callable = jax.jit(
-            lambda p, c, t: sample_batched_step(p, cfg, c, t, opts),
+        self.decode: Callable = _jit(
+            "decode", lambda p, c, t: sample_batched_step(p, cfg, c, t, opts),
             donate_argnums=(1,))
         # all-greedy ticks skip the sampling machinery entirely (the
         # engine picks this program when no active slot has temp > 0;
         # outputs are bit-identical to `decode` at temperature 0)
-        self.decode_greedy: Callable = jax.jit(
+        self.decode_greedy: Callable = _jit(
+            "decode_greedy",
             lambda p, c, t: greedy_batched_step(p, cfg, c, t, opts),
             donate_argnums=(1,))
-        self.decode_ref: Callable = jax.jit(
-            lambda p, c, t: decode_step(p, cfg, c, t, opts))
-        self.sample_ref: Callable = jax.jit(
-            lambda p, c, t: sample_step(p, cfg, c, t, opts))
-        self.sample_first: Callable = jax.jit(
+        self.decode_ref: Callable = _jit(
+            "decode_ref", lambda p, c, t: decode_step(p, cfg, c, t, opts))
+        self.sample_ref: Callable = _jit(
+            "sample_ref", lambda p, c, t: sample_step(p, cfg, c, t, opts))
+        self.sample_first: Callable = _jit(
+            "sample_first",
             lambda lg, k, t, tk: sample_logits(lg, k, t, tk, cfg.vocab_size))
-        self.admit_slot: Callable = jax.jit(
+        self.admit_slot: Callable = _jit(
+            "admit_slot",
             lambda stacked, c, i, k, t, tk: admit_slot(stacked, c, i, k, t,
                                                        tk),
             donate_argnums=(0,))
@@ -126,8 +137,8 @@ class ServePrograms:
         fresh = bucket not in self._prefills
         if fresh:
             cfg, opts = self._cfg, self._opts
-            self._prefills[bucket] = jax.jit(
-                lambda p, c, t: prefill(p, cfg, t, c, opts))
+            self._prefills[bucket] = _jit(
+                "prefill", lambda p, c, t: prefill(p, cfg, t, c, opts))
         return self._prefills[bucket], fresh
 
     def prefill_batch(self, bucket: int, k: int) -> Tuple[Callable, bool]:
@@ -139,7 +150,8 @@ class ServePrograms:
         fresh = (bucket, k) not in self._prefill_batches
         if fresh:
             cfg, opts, max_seq = self._cfg, self._opts, self._max_seq
-            self._prefill_batches[(bucket, k)] = jax.jit(
+            self._prefill_batches[(bucket, k)] = _jit(
+                "prefill_batch",
                 lambda p, st, t, s, ky, tp, tk: batched_prefill_admit(
                     p, cfg, st, t, s, ky, tp, tk, opts, max_seq),
                 donate_argnums=(1,))
@@ -159,7 +171,8 @@ class ServePrograms:
             cfg, opts = self._cfg, self._opts
             step = (paged_kernel_sample_batched_step if opts.paged_kernel
                     else paged_sample_batched_step)
-            self._paged_decodes[key] = jax.jit(
+            self._paged_decodes[key] = _jit(
+                "paged_decode",
                 lambda p, c, pl, t, tb: step(p, cfg, c, pl, t, tb, opts),
                 donate_argnums=(1, 2))
         return self._paged_decodes[key], fresh
@@ -173,7 +186,8 @@ class ServePrograms:
         fresh = key not in self._paged_prefill_batches
         if fresh:
             cfg, opts = self._cfg, self._opts
-            self._paged_prefill_batches[key] = jax.jit(
+            self._paged_prefill_batches[key] = _jit(
+                "paged_prefill",
                 lambda p, st, pl, t, s, ky, tp, tk, db: paged_prefill_admit(
                     p, cfg, st, pl, t, s, ky, tp, tk, db, opts),
                 donate_argnums=(1, 2))
@@ -185,7 +199,8 @@ class ServePrograms:
         prefix-reuse admissions; stacked side donated)."""
         fresh = "admit" not in self._paged_admit
         if fresh:
-            self._paged_admit["admit"] = jax.jit(
+            self._paged_admit["admit"] = _jit(
+                "paged_admit",
                 lambda st, c, i, k, t, tk: admit_slot(st, c, i, k, t, tk),
                 donate_argnums=(0,))
         return self._paged_admit["admit"], fresh
@@ -198,7 +213,8 @@ class ServePrograms:
         key = (nblk, num_blocks, block_size)
         fresh = key not in self._thaw_scatters
         if fresh:
-            self._thaw_scatters[key] = jax.jit(
+            self._thaw_scatters[key] = _jit(
+                "thaw_scatter",
                 lambda pl, rk, rv, ids: paged_thaw_write(pl, rk, rv, ids),
                 donate_argnums=(0,))
         return self._thaw_scatters[key], fresh
@@ -210,8 +226,8 @@ class ServePrograms:
         key = (num_blocks, block_size)
         fresh = key not in self._copy_blocks
         if fresh:
-            self._copy_blocks[key] = jax.jit(
-                lambda pl, s, d: paged_copy_block(pl, s, d),
+            self._copy_blocks[key] = _jit(
+                "copy_block", lambda pl, s, d: paged_copy_block(pl, s, d),
                 donate_argnums=(0,))
         return self._copy_blocks[key], fresh
 

@@ -85,7 +85,7 @@ from repro.models.layers import Params, dtype_of
 from repro.models.model import (init_cache, init_paged_pool,
                                 init_paged_slot_cache, init_slot_cache)
 from repro.models.runtime import DEFAULT_OPTIONS, RuntimeOptions
-from repro.obs import NULL_RECORDER, MetricsRegistry
+from repro.obs import NULL_RECORDER, MetricsRegistry, counting
 
 from .compile_cache import GLOBAL_COMPILE_CACHE, CompileCache, ServePrograms
 from .paging import (DEFAULT_BLOCK_SIZE, TRASH_BLOCK, BlockPool,
@@ -147,7 +147,9 @@ class ServeStats:
     are greedy).  ``recompiles`` is the number of jitted programs *this*
     engine's requests caused to be built (0 on an engine that found
     everything in a warm :class:`CompileCache`, which is how fleet-wide
-    program sharing is asserted).
+    program sharing is asserted).  ``backend_compiles`` counts XLA's own
+    compiles inside this engine's ticks (:mod:`repro.obs.compiles`),
+    eager ops and loads from the persistent compilation cache included.
 
     Since the observability layer landed this is a **view** over the
     engine's :class:`~repro.obs.metrics.MetricsRegistry` — each
@@ -161,6 +163,7 @@ class ServeStats:
                  "prefill_calls": "engine.prefill_calls",
                  "sampled_tokens": "engine.sampled_tokens",
                  "recompiles": "engine.recompiles",
+                 "backend_compiles": "engine.backend_compiles",
                  "oom_events": "engine.oom_events",
                  "requeues": "engine.requeues",
                  "freezes": "engine.freezes",
@@ -189,6 +192,8 @@ class ServeStats:
                               lambda s, v: s._set("sampled_tokens", v))
     recompiles = property(lambda s: s._get("recompiles"),
                           lambda s, v: s._set("recompiles", v))
+    backend_compiles = property(lambda s: s._get("backend_compiles"),
+                                lambda s, v: s._set("backend_compiles", v))
     oom_events = property(lambda s: s._get("oom_events"),
                           lambda s, v: s._set("oom_events", v))
     requeues = property(lambda s: s._get("requeues"),
@@ -309,14 +314,15 @@ class ServingEngine:
         # paths guard on ``recorder.enabled``); the pid names this
         # engine's track in exported traces (the fleet controller passes
         # the device id).  The metrics registry backs ``stats`` and the
-        # step-time EWMA/histogram — a shared registry makes a fleet's
-        # engines aggregate into one namespace.
+        # step-time EWMA — a shared registry makes a fleet's engines
+        # aggregate into one namespace.
         self.recorder = recorder
         self.pid = pid if pid is not None else f"engine{next(_ENGINE_SEQ)}"
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.stats = ServeStats(self.metrics)
         self._ewma = self.metrics.ewma("engine.step_time_s", alpha=0.2)
-        self._step_hist = self.metrics.histogram("engine.step_time_hist_s")
+        self._backend_compiles = self.metrics.counter(
+            "engine.backend_compiles")
         self._queue: Deque[Request] = deque()
         self._active: List[Optional[Request]] = [None] * slots
         self.generation = 0
@@ -341,6 +347,13 @@ class ServingEngine:
         self._admit_holdoff = 0
         self._oom_backoff = 0
         self.oom_backoff_cap = 8
+
+    def _span(self, name: str, args=None):
+        """A span on this engine's ``engine`` track: a profiler
+        annotation always, recorder events where one records (see
+        :meth:`repro.obs.TraceRecorder.span`)."""
+        return self.recorder.span(name, pid=self.pid, tid="engine",
+                                  args=args)
 
     # ------------------------------------------------------------ programs --
     def _note_compile(self, what: str, **detail) -> None:
@@ -424,16 +437,9 @@ class ServingEngine:
             # evicts the youngest admission first
             self._slot_seq = [0] * self.slots
             self._admit_seq = itertools.count(1)
-            self._update_block_gauges()
         else:
             self._caches = [init_cache(self.cfg, 1, self.max_seq, self.opts)
                             for _ in range(self.slots)]
-
-    def _update_block_gauges(self) -> None:
-        self.metrics.gauge("engine.blocks_used").set(self._blocks.used_blocks)
-        self.metrics.gauge("engine.blocks_free").set(self._blocks.free_blocks)
-        self.metrics.gauge("engine.blocks_shared").set(
-            self._blocks.shared_blocks)
 
     @property
     def block_pool(self) -> Optional[BlockPool]:
@@ -567,57 +573,54 @@ class ServingEngine:
         k = len(batch)
         kb = self._k_bucket(k)
         pad = kb - k
-        slots_for = [free.pop(0) for _ in range(k)]
-        toks = np.zeros((kb, bucket), np.int32)
-        keys = np.zeros((kb, 2), np.uint32)
-        temps = np.zeros((kb,), np.float32)
-        top_ks = np.zeros((kb,), np.int32)
-        slot_ids = np.full((kb,), slots_for[0], np.int32)
-        for i, req in enumerate(batch):
-            self._truncate(req, bucket)
-            row = pad + i
-            toks[row, bucket - len(req.prompt):] = req.prompt  # left-pad
-            s = self._sampling_of(req)
-            keys[row] = request_key(s.seed, req.rid, len(req.generated))
-            temps[row] = s.temperature
-            top_ks[row] = s.top_k
-            slot_ids[row] = slots_for[i]
-        if self.recorder.enabled:
-            self.recorder.begin("engine.prefill", pid=self.pid,
-                                tid="engine", cat="engine",
-                                args={"bucket": bucket, "k": k,
+        with self._span("engine.prefill",
+                        args=lambda: {"bucket": bucket, "k": k,
                                       "k_bucket": kb,
-                                      "rids": [r.rid for r in batch]})
-        if self.decode_mode == "paged":
-            nblk = bucket // self.block_size
-            # pad rows scatter into the trash block; real rows into fresh
-            # private blocks (the pool cap in _admit_paged_head guarantees
-            # the allocation succeeds)
-            dest = np.zeros((kb, nblk), np.int32)
+                                      "rids": [r.rid for r in batch]}):
+            slots_for = [free.pop(0) for _ in range(k)]
+            toks = np.zeros((kb, bucket), np.int32)
+            keys = np.zeros((kb, 2), np.uint32)
+            temps = np.zeros((kb,), np.float32)
+            top_ks = np.zeros((kb,), np.int32)
+            slot_ids = np.full((kb,), slots_for[0], np.int32)
             for i, req in enumerate(batch):
-                ids = self._blocks.alloc(nblk)
-                dest[pad + i] = ids
-                for j, b in enumerate(ids):
-                    self._blocks.assign(slots_for[i], j, b)
-            fn = self._paged_prefill_fn(bucket, kb)
-            first, last, self._cache, self._pool = fn(
-                self.params, self._cache, self._pool, jnp.asarray(toks),
-                jnp.asarray(slot_ids), jnp.asarray(keys),
-                jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(dest))
-        else:
-            last = None
-            fn = self._prefill_batch_fn(bucket, kb)
-            first, self._cache = fn(self.params, self._cache,
-                                    jnp.asarray(toks), jnp.asarray(slot_ids),
-                                    jnp.asarray(keys), jnp.asarray(temps),
-                                    jnp.asarray(top_ks))
-        first = jax.device_get(first)
-        self.stats.prefill_calls += 1
-        stamp = time.perf_counter()
-        if self.recorder.enabled:
-            self.recorder.end("engine.prefill", pid=self.pid, tid="engine",
-                              cat="engine", wall_s=stamp)
+                self._truncate(req, bucket)
+                row = pad + i
+                toks[row, bucket - len(req.prompt):] = req.prompt  # left-pad
+                s = self._sampling_of(req)
+                keys[row] = request_key(s.seed, req.rid, len(req.generated))
+                temps[row] = s.temperature
+                top_ks[row] = s.top_k
+                slot_ids[row] = slots_for[i]
+            if self.decode_mode == "paged":
+                nblk = bucket // self.block_size
+                # pad rows scatter into the trash block; real rows into
+                # fresh private blocks (the pool cap in _admit_paged_head
+                # guarantees the allocation succeeds)
+                dest = np.zeros((kb, nblk), np.int32)
+                for i, req in enumerate(batch):
+                    ids = self._blocks.alloc(nblk)
+                    dest[pad + i] = ids
+                    for j, b in enumerate(ids):
+                        self._blocks.assign(slots_for[i], j, b)
+                fn = self._paged_prefill_fn(bucket, kb)
+                first, last, self._cache, self._pool = fn(
+                    self.params, self._cache, self._pool, jnp.asarray(toks),
+                    jnp.asarray(slot_ids), jnp.asarray(keys),
+                    jnp.asarray(temps), jnp.asarray(top_ks),
+                    jnp.asarray(dest))
+            else:
+                last = None
+                fn = self._prefill_batch_fn(bucket, kb)
+                first, self._cache = fn(self.params, self._cache,
+                                        jnp.asarray(toks),
+                                        jnp.asarray(slot_ids),
+                                        jnp.asarray(keys), jnp.asarray(temps),
+                                        jnp.asarray(top_ks))
+            with self._span("engine.wait"):
+                first = jax.device_get(first)
+            self.stats.prefill_calls += 1
+            stamp = time.perf_counter()
         for i, req in enumerate(batch):
             slot = slots_for[i]
             if self.decode_mode == "paged":
@@ -643,19 +646,19 @@ class ServingEngine:
                         self._blocks)
             alive = self._emit_first(req, int(first[pad + i]), stamp, free,
                                      slot)
-            if self.decode_mode == "paged":
-                if not alive:
-                    # budget completed at prefill: the slot's references
-                    # go, but a cached prefix entry keeps the blocks live
-                    self._blocks.release_slot(slot)
-                self._update_block_gauges()
+            if self.decode_mode == "paged" and not alive:
+                # budget completed at prefill: the slot's references go,
+                # but a cached prefix entry keeps the blocks live
+                self._blocks.release_slot(slot)
 
     def _snapshot_slot_leaves(self, slot: int) -> dict:
         """Host copies of one slot's non-KV, non-sampling cache leaves
         (batch=1 layout) — the state a prefix-cache re-admission must
         restore alongside the shared blocks."""
-        return {name: np.asarray(jax.device_get(leaf[slot]))
-                for name, leaf in self._cache.items() if name != "sample"}
+        with self._span("engine.wait"):
+            return {name: np.asarray(jax.device_get(leaf[slot]))
+                    for name, leaf in self._cache.items()
+                    if name != "sample"}
 
     def _admit_from_prefix(self, req: Request, entry: PrefixEntry,
                            free: List[int]) -> None:
@@ -681,15 +684,16 @@ class ServingEngine:
                                              top_k)
         self._slot_pos[slot] = entry.pos
         self._slot_seq[slot] = next(self._admit_seq)
+        with self._span("engine.wait"):
+            tok = int(tok)
         stamp = time.perf_counter()
         if self.recorder.enabled:
             self.recorder.instant("engine.prefix_hit", pid=self.pid,
                                   tid="engine", cat="engine", wall_s=stamp,
                                   args={"rid": req.rid,
                                         "blocks": len(entry.block_ids)})
-        if not self._emit_first(req, int(tok), stamp, free, slot):
+        if not self._emit_first(req, tok, stamp, free, slot):
             self._blocks.release_slot(slot)
-        self._update_block_gauges()
 
     def _admit_one(self, req: Request, free: List[int]) -> None:
         """Sequential reference admission: one prefill jit call for this
@@ -698,28 +702,25 @@ class ServingEngine:
         slot = free.pop(0)
         bucket = self._bucket(len(req.prompt))
         self._truncate(req, bucket)
-        if self.recorder.enabled:
-            self.recorder.begin("engine.prefill", pid=self.pid,
-                                tid="engine", cat="engine",
-                                args={"bucket": bucket, "k": 1,
-                                      "rids": [req.rid]})
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, bucket - len(req.prompt):] = req.prompt  # left-pad
-        cache = init_cache(self.cfg, 1, self.max_seq, self.opts)
-        logits, cache = self._prefill_fn(bucket)(
-            self.params, cache, jnp.asarray(toks))
-        self.stats.prefill_calls += 1
-        s = self._sampling_of(req)
-        key = jnp.asarray(request_key(s.seed, req.rid, len(req.generated)))
-        temp = jnp.float32(s.temperature)
-        top_k = jnp.int32(s.top_k)
-        tok, key = self._programs.sample_first(logits[0, -1], key, temp,
-                                               top_k)
-        nxt = int(tok)
-        stamp = time.perf_counter()
-        if self.recorder.enabled:
-            self.recorder.end("engine.prefill", pid=self.pid, tid="engine",
-                              cat="engine", wall_s=stamp)
+        with self._span("engine.prefill",
+                        args=lambda: {"bucket": bucket, "k": 1,
+                                      "rids": [req.rid]}):
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, bucket - len(req.prompt):] = req.prompt  # left-pad
+            cache = init_cache(self.cfg, 1, self.max_seq, self.opts)
+            logits, cache = self._prefill_fn(bucket)(
+                self.params, cache, jnp.asarray(toks))
+            self.stats.prefill_calls += 1
+            s = self._sampling_of(req)
+            key = jnp.asarray(request_key(s.seed, req.rid,
+                                          len(req.generated)))
+            temp = jnp.float32(s.temperature)
+            top_k = jnp.int32(s.top_k)
+            tok, key = self._programs.sample_first(logits[0, -1], key, temp,
+                                                   top_k)
+            with self._span("engine.wait"):
+                nxt = int(tok)
+            stamp = time.perf_counter()
         if not self._emit_first(req, nxt, stamp, free, slot):
             return
         if self.decode_mode == "batched":
@@ -834,60 +835,62 @@ class ServingEngine:
     def _decode_batched(self) -> int:
         if not any(r is not None for r in self._active):
             return 0
-        tokens = np.zeros(self.slots, np.int32)
-        sampling = False
-        for slot, req in enumerate(self._active):
-            if req is not None:
-                tokens[slot] = req.generated[-1]
-                sampling = sampling or \
-                    self._sampling_of(req).temperature > 0
-        # all-greedy ticks take the pure-argmax program: no per-slot
-        # argsort/categorical work selected away by a where — the default
-        # greedy engine keeps its historical hot-path cost.  Outputs are
-        # bit-identical either way, so mixed workloads can alternate.
-        step_fn = (self._programs.decode if sampling
-                   else self._programs.decode_greedy)
-        nxt, pos, self._cache = step_fn(
-            self.params, self._cache, jnp.asarray(tokens))
+        with self._span("engine.dispatch"):
+            tokens = np.zeros(self.slots, np.int32)
+            sampling = False
+            for slot, req in enumerate(self._active):
+                if req is not None:
+                    tokens[slot] = req.generated[-1]
+                    sampling = sampling or \
+                        self._sampling_of(req).temperature > 0
+            # all-greedy ticks take the pure-argmax program: no per-slot
+            # argsort/categorical work selected away by a where — the
+            # default greedy engine keeps its historical hot-path cost.
+            # Outputs are bit-identical either way, so mixed workloads can
+            # alternate.
+            step_fn = (self._programs.decode if sampling
+                       else self._programs.decode_greedy)
+            nxt, pos, self._cache = step_fn(
+                self.params, self._cache, jnp.asarray(tokens))
         return self._bookkeep_decode(nxt, pos)
 
     def _bookkeep_decode(self, nxt, pos) -> int:
         """Shared post-step bookkeeping for the batched and paged decode
         paths: one bulk device→host transfer, per-slot token append,
         finish detection and trace emission."""
-        nxt, pos = jax.device_get((nxt, pos))   # one bulk transfer per tick
+        with self._span("engine.wait"):
+            nxt, pos = jax.device_get((nxt, pos))   # one bulk transfer
         paged = self.decode_mode == "paged"
         emitted = 0
-        freed_blocks = False
         rec = self.recorder
-        stamp = time.perf_counter() if rec.enabled else 0.0
-        for slot, req in enumerate(self._active):
-            if req is None:      # masked slot: decoded, output ignored
-                continue
-            req.generated.append(int(nxt[slot]))
-            emitted += 1
-            if paged:
-                self._slot_pos[slot] = int(pos[slot])
-            if self._sampling_of(req).temperature > 0:
-                self.stats.sampled_tokens += 1
-            if rec.enabled:
-                rec.instant("req.decode", pid=self.pid, tid=f"slot{slot}",
-                            cat="request", wall_s=stamp,
-                            args={"rid": req.rid, "token": int(nxt[slot])})
-            if len(req.generated) >= req.max_new_tokens \
-                    or int(pos[slot]) >= self.max_seq - 1:
-                req.done = True
-                self._active[slot] = None
+        with self._span("engine.bookkeep"):
+            stamp = time.perf_counter() if rec.enabled else 0.0
+            for slot, req in enumerate(self._active):
+                if req is None:      # masked slot: decoded, output ignored
+                    continue
+                req.generated.append(int(nxt[slot]))
+                emitted += 1
                 if paged:
-                    self._blocks.release_slot(slot)
-                    freed_blocks = True
+                    self._slot_pos[slot] = int(pos[slot])
+                if self._sampling_of(req).temperature > 0:
+                    self.stats.sampled_tokens += 1
                 if rec.enabled:
-                    rec.end("req.slot", pid=self.pid, tid=f"slot{slot}",
-                            cat="request", wall_s=stamp,
-                            args={"rid": req.rid, "reason": "finished",
-                                  "tokens": len(req.generated)})
-        if freed_blocks:
-            self._update_block_gauges()
+                    rec.instant("req.decode", pid=self.pid,
+                                tid=f"slot{slot}", cat="request",
+                                wall_s=stamp,
+                                args={"rid": req.rid,
+                                      "token": int(nxt[slot])})
+                if len(req.generated) >= req.max_new_tokens \
+                        or int(pos[slot]) >= self.max_seq - 1:
+                    req.done = True
+                    self._active[slot] = None
+                    if paged:
+                        self._blocks.release_slot(slot)
+                    if rec.enabled:
+                        rec.end("req.slot", pid=self.pid, tid=f"slot{slot}",
+                                cat="request", wall_s=stamp,
+                                args={"rid": req.rid, "reason": "finished",
+                                      "tokens": len(req.generated)})
         return emitted
 
     # ------------------------------------------------------ paged decode --
@@ -938,21 +941,22 @@ class ServingEngine:
                     self._pool, jnp.int32(bid), jnp.int32(ids[0]))
                 self._blocks.decref(bid)
             self._blocks.assign(slot, idx, ids[0])
-            self._update_block_gauges()
 
     def _decode_paged(self) -> int:
         if not any(r is not None for r in self._active):
             return 0
-        self._ensure_tail_blocks()
-        tokens = np.zeros(self.slots, np.int32)
-        for slot, req in enumerate(self._active):
-            if req is not None:
-                tokens[slot] = req.generated[-1]
-        # block tables are runtime data: constant (slots, max_seq/bs)
-        # shape, so occupancy/sharing churn reuses one compiled program
-        nxt, pos, self._cache, self._pool = self._paged_decode_fn()(
-            self.params, self._cache, self._pool, jnp.asarray(tokens),
-            jnp.asarray(self._blocks.tables))
+        with self._span("engine.blocks"):
+            self._ensure_tail_blocks()
+        with self._span("engine.dispatch"):
+            tokens = np.zeros(self.slots, np.int32)
+            for slot, req in enumerate(self._active):
+                if req is not None:
+                    tokens[slot] = req.generated[-1]
+            # block tables are runtime data: constant (slots, max_seq/bs)
+            # shape, so occupancy/sharing churn reuses one compiled program
+            nxt, pos, self._cache, self._pool = self._paged_decode_fn()(
+                self.params, self._cache, self._pool, jnp.asarray(tokens),
+                jnp.asarray(self._blocks.tables))
         return self._bookkeep_decode(nxt, pos)
 
     def lower_decode(self) -> "jax.stages.Lowered":
@@ -998,37 +1002,32 @@ class ServingEngine:
     def step(self) -> int:
         """One engine tick: admit waiting requests, decode one token for
         every active slot.  Returns number of tokens emitted."""
-        self._admit()
-        # time only the decode sweep: prefill/compile costs would otherwise
-        # masquerade as decode-step latency in the telemetry channel
-        rec = self.recorder
-        t0 = time.perf_counter()
-        if rec.enabled:
-            rec.begin("engine.step", pid=self.pid, tid="engine",
-                      cat="engine", wall_s=t0,
-                      args={"generation": self.generation})
-        if self.decode_mode == "batched":
-            emitted = self._decode_batched()
-        elif self.decode_mode == "paged":
-            emitted = self._decode_paged()
-        else:
-            emitted = self._decode_per_slot()
-        self.stats.steps += 1
-        self.stats.tokens_out += emitted
-        t1 = time.perf_counter()
-        dt = t1 - t0
-        self.step_times.append(dt)
-        self._ewma.update(dt)
-        self._step_hist.observe(dt)
-        if rec.enabled:
-            rec.end("engine.step", pid=self.pid, tid="engine",
-                    cat="engine", wall_s=t1, args={"emitted": emitted})
-        if self.slo is not None and emitted:
-            # every active slot advanced one token this step, so the
-            # step wall time is each of those tokens' inter-token time
-            self.slo.observe("tpot", dt, n=emitted)
-        if self.on_step is not None:
-            self.on_step(dt, emitted, self.generation)
+        with counting(self._backend_compiles), self._span("engine.tick"):
+            with self._span("engine.admit"):
+                self._admit()
+            # time only the decode sweep: prefill/compile costs would
+            # otherwise masquerade as decode-step latency in the telemetry
+            # channel
+            with self._span("engine.step",
+                            args=lambda: {"generation": self.generation}):
+                t0 = time.perf_counter()
+                if self.decode_mode == "batched":
+                    emitted = self._decode_batched()
+                elif self.decode_mode == "paged":
+                    emitted = self._decode_paged()
+                else:
+                    emitted = self._decode_per_slot()
+                self.stats.steps += 1
+                self.stats.tokens_out += emitted
+                dt = time.perf_counter() - t0
+            self.step_times.append(dt)
+            self._ewma.update(dt)
+            if self.slo is not None and emitted:
+                # every active slot advanced one token this step, so the
+                # step wall time is each of those tokens' inter-token time
+                self.slo.observe("tpot", dt, n=emitted)
+            if self.on_step is not None:
+                self.on_step(dt, emitted, self.generation)
         return emitted
 
     @property
@@ -1084,42 +1083,46 @@ class ServingEngine:
         The sampling subtree carries the slot's **advanced** PRNG key, so
         the thawed stream continues bit for bit."""
         req = self._active[slot]
-        if self.decode_mode == "per_slot":
-            cache = self._caches[slot]
-            pos = int(jax.device_get(cache["pos"]))
-            leaves = {name: np.asarray(jax.device_get(leaf))
-                      for name, leaf in cache.items() if name != "sample"}
-            sample = {name: np.asarray(jax.device_get(v))
-                      for name, v in cache["sample"].items()}
-        else:
-            pos = (self._slot_pos[slot] if self.decode_mode == "paged"
-                   else int(jax.device_get(self._cache["pos"][slot])))
-            leaves = {name: np.asarray(jax.device_get(leaf[slot]))
-                      for name, leaf in self._cache.items()
-                      if name != "sample"}
-            sample = {name: np.asarray(jax.device_get(arr[slot]))
-                      for name, arr in self._cache["sample"].items()}
-        for name in _SEQ_TRIM_LEAVES:
-            if name in leaves:
-                leaves[name] = leaves[name][:, :, :pos]
-        if self.decode_mode == "paged":
-            # gather this slot's blocks into dense (n_attn, 1, pos, ...) KV;
-            # int8 pools dequantize first so the blob stays portable in
-            # kv_cache_dtype (any engine can thaw it, re-quantizing or not)
-            bs = self.block_size
-            nblk = blocks_needed(pos, bs)
-            ids = self._blocks.tables[slot, :nblk]
-            for name in ("k", "v"):
-                blocks = self._pool[name][jnp.asarray(ids)]
-                if name + "_scale" in self._pool:
-                    blocks = kv_dequant_rows(
-                        blocks, self._pool[name + "_scale"][jnp.asarray(ids)],
-                        dtype_of(self.opts.kv_cache_dtype))
-                g = np.asarray(jax.device_get(blocks))
-                n_attn, kvh, hd = g.shape[1], g.shape[3], g.shape[4]
-                dense = g.transpose(1, 0, 2, 3, 4).reshape(
-                    n_attn, nblk * bs, kvh, hd)[:, :pos]
-                leaves[name] = dense[:, None]
+        with self._span("engine.wait"):
+            if self.decode_mode == "per_slot":
+                cache = self._caches[slot]
+                pos = int(jax.device_get(cache["pos"]))
+                leaves = {name: np.asarray(jax.device_get(leaf))
+                          for name, leaf in cache.items()
+                          if name != "sample"}
+                sample = {name: np.asarray(jax.device_get(v))
+                          for name, v in cache["sample"].items()}
+            else:
+                pos = (self._slot_pos[slot] if self.decode_mode == "paged"
+                       else int(jax.device_get(self._cache["pos"][slot])))
+                leaves = {name: np.asarray(jax.device_get(leaf[slot]))
+                          for name, leaf in self._cache.items()
+                          if name != "sample"}
+                sample = {name: np.asarray(jax.device_get(arr[slot]))
+                          for name, arr in self._cache["sample"].items()}
+            for name in _SEQ_TRIM_LEAVES:
+                if name in leaves:
+                    leaves[name] = leaves[name][:, :, :pos]
+            if self.decode_mode == "paged":
+                # gather this slot's blocks into dense (n_attn, 1, pos, ...)
+                # KV; int8 pools dequantize first so the blob stays portable
+                # in kv_cache_dtype (any engine can thaw it, re-quantizing
+                # or not)
+                bs = self.block_size
+                nblk = blocks_needed(pos, bs)
+                ids = self._blocks.tables[slot, :nblk]
+                for name in ("k", "v"):
+                    at = jnp.asarray(ids)
+                    blocks = self._pool[name][at]
+                    if name + "_scale" in self._pool:
+                        blocks = kv_dequant_rows(
+                            blocks, self._pool[name + "_scale"][at],
+                            dtype_of(self.opts.kv_cache_dtype))
+                    g = np.asarray(jax.device_get(blocks))
+                    n_attn, kvh, hd = g.shape[1], g.shape[3], g.shape[4]
+                    dense = g.transpose(1, 0, 2, 3, 4).reshape(
+                        n_attn, nblk * bs, kvh, hd)[:, :pos]
+                    leaves[name] = dense[:, None]
         frozen = FrozenRequest(rid=req.rid, pos=pos,
                                consumed=len(req.generated), leaves=leaves,
                                sample=sample, fingerprint=self.fingerprint,
@@ -1138,7 +1141,6 @@ class ServingEngine:
         self._active[slot] = None
         if self.decode_mode == "paged":
             self._blocks.release_slot(slot)
-            self._update_block_gauges()
         return frozen
 
     def freeze(self, rid: int) -> Optional[Request]:
@@ -1210,56 +1212,20 @@ class ServingEngine:
         shape (padding beyond ``pos`` is never read unmasked) and the
         slot resumes decoding from the blob's advanced sampling key."""
         fz = req.frozen
-        key = jnp.asarray(fz.sample["key"])
-        temp = jnp.asarray(fz.sample["temp"], jnp.float32)
-        top_k = jnp.asarray(fz.sample["top_k"], jnp.int32)
-        if self.decode_mode == "per_slot":
-            cache = init_cache(self.cfg, 1, self.max_seq, self.opts)
-            cache = {name: self._padded_to(fz.leaves[name], leaf.shape,
-                                           leaf.dtype)
-                     for name, leaf in cache.items()}
-            cache["sample"] = {"key": key, "temp": temp, "top_k": top_k}
-            self._caches[slot] = cache
-        elif self.decode_mode == "batched":
-            row = {name: self._padded_to(fz.leaves[name], leaf.shape[1:],
-                                         leaf.dtype)
-                   for name, leaf in self._cache.items() if name != "sample"}
-            self._cache = self._programs.admit_slot(
-                self._cache, row, jnp.int32(slot), key, temp, top_k)
-        else:
-            bs = self.block_size
-            nblk = blocks_needed(fz.pos, bs)
-            # program count stays bounded: the scatter is keyed on the
-            # *bucketed* block count, trailing ids aimed at trash
-            nblk_prog = self._bucket(fz.pos) // bs
+        ids = None
+        if self.decode_mode == "paged":
+            nblk = blocks_needed(fz.pos, self.block_size)
             ids = self._alloc_blocks_reclaiming(nblk, keep_slot=slot)
             if ids is None:
                 raise RuntimeError("paged pool cannot hold one thawed "
                                    "request — pool_blocks misconfigured")
             for j, b in enumerate(ids):
                 self._blocks.assign(slot, j, b)
-            rows = {}
-            for name in ("k", "v"):
-                src = fz.leaves[name][:, 0]          # (n_attn, pos, kvh, hd)
-                n_attn, _, kvh, hd = src.shape
-                buf = np.zeros((n_attn, nblk_prog * bs, kvh, hd), src.dtype)
-                buf[:, :fz.pos] = src
-                rows[name] = jnp.asarray(
-                    buf.reshape(n_attn, nblk_prog, bs, kvh, hd)
-                    .transpose(1, 0, 2, 3, 4))
-            ids_arr = np.full(nblk_prog, TRASH_BLOCK, np.int32)
-            ids_arr[:nblk] = ids
-            self._pool = self._thaw_scatter_fn(nblk_prog)(
-                self._pool, rows["k"], rows["v"], jnp.asarray(ids_arr))
-            row = {name: self._padded_to(fz.leaves[name], leaf.shape[1:],
-                                         leaf.dtype)
-                   for name, leaf in self._cache.items() if name != "sample"}
-            self._cache = self._paged_admit_fn()(self._cache, row,
-                                                 jnp.int32(slot), key, temp,
-                                                 top_k)
             self._slot_pos[slot] = fz.pos
             self._slot_seq[slot] = next(self._admit_seq)
-            self._update_block_gauges()
+        # the blob's uploads and the programs that write them into place
+        with self._span("engine.wait"):
+            self._upload_frozen(fz, slot, ids)
         req.frozen = None
         self._active[slot] = req
         self.stats.thaws += 1
@@ -1273,6 +1239,49 @@ class ServingEngine:
             self.recorder.begin("req.slot", pid=self.pid, tid=f"slot{slot}",
                                 cat="request", wall_s=stamp,
                                 args={"rid": req.rid})
+
+    def _upload_frozen(self, fz: FrozenRequest, slot: int,
+                       ids: Optional[List[int]]) -> None:
+        """Write a blob's state into ``slot`` (and, paged, its KV into
+        the blocks ``ids``)."""
+        key = jnp.asarray(fz.sample["key"])
+        temp = jnp.asarray(fz.sample["temp"], jnp.float32)
+        top_k = jnp.asarray(fz.sample["top_k"], jnp.int32)
+        if self.decode_mode == "per_slot":
+            cache = init_cache(self.cfg, 1, self.max_seq, self.opts)
+            cache = {name: self._padded_to(fz.leaves[name], leaf.shape,
+                                           leaf.dtype)
+                     for name, leaf in cache.items()}
+            cache["sample"] = {"key": key, "temp": temp, "top_k": top_k}
+            self._caches[slot] = cache
+            return
+        row = {name: self._padded_to(fz.leaves[name], leaf.shape[1:],
+                                     leaf.dtype)
+               for name, leaf in self._cache.items() if name != "sample"}
+        if self.decode_mode == "batched":
+            self._cache = self._programs.admit_slot(
+                self._cache, row, jnp.int32(slot), key, temp, top_k)
+            return
+        bs = self.block_size
+        # program count stays bounded: the scatter is keyed on the
+        # *bucketed* block count, trailing ids aimed at trash
+        nblk_prog = self._bucket(fz.pos) // bs
+        rows = {}
+        for name in ("k", "v"):
+            src = fz.leaves[name][:, 0]          # (n_attn, pos, kvh, hd)
+            n_attn, _, kvh, hd = src.shape
+            buf = np.zeros((n_attn, nblk_prog * bs, kvh, hd), src.dtype)
+            buf[:, :fz.pos] = src
+            rows[name] = jnp.asarray(
+                buf.reshape(n_attn, nblk_prog, bs, kvh, hd)
+                .transpose(1, 0, 2, 3, 4))
+        ids_arr = np.full(nblk_prog, TRASH_BLOCK, np.int32)
+        ids_arr[:len(ids)] = ids
+        self._pool = self._thaw_scatter_fn(nblk_prog)(
+            self._pool, rows["k"], rows["v"], jnp.asarray(ids_arr))
+        self._cache = self._paged_admit_fn()(self._cache, row,
+                                             jnp.int32(slot), key, temp,
+                                             top_k)
 
     def drain_waiting(self) -> List[Request]:
         """Detach every *waiting* (queued, not yet admitted) request in
@@ -1314,22 +1323,22 @@ class ServingEngine:
         variant really changed (retraining-free variant switching).
         Programs come from the compile cache, so swapping back to an
         already-served variant costs zero compiles."""
-        requeued = self.requeue_active(reason="swap_requeue")
-        if self.recorder.enabled:
-            self.recorder.instant(
-                "engine.swap", pid=self.pid, tid="engine", cat="engine",
-                args={"generation": self.generation + 1,
-                      "requeued": requeued})
-        self.cfg, self.params, self.opts = cfg, params, opts
-        self.params_version = (params_version if params_version is not None
-                               else id(params))
-        self.generation += 1
-        self._programs = self._bind_programs()
-        self._reset_caches()
-        # blobs that can't thaw against the new binding re-admit via the
-        # legacy path; dropping them up front lets the whole requeue
-        # merge into one admission burst instead of k head-of-line
-        # fragments (pinned by the swap prefill_calls tests)
-        for r in self._queue:
-            if r.frozen is not None and not self.can_thaw(r.frozen):
-                self._discard_frozen(r)
+        with self._span("engine.swap", args=lambda: {
+                "generation": self.generation + 1,
+                "requeued": sum(r is not None for r in self._active)}):
+            self.requeue_active(reason="swap_requeue")
+            self.cfg, self.params, self.opts = cfg, params, opts
+            self.params_version = (params_version
+                                   if params_version is not None
+                                   else id(params))
+            self.generation += 1
+            self._programs = self._bind_programs()
+            self._reset_caches()
+            # blobs that can't thaw against the new binding re-admit via
+            # the legacy path; dropping them up front lets the whole
+            # requeue merge into one admission burst instead of k
+            # head-of-line fragments (pinned by the swap prefill_calls
+            # tests)
+            for r in self._queue:
+                if r.frozen is not None and not self.can_thaw(r.frozen):
+                    self._discard_frozen(r)

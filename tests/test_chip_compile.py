@@ -14,7 +14,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
-from repro.kernels.paged_decode_attn import paged_decode_attention
+from repro.kernels.paged_decode_attn import (KERNEL_NAME,
+                                             paged_decode_attention)
 from repro.models.model import (init_paged_pool, init_paged_slot_cache,
                                 init_params)
 from repro.models.runtime import DEFAULT_OPTIONS
@@ -109,4 +110,8 @@ def test_paged_decode_step_compiles(one_chip, kv_dtype):
                                   sharding=one_chip)
     step, _ = ServePrograms(PAPER, opts, max_seq).paged_decode(nb, BLOCK)
     compiled = step.lower(params, cache, pool, tokens, tables).compile()
-    assert MARK in compiled.as_text()
+    text = compiled.as_text()
+    assert MARK in text
+    # stable names: the step's module, and the kernel inside it
+    assert "jit_paged_decode" in text
+    assert KERNEL_NAME in text

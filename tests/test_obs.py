@@ -184,7 +184,9 @@ def test_stats_are_views_over_registry():
     assert eng.stats.tokens_out == m.counter("engine.tokens_out").value
     assert eng.stats.prefills == m.counter("engine.prefills").value
     assert eng.step_time_ewma_s == m.ewma("engine.step_time_s").value
-    assert m.histogram("engine.step_time_hist_s").count == eng.stats.steps
+    assert eng.stats.backend_compiles == \
+        m.counter("engine.backend_compiles").value
+    assert len(eng.step_times) == eng.stats.steps
 
 
 def test_ewma_gauge_bit_identical_to_legacy_fold():
