@@ -165,9 +165,9 @@ def _steady_decode_s(engine: ServingEngine, steps: int, *,
     """Median seconds of the engine's bound paged decode program, each
     call closed by ``block_until_ready``.  ``full``: every slot holds
     ``max_seq - steps - 1`` tokens in blocks of its own, so the kernel
-    fetches a distinct pool block at every grid step (the served worst
+    sweeps and fetches every block of every slot (the served worst
     case).  Otherwise every slot is empty and every table entry is the
-    trash block, which the kernel's pipeline fetches once."""
+    trash block: the kernel sweeps no block and fetches none."""
     compiled = engine.lower_decode().compile()
     cache = init_paged_slot_cache(engine.cfg, engine.slots, engine.max_seq,
                                   engine.opts)

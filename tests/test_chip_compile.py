@@ -26,12 +26,15 @@ BLOCK = 16
 SLOTS = 8
 MARK = "tpu_custom_call"
 
-# (heads, kv heads, head dim, blocks per slot): paper-backbone at its
-# registered max_seq_len, and one GQA geometry of a larger served model
+# (heads, kv heads, head dim, blocks per slot, slots): paper-backbone at
+# its registered max_seq_len, one GQA geometry of a larger served model,
+# and Yi-34B's (group 7) as the chip benchmark serves it, 32 slots of
+# 2304 tokens swept 8 blocks per grid step
 GEOMETRIES = {
     "paper": (PAPER.num_heads, PAPER.num_kv_heads, PAPER.resolved_head_dim,
-              PAPER.max_seq_len // BLOCK),
-    "gqa": (32, 8, 128, 64),
+              PAPER.max_seq_len // BLOCK, SLOTS),
+    "gqa": (32, 8, 128, 64, SLOTS),
+    "yi-34b": (56, 8, 128, 2304 // BLOCK, 32),
 }
 
 
@@ -49,18 +52,18 @@ def one_chip():
 
 
 def _kernel_args(one_chip, geometry, kv):
-    h, kvh, hd, mb = GEOMETRIES[geometry]
-    nb = SLOTS * mb + 1
+    h, kvh, hd, mb, slots = GEOMETRIES[geometry]
+    nb = slots * mb + 1
 
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     kv_dt = jnp.int8 if kv == "int8" else jnp.bfloat16
-    args = [s((SLOTS, h, hd), jnp.bfloat16),
+    args = [s((slots, h, hd), jnp.bfloat16),
             s((nb, BLOCK, kvh, hd), kv_dt), s((nb, BLOCK, kvh, hd), kv_dt),
-            s((SLOTS, mb), jnp.int32), s((SLOTS,), jnp.int32),
-            s((SLOTS, kvh, hd), jnp.bfloat16),
-            s((SLOTS, kvh, hd), jnp.bfloat16)]
+            s((slots, mb), jnp.int32), s((slots,), jnp.int32),
+            s((slots, kvh, hd), jnp.bfloat16),
+            s((slots, kvh, hd), jnp.bfloat16)]
     scales = ([s((nb, BLOCK), jnp.float32)] * 2 if kv == "int8"
               else [None, None])
     return args + scales

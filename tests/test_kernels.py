@@ -308,7 +308,11 @@ def _paged_case(seed, slots, H, kvh, hd, bs, mb, kv_dtype, pos_spec):
     return (q, kb, vb, tables, pos, kn, vn), kwargs
 
 
-@pytest.mark.parametrize("bs,mb", [(4, 5), (8, 3), (16, 2)])
+# (bs, mb): one grid step; then several steps of R blocks — R = 8
+# (Yi-34B's 144-block sweep has 18 such steps), R = 7, and R = 1 where mb
+# (11) has no divisor near 128 / bs
+@pytest.mark.parametrize("bs,mb", [(4, 5), (8, 3), (16, 2), (16, 16),
+                                   (16, 14), (16, 11)])
 @pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
 @pytest.mark.parametrize("pos_spec", ["ragged", "zero", "full_tail"])
 def test_paged_decode_matches_ref(bs, mb, kv_dtype, pos_spec):
@@ -332,6 +336,83 @@ def test_paged_decode_window_matches_ref(window):
     o_r = ref.paged_decode_attn_ref(*args, window=window)
     np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
                                atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("mb,bs,per_step", [
+    (144, 16, 8), (128, 16, 8), (16, 16, 8), (14, 16, 7), (7, 16, 7),
+    (11, 16, 1), (3, 64, 1), (3, 4, 3), (48, 8, 16), (64, 4, 32),
+    (2, 256, 1)])
+def test_paged_decode_blocks_per_step(mb, bs, per_step):
+    """R is the largest divisor of mb with R * bs <= 128, else 1."""
+    from repro.kernels.paged_decode_attn import blocks_per_step
+    assert blocks_per_step(mb, bs) == per_step
+
+
+def _dead_blocks_nan_case(bs, mb, kv_dtype, window):
+    """One slot per boundary position, each over blocks of its own, with
+    the trash block and every whole block outside the slot's live rows
+    ``[lo, pos)`` filled with NaN (K, V and int8 scales).  Returns the
+    poisoned and the clean problem."""
+    from repro.kernels.act_quant import kv_quant_rows
+    from repro.kernels.paged_decode_attn import blocks_per_step
+    rows = blocks_per_step(mb, bs) * bs
+    pos = sorted({0, bs - 1, bs, bs + 1, rows - 1, rows, rows + 1,
+                  mb * bs - 1} & set(range(mb * bs)))
+    slots, kvh, hd, group = len(pos), 2, 16, 2
+    rng = np.random.default_rng(bs * mb + window)
+    nb = slots * mb + 1                      # block 0 is the trash block
+    kb = rng.standard_normal((nb, bs, kvh, hd)).astype(np.float32)
+    vb = rng.standard_normal((nb, bs, kvh, hd)).astype(np.float32)
+    tables = 1 + np.arange(slots * mb, dtype=np.int32).reshape(slots, mb)
+    dead = [0]
+    for s, p in enumerate(pos):
+        lo = max(p - window + 1, 0) if window else 0
+        for c in range(mb):
+            if c * bs >= p or (c + 1) * bs <= lo:   # no live row in it
+                dead.append(tables[s, c])
+                if s % 2:                   # as the engine: the trash block
+                    tables[s, c] = 0
+    args = [jnp.asarray(rng.standard_normal((slots, kvh * group, hd)),
+                        jnp.float32)]
+    tail = [jnp.asarray(tables), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(rng.standard_normal((slots, kvh, hd)), jnp.float32),
+            jnp.asarray(rng.standard_normal((slots, kvh, hd)), jnp.float32)]
+    kw = {}
+    if kv_dtype == "int8":
+        kq, ks = map(np.asarray, kv_quant_rows(jnp.asarray(kb)))
+        vq, vs = map(np.asarray, kv_quant_rows(jnp.asarray(vb)))
+        clean = (args + [jnp.asarray(kq), jnp.asarray(vq)] + tail,
+                 dict(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs)))
+        ks, vs = ks.copy(), vs.copy()
+        ks[dead] = vs[dead] = np.nan
+        kw = dict(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
+        return (clean[0], kw), clean
+    clean = (args + [jnp.asarray(kb), jnp.asarray(vb)] + tail, kw)
+    kb, vb = kb.copy(), vb.copy()
+    kb[dead] = vb[dead] = np.nan
+    return (args + [jnp.asarray(kb), jnp.asarray(vb)] + tail, kw), clean
+
+
+@pytest.mark.parametrize("bs,mb", [(16, 16), (8, 48), (16, 14), (16, 11),
+                                   (4, 3)])
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("window", [0, 40])
+def test_paged_decode_never_reads_dead_blocks(bs, mb, kv_dtype, window):
+    """A block that holds no live row is neither fetched into the sweep
+    nor masked in it: NaN there (and in the trash block) leaves every
+    output finite and equal to the oracle on the clean pool.  Positions
+    sit at 0, around a block boundary, around a step boundary of R * bs
+    rows and at the last row; with a window the leading blocks are dead
+    too."""
+    from repro.kernels import paged_decode_attention
+    (args, kw), (clean, clean_kw) = _dead_blocks_nan_case(bs, mb, kv_dtype,
+                                                          window)
+    o_k = np.asarray(paged_decode_attention(*args, window=window,
+                                            interpret=True, **kw))
+    o_r = np.asarray(ref.paged_decode_attn_ref(*clean, window=window,
+                                               **clean_kw))
+    assert np.isfinite(o_k).all()
+    np.testing.assert_allclose(o_k, o_r, atol=2e-5, rtol=1e-4)
 
 
 def test_paged_decode_pos_zero_is_new_token_only():
