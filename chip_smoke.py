@@ -255,9 +255,9 @@ def _first_step_logits(engine: ServingEngine) -> dict:
     tokens = jnp.asarray(tokens)
     if engine.decode_mode == "paged":
         step = jax.jit(paged_kernel_decode_logits, static_argnums=(1, 6))
-        logits, _ = step(engine.params, engine.cfg, engine._cache,
-                         engine._pool, tokens,
-                         jnp.asarray(engine.block_pool.tables), engine.opts)
+        logits = step(engine.params, engine.cfg, engine._cache,
+                      engine._pool, tokens,
+                      jnp.asarray(engine.block_pool.tables), engine.opts)[0]
     else:
         cfg, opts = engine.cfg, engine.opts
         logits = jax.jit(jax.vmap(

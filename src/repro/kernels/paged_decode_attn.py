@@ -177,7 +177,7 @@ def paged_decode_attention(q: jax.Array, k_blocks: jax.Array,
                            v_new: jax.Array, *,
                            k_scale: jax.Array | None = None,
                            v_scale: jax.Array | None = None,
-                           window: int = 0,
+                           window: int = 0, scale: float | None = None,
                            interpret: bool = False) -> jax.Array:
     """Single-query GQA attention straight off the block table.
 
@@ -185,7 +185,8 @@ def paged_decode_attention(q: jax.Array, k_blocks: jax.Array,
     pool slice; tables: (slots, mb) int32; pos: (slots,) int32 tokens
     already resident; k_new/v_new: (slots, kvh, hd) — the current token's
     KV, not yet scattered.  Optional k/v_scale: (num_blocks, bs) f32
-    per-row int8 scales (pass both or neither).  Returns (slots, H, hd).
+    per-row int8 scales (pass both or neither).  ``scale``: the softmax
+    scale (default ``1/sqrt(hd)``).  Returns (slots, H, hd).
     """
     slots, h, hd = q.shape
     nb, bs, kvh, _ = k_blocks.shape
@@ -200,7 +201,8 @@ def paged_decode_attention(q: jax.Array, k_blocks: jax.Array,
     pos = pos.astype(jnp.int32)
     kernel = functools.partial(
         _paged_decode_kernel, has_scales=has_scales, block_size=bs,
-        per_step=per_step, window=window, scale=float(1.0 / np.sqrt(hd)))
+        per_step=per_step, window=window,
+        scale=float(1.0 / np.sqrt(hd) if scale is None else scale))
 
     hd_p = -(-hd // LANES) * LANES                   # whole lane tiles
 

@@ -117,7 +117,8 @@ def paged_decode_attn_ref(q: jax.Array, k_blocks: jax.Array,
                           v_new: jax.Array, *,
                           k_scale: jax.Array | None = None,
                           v_scale: jax.Array | None = None,
-                          window: int = 0) -> jax.Array:
+                          window: int = 0,
+                          scale: float | None = None) -> jax.Array:
     """Single-query GQA attention over a paged KV pool (oracle).
 
     q: (slots, H, hd); k/v_blocks: (num_blocks, bs, kvh, hd) — ONE layer's
@@ -127,13 +128,14 @@ def paged_decode_attn_ref(q: jax.Array, k_blocks: jax.Array,
     always-valid extra key (it has NOT been scattered into the pool yet).
     Optional k/v_scale: (num_blocks, bs) f32 per-row int8 scales.
     ``window`` keeps pool columns > pos - window (the new token is position
-    ``pos``, so with window w the valid set is (pos-w, pos]).
+    ``pos``, so with window w the valid set is (pos-w, pos]).  ``scale``
+    is the softmax scale (default 1/sqrt(hd)).
     Returns (slots, H, hd) in q.dtype."""
     slots, h, hd = q.shape
     nb, bs, kvh, _ = k_blocks.shape
     mb = tables.shape[1]
     g = h // kvh
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
 
     def one(qi, tbl, p, kn, vn):
         kf = k_blocks[tbl].astype(jnp.float32).reshape(mb * bs, kvh, hd)
@@ -175,3 +177,24 @@ def ssd_scan_kernel_ref(x: jax.Array, dt: jax.Array, a: jax.Array,
         return y[0, :, 0, :], st[0, 0]
 
     return jax.vmap(one)(x, dt, a, b, c)
+
+
+# ------------------------------------------------------- mamba decode ------
+def mamba_decode_step_ref(state: jax.Array, layer: jax.Array, x: jax.Array,
+                          dt: jax.Array, a: jax.Array, d: jax.Array,
+                          b: jax.Array, c: jax.Array):
+    """The Mamba-2 decode update of one layer of a slot-stacked state
+    (oracle of :mod:`repro.kernels.mamba_decode`, same layout):
+    ``s <- exp(dt A) s + B (dt x)``, ``y = sum_N(s C) + D x``.
+
+    state: (slots, L, R, N, T) f32; x, dt: (slots, R, T); a, d: (R, T);
+    b, c: (slots, N, T).  Returns ``(state with layer updated, y (slots,
+    R, T) f32)``."""
+    f32 = jnp.float32
+    s = jax.lax.dynamic_index_in_dim(state, layer, axis=1, keepdims=False)
+    x = x.astype(f32)
+    dt = dt.astype(f32)
+    decay = jnp.exp(dt * a.astype(f32))[:, :, None, :]        # (S, R, 1, T)
+    s = s * decay + b.astype(f32)[:, None] * (dt * x)[:, :, None, :]
+    y = jnp.sum(s * c.astype(f32)[:, None], axis=2) + d.astype(f32) * x
+    return jax.lax.dynamic_update_index_in_dim(state, s, layer, axis=1), y

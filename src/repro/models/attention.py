@@ -65,12 +65,14 @@ def _group(q: jax.Array, num_kv_heads: int) -> jax.Array:
 # ------------------------------------------------------------- full (oracle)
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                    causal: bool = True, window: int = 0,
-                   q_offset: int | jax.Array = 0) -> jax.Array:
-    """Reference attention.  q: (B,Sq,H,hd); k,v: (B,Sk,K,hd)."""
+                   q_offset: int | jax.Array = 0,
+                   scale: float | None = None) -> jax.Array:
+    """Reference attention.  q: (B,Sq,H,hd); k,v: (B,Sk,K,hd).  ``scale``
+    is the softmax scale (default ``1/sqrt(hd)``)."""
     b, sq, h, hd = q.shape
     kheads = k.shape[2]
     qg = _group(q, kheads)
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
     scores = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     rows = jnp.arange(sq) + q_offset
@@ -89,7 +91,8 @@ def full_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ------------------------------------------------------- chunked flash-style
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool = True, window: int = 0,
-                      q_chunk: int = 512, k_chunk: int = 1024) -> jax.Array:
+                      q_chunk: int = 512, k_chunk: int = 1024,
+                      scale: float | None = None) -> jax.Array:
     """Online-softmax attention with bounded memory.
 
     Scans over query chunks (outer) and KV chunks (inner), keeping running
@@ -102,7 +105,7 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     k_chunk = min(k_chunk, sk)
     assert sq % q_chunk == 0 and sk % k_chunk == 0, (sq, q_chunk, sk, k_chunk)
     nq, nk = sq // q_chunk, sk // k_chunk
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
 
     qr = _group(q, kheads).reshape(b, nq, q_chunk, kheads, h // kheads, hd)
     qr = jnp.moveaxis(qr, 1, 0)                        # (nq, b, cq, K, G, hd)

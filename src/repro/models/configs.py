@@ -1,8 +1,9 @@
 """Unified model configuration for every architecture family the framework serves.
 
 A single ``ModelConfig`` describes dense, MoE, SSM (Mamba2), hybrid
-(Mamba2 + shared attention), encoder-decoder (Whisper-style) and VLM
-(vision-stub + LLM) architectures.  The elastic-inference component
+(Mamba2 + shared attention), hybrid MoE (Mamba2 or attention mixers, each
+followed by a MoE FFN: Granite-4.0-H), encoder-decoder (Whisper-style)
+and VLM (vision-stub + LLM) architectures.  The elastic-inference component
 (``repro.elastic``) derives runtime variants from the same config via the
 paper's compression operators; the analytic cost helpers here feed the
 runtime performance profiler (paper Eq. 1 / Eq. 2).
@@ -21,11 +22,14 @@ LOCAL = "local_attn"   # sliding-window self-attention + FFN
 MAMBA = "mamba"        # Mamba2 (SSD) block
 SHARED_ATTN = "shared_attn"  # hybrid: shared-weight attention block (Zamba2)
 
+HYBRID_MOE = "hybrid_moe"   # arch_type: a mixer + MoE FFN a layer (Granite)
+
 
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    arch_type: str                      # dense | moe | ssm | hybrid | audio | vlm
+    # dense | moe | ssm | hybrid | hybrid_moe | audio | vlm
+    arch_type: str
     num_layers: int
     d_model: int
     num_heads: int
@@ -48,6 +52,10 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_shared_expert: bool = False     # llama4-style shared expert
     router_aux_weight: float = 0.01
+    # hybrid_moe: the shared expert's own width, and the ids of the routed
+    # experts this chip holds (the router stays ``num_experts`` wide)
+    shared_expert_d_ff: int = 0
+    experts_held: Tuple[int, ...] = ()
 
     # --- SSM (Mamba2 / SSD) -------------------------------------------------
     ssm_state_dim: int = 0
@@ -59,6 +67,15 @@ class ModelConfig:
 
     # --- hybrid (Zamba2) ----------------------------------------------------
     shared_attn_period: int = 0         # apply shared attn block every N blocks
+
+    # --- hybrid_moe (Granite-4.0-H) ----------------------------------------
+    # each layer's mixer, MAMBA or ATTN (without rotary: NoPE), in order;
+    # every layer then has a MoE FFN
+    layer_types: Tuple[str, ...] = ()
+    attn_scale: float = 0.0             # softmax scale; 0 -> 1/sqrt(head_dim)
+    embed_scale: float = 1.0            # embeddings x this
+    residual_scale: float = 1.0         # each residual branch x this
+    logits_scale: float = 1.0           # logits / this
 
     # --- encoder-decoder (Whisper) -------------------------------------------
     is_encoder_decoder: bool = False
@@ -108,6 +125,10 @@ class ModelConfig:
         return self.ssm_d_inner + 2 * self.ssm_ngroups * self.ssm_state_dim
 
     @property
+    def resolved_attn_scale(self) -> float:
+        return self.attn_scale or 1.0 / float(np.sqrt(self.resolved_head_dim))
+
+    @property
     def is_attention_free(self) -> bool:
         return self.arch_type == "ssm"
 
@@ -119,6 +140,8 @@ class ModelConfig:
 
     def block_pattern(self) -> Tuple[str, ...]:
         """Per-layer block kinds.  Homogeneous stacks collapse to one kind."""
+        if self.arch_type == HYBRID_MOE:
+            return tuple(self.layer_types)
         if self.arch_type == "ssm":
             return tuple([MAMBA] * self.num_layers)
         if self.arch_type == "hybrid":

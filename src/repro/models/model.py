@@ -13,11 +13,12 @@ import jax
 import jax.numpy as jnp
 
 from . import attention as attn_mod
+from . import hybrid_moe
 from . import moe as moe_mod
 from . import ssm as ssm_mod
 from ..kernels import ops as kernel_ops
 from ..kernels.act_quant import kv_dequant_rows, kv_quant_rows
-from .configs import ATTN, LOCAL, MAMBA, ModelConfig
+from .configs import ATTN, HYBRID_MOE, LOCAL, MAMBA, ModelConfig
 from .layers import (Params, dtype_of, embed_lookup, ffn_apply, matmul_w,
                      rms_norm, unembed)
 from .runtime import DEFAULT_OPTIONS, RuntimeOptions
@@ -36,6 +37,8 @@ __all__ = ["init_params", "forward", "lm_loss", "init_cache", "prefill",
 
 
 def _n_attn_layers(cfg: ModelConfig) -> int:
+    if cfg.arch_type == HYBRID_MOE:
+        return hybrid_moe.n_layers_of(cfg, ATTN)
     if cfg.arch_type in ("ssm", "hybrid"):
         return 0
     return cfg.num_layers
@@ -49,6 +52,9 @@ def _n_shared_sites(cfg: ModelConfig) -> int:
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
                opts: RuntimeOptions = DEFAULT_OPTIONS) -> Cache:
+    if cfg.arch_type == HYBRID_MOE:
+        raise ValueError("a hybrid_moe stack is served on the paged path "
+                         "only (decode_mode='paged', paged_kernel=True)")
     kv_dt = dtype_of(opts.kv_cache_dtype)
     hd = cfg.resolved_head_dim
     cache: Cache = {"pos": jnp.zeros((), jnp.int32)}
@@ -91,10 +97,15 @@ def init_slot_cache(cfg: ModelConfig, slots: int, max_seq: int,
     one = init_cache(cfg, 1, max_seq, opts)
     stacked = jax.tree_util.tree_map(
         lambda a: jnp.zeros((slots,) + a.shape, a.dtype), one)
-    stacked["sample"] = {"key": jnp.zeros((slots, 2), jnp.uint32),
-                         "temp": jnp.zeros((slots,), jnp.float32),
-                         "top_k": jnp.zeros((slots,), jnp.int32)}
+    stacked["sample"] = _init_sample_state(slots)
     return stacked
+
+
+def _init_sample_state(slots: int) -> Cache:
+    """The greedy zero of a slot cache's ``"sample"`` subtree."""
+    return {"key": jnp.zeros((slots, 2), jnp.uint32),
+            "temp": jnp.zeros((slots,), jnp.float32),
+            "top_k": jnp.zeros((slots,), jnp.int32)}
 
 
 def write_cache_slot(stacked: Cache, cache: Cache, slot: jax.Array) -> Cache:
@@ -308,7 +319,12 @@ def init_paged_slot_cache(cfg: ModelConfig, slots: int, max_seq: int,
                           opts: RuntimeOptions = DEFAULT_OPTIONS) -> Cache:
     """A slot-stacked serving cache *without* the dense ``k``/``v``
     leaves (those live in the block pool); everything else — ``pos``,
-    the ``"sample"`` subtree, cross-attention KV — stays per-slot."""
+    the ``"sample"`` subtree, cross-attention KV, a hybrid MoE stack's
+    recurrent ``ssm``/``conv`` state — stays per-slot."""
+    if cfg.arch_type == HYBRID_MOE:
+        return {"pos": jnp.zeros((slots,), jnp.int32),
+                "sample": _init_sample_state(slots),
+                **hybrid_moe.init_state(cfg, slots)}
     stacked = init_slot_cache(cfg, slots, max_seq, opts)
     return {k: v for k, v in stacked.items() if k not in ("k", "v")}
 
@@ -332,6 +348,9 @@ def paged_sample_batched_step(params: Params, cfg: ModelConfig,
     An int8 pool (``opts.kv_dtype == "int8"``) dequantizes per row while
     gathering and re-quantizes the newly written row before the scatter —
     the dense computation in the middle is unchanged."""
+    if cfg.arch_type == HYBRID_MOE:
+        raise ValueError("a hybrid_moe stack decodes through the kernel "
+                         "step only (paged_kernel=True)")
     pk, pv = pool["k"], pool["v"]
     psk, psv = pool.get("k_scale"), pool.get("v_scale")
     _, n_attn, bs, kvh, hd = pk.shape
@@ -436,13 +455,13 @@ def paged_kernel_sample_batched_step(params: Params, cfg: ModelConfig,
     Drop-in twin of :func:`paged_sample_batched_step` (same signature,
     same return contract) selected by ``opts.paged_kernel``:
     :func:`paged_kernel_decode_logits`, then per-slot sampling."""
-    logits, new_pool = paged_kernel_decode_logits(params, cfg, slot_cache,
-                                                  pool, tokens, tables, opts)
+    logits, new_pool, new_state = paged_kernel_decode_logits(
+        params, cfg, slot_cache, pool, tokens, tables, opts)
     s = slot_cache["sample"]
     nxt, new_keys = jax.vmap(
         lambda lg, ky, t, tk: sample_logits(lg, ky, t, tk, cfg.vocab_size)
     )(logits, s["key"], s["temp"], s["top_k"])
-    new_cache = dict(slot_cache)
+    new_cache = dict(slot_cache, **new_state)
     new_cache["sample"] = {"key": new_keys, "temp": s["temp"],
                            "top_k": s["top_k"]}
     new_cache["pos"] = slot_cache["pos"] + 1
@@ -454,8 +473,10 @@ def paged_kernel_decode_logits(params: Params, cfg: ModelConfig,
                                tokens: jax.Array, tables: jax.Array,
                                opts: RuntimeOptions = DEFAULT_OPTIONS):
     """The forward half of :func:`paged_kernel_sample_batched_step`:
-    ``(slots, vocab)`` logits for each slot's next token, and the pool
-    with every slot's new KV row scattered into its tail block.
+    ``(slots, vocab)`` logits for each slot's next token, the pool with
+    every slot's new KV row scattered into its tail block, and the slot
+    leaves the step updated besides (a hybrid MoE stack's recurrent
+    state; ``{}`` for an attention stack).
 
     Instead of materializing a dense ``(mb * bs)`` view per slot, every
     layer's attention reads its pool blocks *through the block table* via
@@ -473,10 +494,17 @@ def paged_kernel_decode_logits(params: Params, cfg: ModelConfig,
     would make it free."""
     from .layers import (cast_params, mask_padded_logits_raw,
                          rotary_embedding)
+    pos = slot_cache["pos"]                                   # (slots,)
+    bs = pool["k"].shape[2]
+    tables = tables.astype(jnp.int32)
+    blks = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
+    if cfg.arch_type == HYBRID_MOE:
+        logits, rk, rv, state = hybrid_moe.paged_decode(
+            params, cfg, slot_cache, pool, tokens, tables, opts)
+        return logits, _scatter_kv_rows(pool, rk, rv, blks, pos % bs), state
     act_dt = dtype_of(cfg.activation_dtype)
     params = cast_params(params, act_dt)
     x = embed_lookup(params["embed"], tokens).astype(act_dt)  # (slots, D)
-    pos = slot_cache["pos"]                                   # (slots,)
     pk, pv = pool["k"], pool["v"]
     _, n_attn, bs, kvh, hd = pk.shape
     has_scales = "k_scale" in pool
@@ -485,7 +513,6 @@ def paged_kernel_decode_logits(params: Params, cfg: ModelConfig,
     ks_l = jnp.moveaxis(pool["k_scale"], 1, 0) if has_scales else None
     vs_l = jnp.moveaxis(pool["v_scale"], 1, 0) if has_scales else None
     sin, cos = rotary_embedding(pos[:, None], hd, cfg.rope_theta)
-    tables = tables.astype(jnp.int32)
 
     kinds, _ = _pattern_period(cfg)
     period = len(kinds)
@@ -560,9 +587,7 @@ def paged_kernel_decode_logits(params: Params, cfg: ModelConfig,
     # block (same collision-freedom argument as the gather step)
     rk = jnp.moveaxis(row_k, 0, 1)                  # (slots, n_attn, kvh, hd)
     rv = jnp.moveaxis(row_v, 0, 1)
-    blks = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
-    offs = pos % bs
-    return logits, _scatter_kv_rows(pool, rk, rv, blks, offs)
+    return logits, _scatter_kv_rows(pool, rk, rv, blks, pos % bs), {}
 
 
 def paged_prefill_admit(params: Params, cfg: ModelConfig, slot_cache: Cache,
@@ -580,8 +605,29 @@ def paged_prefill_admit(params: Params, cfg: ModelConfig, slot_cache: Cache,
     k, bucket = tokens.shape
     _, n_attn, bs, kvh, hd = pool["k"].shape
     nblk = bucket // bs
-    cache = init_cache(cfg, k, bucket, opts)
-    logits, cache = prefill(params, cfg, tokens, cache, opts)
+    if cfg.arch_type == HYBRID_MOE:
+        logits, kv_k, kv_v, state = hybrid_moe.prefill(params, cfg, tokens,
+                                                       opts)
+        # each row's recurrent state goes to its slot whole
+        rows = [{"pos": jnp.int32(bucket),
+                 **{name: a[i] for name, a in state.items()}}
+                for i in range(k)]
+    else:
+        cache = init_cache(cfg, k, bucket, opts)
+        logits, cache = prefill(params, cfg, tokens, cache, opts)
+        kv_k, kv_v = cache["k"], cache["v"]
+        model_side = {key: v for key, v in slot_cache.items()
+                      if key != "sample"}
+        row_src = {key: v for key, v in cache.items() if key not in ("k", "v")}
+        rows = []
+        for i in range(k):
+            row = jax.tree_util.tree_map(
+                lambda a, i=i: a if a.ndim == 0 else
+                jax.lax.slice_in_dim(a, i, i + 1, axis=1), row_src)
+            rows.append(jax.tree_util.tree_map(
+                lambda s, c: c if c.ndim == 0 else jnp.pad(
+                    c, [(0, t - n) for t, n in zip(s.shape[1:], c.shape)]),
+                model_side, row))
     last = logits[:, -1]
     first, new_keys = jax.vmap(
         lambda lg, ky, t, tk: sample_logits(lg, ky, t, tk, cfg.vocab_size)
@@ -593,7 +639,7 @@ def paged_prefill_admit(params: Params, cfg: ModelConfig, slot_cache: Cache,
 
     flat = dest_blocks.reshape(-1)
     new_pool = dict(pool)
-    bk, bv = blockify(cache["k"]), blockify(cache["v"])
+    bk, bv = blockify(kv_k), blockify(kv_v)
     if "k_scale" in pool:                # quantize at append time
         bk, sk = kv_quant_rows(bk)
         bv, sv = kv_quant_rows(bv)
@@ -602,16 +648,7 @@ def paged_prefill_admit(params: Params, cfg: ModelConfig, slot_cache: Cache,
     new_pool["k"] = pool["k"].at[flat].set(bk.astype(pool["k"].dtype))
     new_pool["v"] = pool["v"].at[flat].set(bv.astype(pool["v"].dtype))
     out = slot_cache
-    model_side = {key: v for key, v in slot_cache.items() if key != "sample"}
-    row_src = {key: v for key, v in cache.items() if key not in ("k", "v")}
-    for i in range(k):
-        row = jax.tree_util.tree_map(
-            lambda a, i=i: a if a.ndim == 0 else
-            jax.lax.slice_in_dim(a, i, i + 1, axis=1), row_src)
-        row = jax.tree_util.tree_map(
-            lambda s, c: c if c.ndim == 0 else jnp.pad(
-                c, [(0, t - n) for t, n in zip(s.shape[1:], c.shape)]),
-            model_side, row)
+    for i, row in enumerate(rows):
         out = admit_slot(out, row, slot_ids[i], new_keys[i], temps[i],
                          top_ks[i])
     return first, last, out, new_pool
@@ -894,7 +931,8 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
             for j in range(period):
                 layer = jax.tree_util.tree_map(lambda a: a[j], layer_pp)
                 h = rms_norm(x, layer["ln"], cfg.norm_eps)
-                y, st, cv = _mamba_prefill_states(layer["mamba"], h, cfg)
+                y, st, cv = ssm_mod.mamba_prefill_states(layer["mamba"], h,
+                                                         cfg)
                 x = x + y.astype(x.dtype)
                 sts.append(st)
                 cvs.append(cv.astype(kv_dt))
@@ -926,7 +964,7 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
         for j in range(n_full, n):
             layer = jax.tree_util.tree_map(lambda a: a[j], params["layers"])
             h = rms_norm(x, layer["ln"], cfg.norm_eps)
-            y, st, cv = _mamba_prefill_states(layer["mamba"], h, cfg)
+            y, st, cv = ssm_mod.mamba_prefill_states(layer["mamba"], h, cfg)
             x = x + y.astype(x.dtype)
             new_cache["ssm"] = new_cache["ssm"].at[j].set(st)
             new_cache["conv"] = new_cache["conv"].at[j].set(cv.astype(kv_dt))
@@ -996,29 +1034,3 @@ def _attn_prefill_kv(layer, x, cfg, opts, window: int = 0, cross_src=None):
     x, _ = transformer_block(layer, x, cfg, opts, window=window,
                              causal=True, cross_src=cross_src)
     return x, k_rot, v
-
-
-def _mamba_prefill_states(mp, h, cfg):
-    """Mamba block forward that also returns (final ssm state, conv state)."""
-    bsz, s, _ = h.shape
-    di, nh, hdim = cfg.ssm_d_inner, cfg.ssm_num_heads, cfg.ssm_head_dim
-    gr, st = cfg.ssm_ngroups, cfg.ssm_state_dim
-    from .layers import causal_conv1d, gated_rms_norm
-    proj = h @ mp["in_proj"]
-    z = proj[..., :di]
-    xbc_pre = proj[..., di:di + cfg.ssm_conv_dim]
-    dt = proj[..., di + cfg.ssm_conv_dim:]
-    conv_state = xbc_pre[:, -(cfg.ssm_conv_width - 1):, :]
-    xbc = jax.nn.silu(causal_conv1d(xbc_pre, mp["conv_w"], mp["conv_b"])
-                      .astype(jnp.float32)).astype(h.dtype)
-    xs = xbc[..., :di].reshape(bsz, s, nh, hdim)
-    bmat = xbc[..., di:di + gr * st].reshape(bsz, s, gr, st)
-    cmat = xbc[..., di + gr * st:].reshape(bsz, s, gr, st)
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"])
-    a = -jnp.exp(mp["a_log"])
-    y, final_state = ssm_mod.ssd_scan_ref(xs, dt, a, bmat, cmat,
-                                          chunk=cfg.ssm_chunk)
-    y = y + mp["d_skip"][None, None, :, None] * xs
-    y = y.reshape(bsz, s, di)
-    y = gated_rms_norm(y, z, mp["norm_scale"], cfg.norm_eps)
-    return y @ mp["out_proj"], final_state, conv_state

@@ -6,6 +6,10 @@ Expert weights are stacked (E, D, F) and sharded over the "model" mesh axis
 (expert parallelism); the gather/scatter pair is what SPMD lowers to the
 all-to-all exchange.  Compute cost is k·T·FFN (capacity factor 1.0), not
 E·T·FFN — tokens beyond capacity are dropped Switch-style.
+
+:func:`moe_share_apply` is the served layer of a hybrid MoE stack: told
+which experts this chip holds, it routes over all of them and computes
+its own experts' part, dropping no token.
 """
 from __future__ import annotations
 
@@ -122,3 +126,33 @@ def moe_apply_decode(params: Params, x: jax.Array, cfg: ModelConfig
         y = y + ffn_apply(params["shared"], x, gated=cfg.gated_ffn,
                           activation=cfg.activation)
     return y.astype(x.dtype)
+
+
+def moe_share_apply(params: Params, x: jax.Array, cfg: ModelConfig
+                    ) -> jax.Array:
+    """The routed part of a MoE layer that holds only some of its experts
+    (expert parallelism, one chip's share), with no token dropped.
+
+    x: (T, D).  The router keeps its published width: ``params["router"]``
+    is ``(D, cfg.num_experts)``, each token takes the top
+    ``experts_per_token`` logits and a softmax over those (GraniteMoe's
+    gating).  ``params["w_gate" | "w_up" | "w_down"]`` stack the experts
+    this chip holds, ``cfg.experts_held`` in order, and each computes
+    every token with that token's gate for it (0 where the token did not
+    route to it), so the result is exactly what these experts add; the
+    other chips' experts add the rest.  Dense over the held experts, as
+    :func:`moe_apply_decode`: at decode the weights bound the step, and a
+    prefill drops nothing.  The caller adds any shared expert once."""
+    k = cfg.experts_per_token
+    logits = x.astype(jnp.float32) @ params["router"].astype(jnp.float32)
+    top_v, top_i = jax.lax.top_k(logits, k)                       # (T, k)
+    gates = jax.nn.softmax(top_v, axis=-1)
+    held = jnp.asarray(cfg.experts_held, jnp.int32)               # (E_h,)
+    g = jnp.sum(jnp.where(top_i[:, :, None] == held[None, None, :],
+                          gates[:, :, None], 0.0), axis=1)        # (T, E_h)
+    act = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[cfg.activation]
+    hg = jnp.einsum("td,edf->tef", x, params["w_gate"])
+    hu = jnp.einsum("td,edf->tef", x, params["w_up"])
+    h = (act(hg.astype(jnp.float32)) * hu.astype(jnp.float32)
+         * g[:, :, None]).astype(x.dtype)
+    return jnp.einsum("tef,efd->td", h, params["w_down"])

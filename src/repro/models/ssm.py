@@ -190,6 +190,61 @@ def mamba_step(params: Params, x_t: jax.Array, ssm_state: jax.Array,
     return y @ params["out_proj"], ssm_state, conv_state
 
 
+def mamba_prefill_states(mp: Params, h: jax.Array, cfg: ModelConfig):
+    """Mamba block forward over a prompt that also returns its final SSM
+    state ``(B, H, P, N)`` f32 and conv state ``(B, W-1, conv_dim)`` (the
+    last ``W-1`` pre-conv inputs), in ``h.dtype``."""
+    bsz, s, _ = h.shape
+    di, nh, hdim = cfg.ssm_d_inner, cfg.ssm_num_heads, cfg.ssm_head_dim
+    gr, st = cfg.ssm_ngroups, cfg.ssm_state_dim
+    proj = h @ mp["in_proj"]
+    z, xbc_pre, dt = _split_in_proj(cfg, proj)
+    conv_state = xbc_pre[:, -(cfg.ssm_conv_width - 1):, :]
+    xbc = jax.nn.silu(causal_conv1d(xbc_pre, mp["conv_w"], mp["conv_b"])
+                      .astype(jnp.float32)).astype(h.dtype)
+    xs = xbc[..., :di].reshape(bsz, s, nh, hdim)
+    bmat = xbc[..., di:di + gr * st].reshape(bsz, s, gr, st)
+    cmat = xbc[..., di + gr * st:].reshape(bsz, s, gr, st)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"])
+    a = -jnp.exp(mp["a_log"])
+    y, final_state = ssd_scan_ref(xs, dt, a, bmat, cmat, chunk=cfg.ssm_chunk)
+    y = y + mp["d_skip"][None, None, :, None] * xs
+    y = y.reshape(bsz, s, di)
+    y = gated_rms_norm(y, z, mp["norm_scale"], cfg.norm_eps)
+    return y @ mp["out_proj"], final_state, conv_state
+
+
+# ------------------------------------------------ paged (row) state layout --
+# The paged serving path keeps each slot's SSM state as float32 rows of T
+# lanes, ``(R, N, T)`` per layer (``repro.kernels.mamba_decode``): row r
+# holds heads ``r*T//P .. (r+1)*T//P - 1``, its lanes their P channels.
+
+def state_rows(cfg: ModelConfig) -> Tuple[int, int]:
+    """``(R, T)`` of the row layout: ``T = min(128, H*P)`` lanes a row."""
+    width = cfg.ssm_num_heads * cfg.ssm_head_dim
+    t = min(128, width)
+    if t % cfg.ssm_head_dim or width % t or cfg.ssm_ngroups != 1:
+        raise ValueError(
+            f"paged SSM state needs one group and heads that tile rows of "
+            f"{t} lanes (heads {cfg.ssm_num_heads} x {cfg.ssm_head_dim}, "
+            f"groups {cfg.ssm_ngroups})")
+    return width // t, t
+
+
+def heads_to_rows(state: jax.Array, t: int) -> jax.Array:
+    """``(..., H, P, N)`` -> ``(..., H*P//T, N, T)``."""
+    *lead, h, p, n = state.shape
+    a = state.reshape(*lead, h * p // t, t // p, p, n)
+    a = jnp.moveaxis(a, -1, -3)                       # (..., R, N, T//P, P)
+    return a.reshape(*lead, h * p // t, n, t)
+
+
+def per_lane(v: jax.Array, head_dim: int) -> jax.Array:
+    """A per-head ``(..., H)`` value repeated over each head's channels:
+    ``(..., H * head_dim)``."""
+    return jnp.repeat(v, head_dim, axis=-1)
+
+
 def mamba_state_shapes(cfg: ModelConfig, batch: int):
     return (
         (batch, cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_dim),

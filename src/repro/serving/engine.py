@@ -38,6 +38,13 @@ Two decode paths share the scheduler:
   blocks, no gather-to-dense detour — and ``kv_dtype="int8"`` stores
   the pool int8 with per-row scales (~4x resident slots per device;
   greedy streams match the f32 pool on the differential corpus).
+  A hybrid MoE stack (``arch_type="hybrid_moe"``, Mamba-2 and attention
+  mixers) serves on this path only: its attention K/V live in the pool
+  and its Mamba layers' recurrent state in per-slot leaves beside it,
+  written by admission, carried by freeze/thaw and swaps, and updated in
+  place by the decode step.  The blocks cannot rebuild that state, so a
+  prefix-cache hit on such a stack is refused and prefilled
+  (``engine.prefix_refused_recurrent`` counts it).
 
 Any non-``per_slot`` engine can **freeze** an in-flight request into a
 host-side :class:`~repro.serving.paging.FrozenRequest` blob (pages
@@ -58,7 +65,12 @@ are bucketed (powers of two capped at the slot count, short bursts padded
 with throwaway rows), so mixed burst sizes reuse a handful of programs.
 ``prefill_mode="per_request"`` keeps the sequential reference admission
 (one prefill jit per request), which the property suite pins the batched
-path against.
+path against.  While slots decode, batched admission is *staged*: each
+prefill call stalls every decoding slot's next token, so a tick prefills
+one cohort — the requests the last admission passed over, all of them,
+or else the head's bucket among newer arrivals, whose other buckets wait
+one tick.  No request waits twice, and no burst mixes the two cohorts,
+so none is larger than what arrived between two admissions.
 
 Compiled programs come from a :class:`CompileCache` shared across engines
 (process-global by default), so a fleet of same-platform engines compiles
@@ -69,6 +81,7 @@ heterogeneous per-slot policies still share every program.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import time
 from collections import deque
@@ -81,6 +94,7 @@ import numpy as np
 
 from repro.kernels.act_quant import kv_dequant_rows
 from repro.models.configs import ModelConfig
+from repro.models.hybrid_moe import STATE_LEAVES
 from repro.models.layers import Params, dtype_of
 from repro.models.model import (init_cache, init_paged_pool,
                                 init_paged_slot_cache, init_slot_cache)
@@ -323,6 +337,11 @@ class ServingEngine:
         self._ewma = self.metrics.ewma("engine.step_time_s", alpha=0.2)
         self._backend_compiles = self.metrics.counter(
             "engine.backend_compiles")
+        # paged recurrent stacks: prefix hits refused (the blocks cannot
+        # rebuild the state), and the state bytes the live slots hold
+        self._prefix_refused = self.metrics.counter(
+            "engine.prefix_refused_recurrent")
+        self._state_gauge = self.metrics.gauge("engine.state_bytes")
         self._queue: Deque[Request] = deque()
         self._active: List[Optional[Request]] = [None] * slots
         self.generation = 0
@@ -345,6 +364,10 @@ class ServingEngine:
         # untouched unless a fault is actually injected.
         self._oom_pending = 0
         self._admit_holdoff = 0
+        # staged admission (``_admit``): ids of the requests the last
+        # admission passed over, and this tick's cohort
+        self._passed: set = set()
+        self._staged = self._carried = False
         self._oom_backoff = 0
         self.oom_backoff_cap = 8
 
@@ -354,6 +377,13 @@ class ServingEngine:
         :meth:`repro.obs.TraceRecorder.span`)."""
         return self.recorder.span(name, pid=self.pid, tid="engine",
                                   args=args)
+
+    def _state_span(self):
+        """``engine.state`` around host-side handling of a paged stack's
+        recurrent state (admission, freeze, thaw); nothing elsewhere."""
+        if not self._state_leaves:
+            return contextlib.nullcontext()
+        return self._span("engine.state")
 
     # ------------------------------------------------------------ programs --
     def _note_compile(self, what: str, **detail) -> None:
@@ -419,12 +449,19 @@ class ServingEngine:
         return fn
 
     def _reset_caches(self) -> None:
+        self._state_leaves, self._state_slot_bytes = (), 0
         if self.decode_mode == "batched":
             self._cache = init_slot_cache(self.cfg, self.slots, self.max_seq,
                                           self.opts)
         elif self.decode_mode == "paged":
             self._cache = init_paged_slot_cache(self.cfg, self.slots,
                                                 self.max_seq, self.opts)
+            # recurrent state beside the pool (hybrid_moe): per-slot leaves
+            self._state_leaves = tuple(n for n in STATE_LEAVES
+                                       if n in self._cache)
+            self._state_slot_bytes = sum(
+                self._cache[n].nbytes // self.slots
+                for n in self._state_leaves)
             self._pool = init_paged_pool(self.cfg, self.pool_blocks,
                                          self.block_size, self.opts)
             self._blocks = BlockPool(self.slots, self.pool_blocks,
@@ -492,9 +529,11 @@ class ServingEngine:
         anything behind it — later same-bucket arrivals can share its
         burst's free slots but never displace it.  Budget-spent requests
         encountered on the way complete inline; passed-over requests keep
-        their relative order at the queue head.  Returns ``(bucket,
-        requests)``."""
+        their relative order at the queue head.  While admission is
+        staged (:meth:`_admit`) a burst takes only waiters of the head's
+        cohort.  Returns ``(bucket, requests)``."""
         head = self._queue.popleft()
+        cohort = self._in_cohort(head)
         bucket = self._bucket(len(head.prompt))
         batch = [head]
         if limit > 1:
@@ -510,7 +549,8 @@ class ServingEngine:
                     # its generated suffix from the bucket computation
                     kept.append(r)
                     continue
-                if self._bucket(len(r.prompt)) == bucket:
+                if self._bucket(len(r.prompt)) == bucket \
+                        and self._in_cohort(r) == cohort:
                     batch.append(r)
                 else:
                     kept.append(r)
@@ -633,7 +673,16 @@ class ServingEngine:
                                            salt=self.params_version))
                 self._slot_pos[slot] = bucket
                 self._slot_seq[slot] = next(self._admit_seq)
-                if self.prefix_entries > 0:
+                if self.prefix_entries > 0 and self._state_leaves:
+                    # the state is not kept with the prefix: the entry
+                    # only marks the prompt seen, holding no blocks
+                    with self._state_span():
+                        self._prefix.insert(
+                            self._prefix.key_of(padded, self.params_version),
+                            PrefixEntry(block_ids=(), logits_row=None,
+                                        leaves={}, pos=bucket),
+                            self._blocks)
+                elif self.prefix_entries > 0:
                     self._prefix.insert(
                         self._prefix.key_of(padded, self.params_version),
                         PrefixEntry(
@@ -760,9 +809,25 @@ class ServingEngine:
                     args={"backoff_steps": self._admit_holdoff,
                           "queued": len(self._queue)})
             return
+        # Staged admission.  While slots decode, every prefill call stalls
+        # each of their next tokens, so a tick prefills one cohort: the
+        # requests the last admission passed over (all of them, so none
+        # waits twice), or else the head's bucket among the rest, whose
+        # other buckets wait a tick.  A burst never mixes the cohorts, so
+        # none is larger than what arrived between two admissions.
+        self._staged = self.prefill_mode == "batched" and \
+            len(free) < self.slots
+        self._carried = self._staged and any(
+            id(r) in self._passed for r in self._queue)
+        calls = self.stats.prefill_calls
         admitted = False
         while free and self._queue:
             head = self._queue[0]
+            if self._staged and (
+                    not self._in_cohort(head)
+                    or (not self._carried
+                        and self.stats.prefill_calls > calls)):
+                break               # the rest wait for the next tick
             if len(head.generated) >= head.max_new_tokens:
                 # re-queued after a swap with its budget already spent (or
                 # submitted with max_new_tokens=0): emitting another prefill
@@ -797,8 +862,15 @@ class ServingEngine:
                 self._queue.popleft()
                 self._admit_one(head, free)
             admitted = True
+        self._passed = {id(r) for r in self._queue}
+        self._staged = False
         if admitted:
             self._oom_backoff = 0     # a successful admission heals
+
+    def _in_cohort(self, req: Request) -> bool:
+        """Whether staged admission may prefill ``req`` this tick (every
+        request, where admission is not staged)."""
+        return not self._staged or (id(req) in self._passed) == self._carried
 
     def _admit_paged_head(self, head: Request, free: List[int]) -> bool:
         """Admit the head request (plus any same-bucket burst) into the
@@ -810,7 +882,11 @@ class ServingEngine:
         entry = self._prefix.lookup(
             self._prefix.key_of(self._padded_prompt(head, bucket),
                                 self.params_version))
-        if entry is not None:
+        if entry is not None and self._state_leaves:
+            # the blocks hold the attention layers' KV, not the recurrent
+            # state the prompt left: refuse the reuse, prefill instead
+            self._prefix_refused.inc()
+        elif entry is not None:
             self._queue.popleft()
             self._admit_from_prefix(head, entry, free)
             return True
@@ -1020,6 +1096,9 @@ class ServingEngine:
                 self.stats.steps += 1
                 self.stats.tokens_out += emitted
                 dt = time.perf_counter() - t0
+            if self._state_slot_bytes:
+                self._state_gauge.set(self._state_slot_bytes * sum(
+                    r is not None for r in self._active))
             self.step_times.append(dt)
             self._ewma.update(dt)
             if self.slo is not None and emitted:
@@ -1095,9 +1174,10 @@ class ServingEngine:
             else:
                 pos = (self._slot_pos[slot] if self.decode_mode == "paged"
                        else int(jax.device_get(self._cache["pos"][slot])))
-                leaves = {name: np.asarray(jax.device_get(leaf[slot]))
-                          for name, leaf in self._cache.items()
-                          if name != "sample"}
+                with self._state_span():
+                    leaves = {name: np.asarray(jax.device_get(leaf[slot]))
+                              for name, leaf in self._cache.items()
+                              if name != "sample"}
                 sample = {name: np.asarray(jax.device_get(arr[slot]))
                           for name, arr in self._cache["sample"].items()}
             for name in _SEQ_TRIM_LEAVES:
@@ -1224,7 +1304,7 @@ class ServingEngine:
             self._slot_pos[slot] = fz.pos
             self._slot_seq[slot] = next(self._admit_seq)
         # the blob's uploads and the programs that write them into place
-        with self._span("engine.wait"):
+        with self._span("engine.wait"), self._state_span():
             self._upload_frozen(fz, slot, ids)
         req.frozen = None
         self._active[slot] = req

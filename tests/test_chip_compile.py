@@ -14,6 +14,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
+from repro.kernels import mamba_decode
 from repro.kernels.paged_decode_attn import (KERNEL_NAME,
                                              paged_decode_attention)
 from repro.models.model import (init_paged_pool, init_paged_slot_cache,
@@ -118,3 +119,67 @@ def test_paged_decode_step_compiles(one_chip, kv_dtype):
     # stable names: the step's module, and the kernel inside it
     assert "jit_paged_decode" in text
     assert KERNEL_NAME in text
+
+
+# Granite-4.0-H-Small as the chip benchmark serves it: the last stage's 10
+# layers (m m m m m A m m m m) at full width, 9 of 72 experts, 128 slots
+# of 2304 tokens; the estimate must leave room in the chip's 16 GB
+GRANITE_SLOTS, GRANITE_SEQ = 128, 2304
+CHIP_BYTES = 14e9
+
+
+def test_mamba_decode_kernel_compiles(one_chip):
+    """The state update at the cell's shape: 128 slots x 9 layers of 64
+    rows of (128 x 128) float32, the state aliased in place."""
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    slots, layers, rows, n, t = GRANITE_SLOTS, 9, 64, 128, 128
+    compiled = jax.jit(mamba_decode.mamba_decode_step,
+                       donate_argnums=(0,)).lower(
+        s((slots, layers, rows, n, t), jnp.float32), s((), jnp.int32),
+        s((slots, rows, t), jnp.bfloat16), s((slots, rows, t), jnp.float32),
+        s((rows, t), jnp.float32), s((rows, t), jnp.float32),
+        s((slots, n, t), jnp.float32),
+        s((slots, n, t), jnp.float32)).compile()
+    assert mamba_decode.KERNEL_NAME in compiled.as_text()
+    # the state is the output's storage: nothing state-sized besides it
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e8
+
+
+def test_granite_paged_decode_step_compiles(one_chip):
+    """The engine's paged decode program for the Granite cell: both
+    kernels by name in the compiled text, and the compiler's estimate of
+    what it holds (weights, pool, slot state, temporaries) under 14 GB."""
+    import json
+    from pathlib import Path
+
+    import granite_tiny
+    conf = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                       / "chip" / "configs"
+                       / "granite-4.0-h-small-10l.json").read_text())
+    fam = granite_tiny.family()
+    cfg = fam.model_config(conf)
+    opts = DEFAULT_OPTIONS.replace(paged_kernel=True)
+    nb = GRANITE_SLOTS * (GRANITE_SEQ // BLOCK) + 1
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: fam.make_params(conf, 0)))
+    cache = on_chip(jax.eval_shape(lambda: init_paged_slot_cache(
+        cfg, GRANITE_SLOTS, GRANITE_SEQ, opts)))
+    pool = on_chip(jax.eval_shape(lambda: init_paged_pool(cfg, nb, BLOCK,
+                                                          opts)))
+    tokens = jax.ShapeDtypeStruct((GRANITE_SLOTS,), jnp.int32,
+                                  sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((GRANITE_SLOTS, GRANITE_SEQ // BLOCK),
+                                  jnp.int32, sharding=one_chip)
+    step, _ = ServePrograms(cfg, opts, GRANITE_SEQ).paged_decode(nb, BLOCK)
+    compiled = step.lower(params, cache, pool, tokens, tables).compile()
+    text = compiled.as_text()
+    assert KERNEL_NAME in text and mamba_decode.KERNEL_NAME in text
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < CHIP_BYTES

@@ -4,8 +4,9 @@ slot recycling and mid-decode variant swaps; the donated stacked cache
 must never be reused; engines sharing a CompileCache must not recompile
 shared programs (even with heterogeneous per-slot sampling and
 mixed-size admission bursts); a burst of k same-bucket requests costs
-exactly ONE prefill jit call; and batched admission never starves an
-earlier waiter from another bucket."""
+exactly ONE prefill jit call; batched admission never starves an
+earlier waiter from another bucket; and while slots decode, a tick runs
+one prefill call."""
 from collections import deque
 
 import jax
@@ -276,6 +277,44 @@ def test_earlier_cross_bucket_waiter_is_not_starved():
     assert other.done
     assert other.first_token_s is not None
     assert all(other.first_token_s < r.first_token_s for r in late)
+
+
+@pytest.mark.parametrize("decode_mode", ["batched", "paged"])
+def test_staged_admission_prefills_one_cohort_per_tick(decode_mode):
+    """While slots decode, a tick runs one prefill call: the head's
+    bucket among the new arrivals, the other buckets a tick later, ahead
+    of anything newer and never in one burst with it.  An idle engine
+    admits every bucket at once."""
+    rng = np.random.default_rng(29)
+
+    def req(rid, n, new=3):
+        return Request(rid=rid, prompt=rng.integers(
+            0, CFG.vocab_size, size=n).astype(np.int32), max_new_tokens=new)
+
+    kw = {"block_size": 8} if decode_mode == "paged" else {}
+    eng = ServingEngine(CFG, PARAMS, slots=6, max_seq=64,
+                        decode_mode=decode_mode, compile_cache=CC, **kw)
+    idle = [req(0, 6, new=12), req(1, 20, new=12)]     # buckets 16, 32
+    for r in idle:
+        eng.submit(r)
+    eng.step()
+    assert eng.stats.prefill_calls == 2 and all(r.generated for r in idle)
+
+    a, b, c = req(10, 5), req(11, 30), req(12, 9)        # 16, 32, 16
+    for r in (a, b, c):
+        eng.submit(r)
+    eng.step()
+    assert eng.stats.prefill_calls == 3                   # a and c together
+    assert a.generated and c.generated and not b.generated
+    d = req(13, 25)                                       # 32, like b
+    eng.submit(d)
+    eng.step()
+    assert eng.stats.prefill_calls == 4                   # b, without d
+    assert b.generated and not d.generated
+    eng.step()
+    assert eng.stats.prefill_calls == 5 and d.generated
+    eng.drain()
+    assert all(r.done for r in idle + [a, b, c, d])
 
 
 # -------------------------------------------------------------- scheduler --
