@@ -29,8 +29,10 @@ Cache = Dict[str, Any]
 
 __all__ = ["init_params", "forward", "lm_loss", "init_cache", "prefill",
            "decode_step", "Cache", "init_slot_cache", "write_cache_slot",
-           "greedy_batched_step", "sample_logits", "sample_step",
-           "sample_batched_step", "admit_slot", "batched_prefill_admit",
+           "greedy_batched_step", "sample_rows", "sample_logits",
+           "sample_step", "SAMPLER_BRANCHES", "sampler_branch_index",
+           "sample_batched_step", "admit_slot", "cool_slots",
+           "batched_prefill_admit",
            "init_paged_pool", "init_paged_slot_cache",
            "paged_sample_batched_step", "paged_kernel_sample_batched_step",
            "paged_prefill_admit", "paged_thaw_write", "paged_copy_block"]
@@ -138,6 +140,16 @@ def admit_slot(stacked: Cache, cache: Cache, slot: jax.Array,
     return out
 
 
+def cool_slots(stacked: Cache, mask: jax.Array) -> Cache:
+    """Zero the temperature of the slots ``mask`` ``(slots,) bool``
+    names, leaving everything else of the slot-stacked cache as it is:
+    a freed slot's stale sampling state then argmaxes, so it cannot hold
+    the batch on a costlier branch of :func:`sample_rows`."""
+    s = stacked["sample"]
+    return dict(stacked, sample=dict(
+        s, temp=jnp.where(mask, jnp.zeros_like(s["temp"]), s["temp"])))
+
+
 def greedy_batched_step(params: Params, cfg: ModelConfig, cache: Cache,
                         tokens: jax.Array,
                         opts: RuntimeOptions = DEFAULT_OPTIONS):
@@ -160,32 +172,98 @@ def greedy_batched_step(params: Params, cfg: ModelConfig, cache: Cache,
 
 
 # ================================================================ sampling ==
+# The branches of :func:`sample_rows`, cheapest first; a batch takes the
+# cheapest one that covers every row (``sampler_branch`` on the host
+# predicts the same index from the rows' policies).
+SAMPLER_BRANCHES = ("argmax", "temperature", "top_k")
+
+
+def _sort_keys(x: jax.Array) -> jax.Array:
+    """int32 keys ordered as ``lax.sort`` orders float32 ``x``: -0 equals
+    0, every NaN equals NaN and sorts last."""
+    x = jnp.where(x == 0, jnp.zeros_like(x), x)
+    x = jnp.where(jnp.isnan(x), jnp.full_like(x, jnp.nan), x)
+    i = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+
+
+def _top_k_drop(scaled: jax.Array, top_k: jax.Array) -> jax.Array:
+    """Which entries of each ``(n, V)`` row fall outside its ``top_k``
+    largest, ties broken toward the lowest index (the stable descending
+    rank), with ``top_k <= 0`` keeping the whole row.  One sort of the
+    values finds each row's k-th largest; no index permutation is built.
+    Of the entries equal to it, the first ``k - #larger`` stay."""
+    vocab = scaled.shape[-1]
+    keys = _sort_keys(-scaled)                 # ascending = descending rank
+    kk = jnp.clip(top_k, 1, vocab)[:, None]
+    kth = jnp.take_along_axis(jnp.sort(keys, axis=-1), kk - 1, axis=-1)
+    above = keys < kth
+    tied = keys == kth
+    earlier_ties = jnp.cumsum(tied, axis=-1, dtype=jnp.int32) - tied
+    keep = above | (tied & (earlier_ties
+                            < kk - above.sum(-1, keepdims=True,
+                                             dtype=jnp.int32)))
+    return (top_k > 0)[:, None] & ~keep
+
+
+def sample_rows(logits: jax.Array, keys: jax.Array, temps: jax.Array,
+                top_ks: jax.Array, vocab: int
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Draw the next token of each row of ``(n, V)`` vocab-padded logits
+    under the row's own key ``(n, 2)``, temperature and top-k.
+
+    ``temp == 0`` is the exact argmax; ``top_k == 0`` samples the whole
+    vocabulary and ``top_k == 1`` keeps only the argmax.  Which work the
+    batch needs is decided on the device from the runtime policy: with no
+    sampling row it argmaxes and nothing else; with no sampling row
+    holding ``0 < top_k < vocab`` it takes each row's Gumbel-max without
+    any sort; otherwise it masks each row below its k-th largest value
+    first.  Every row's key is split on every call, sampled or not, so a
+    stream depends only on its initial key and the emission index —
+    never on which other rows share the batch.  Returns ``(tokens (n,),
+    advanced keys (n, 2))``."""
+    lg = logits[:, :vocab]
+    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+    split = jax.vmap(jax.random.split)(keys)
+    new_keys, subs = split[:, 0], split[:, 1]
+    sampling = temps > 0
+
+    def draw(top_k_mask: bool) -> jax.Array:
+        scaled = lg.astype(jnp.float32) / jnp.maximum(
+            temps.astype(jnp.float32), 1e-6)[:, None]
+        if top_k_mask:
+            scaled = jnp.where(_top_k_drop(scaled, top_ks),
+                               jnp.finfo(jnp.float32).min, scaled)
+        sampled = jax.vmap(jax.random.categorical)(subs, scaled)
+        return jnp.where(sampling, sampled.astype(jnp.int32), greedy)
+
+    tokens = jax.lax.switch(sampler_branch_index(temps, top_ks, vocab),
+                            [lambda: greedy, lambda: draw(False),
+                             lambda: draw(True)])
+    return tokens, new_keys
+
+
+def sampler_branch_index(temps: jax.Array, top_ks: jax.Array,
+                         vocab: int) -> jax.Array:
+    """The index into ``SAMPLER_BRANCHES`` of the cheapest branch of
+    :func:`sample_rows` that covers every row: top-k if a sampling row
+    has ``0 < top_k < vocab``, else temperature if any row samples, else
+    argmax."""
+    sampling = temps > 0
+    return jnp.where(jnp.any(sampling & (top_ks > 0) & (top_ks < vocab)),
+                     2, jnp.where(jnp.any(sampling), 1, 0))
+
+
 def sample_logits(logits: jax.Array, key: jax.Array, temp: jax.Array,
                   top_k: jax.Array, vocab: int
                   ) -> Tuple[jax.Array, jax.Array]:
-    """Draw the next token from one sequence's (vocab-padded) logits row.
-
-    ``temp == 0`` reduces *exactly* to the greedy argmax the pre-sampling
-    engine computed (the sampled branch is selected away by ``where``);
-    ``top_k == 0`` samples the full vocabulary, ``top_k == 1`` keeps only
-    the argmax.  The key is split on every call, sampled or not, so a
-    stream depends only on the initial key and the emission index — never
-    on which other slots are decoding.  Returns ``(token, advanced key)``.
-    """
-    lg = logits[:vocab]
-    greedy = jnp.argmax(lg).astype(jnp.int32)
-    key, sub = jax.random.split(key)
-    scaled = lg.astype(jnp.float32) / jnp.maximum(
-        temp.astype(jnp.float32), 1e-6)
-    # top-k by stable descending rank (ties keep the lowest index, like
-    # argmax) so top_k==1 is *exactly* greedy even on tied logits;
-    # top_k<=0 keeps the whole vocabulary
-    order = jnp.argsort(-scaled)
-    ranks = jnp.zeros_like(order).at[order].set(jnp.arange(vocab))
-    masked = jnp.where((top_k > 0) & (ranks >= jnp.clip(top_k, 1, vocab)),
-                       jnp.finfo(jnp.float32).min, scaled)
-    sampled = jax.random.categorical(sub, masked).astype(jnp.int32)
-    return jnp.where(temp > 0, sampled, greedy), key
+    """:func:`sample_rows` for one sequence's logits row ``(V,)``: the
+    single-row entry, bit-identical to that row in any batch.  Returns
+    ``(token, advanced key)``."""
+    tok, new_key = sample_rows(
+        logits[None], key[None], jnp.asarray(temp, jnp.float32)[None],
+        jnp.asarray(top_k, jnp.int32)[None], vocab)
+    return tok[0], new_key[0]
 
 
 def sample_step(params: Params, cfg: ModelConfig, cache: Cache,
@@ -196,9 +274,9 @@ def sample_step(params: Params, cfg: ModelConfig, cache: Cache,
     ``token`` is a ``()`` int32 scalar; ``cache`` is a batch=1 cache
     carrying a ``"sample"`` subtree ``{key (2,) uint32, temp (), top_k
     ()}`` (``decode_step`` threads unknown keys through untouched).
-    :func:`sample_batched_step` is exactly ``vmap`` of this function, so
-    per-request streams are bit-identical across the batched and per-slot
-    decode paths."""
+    :func:`sample_batched_step` runs the same ``decode_step`` under
+    ``vmap`` and samples the rows together, so per-request streams are
+    bit-identical across the batched and per-slot decode paths."""
     logits, c2 = decode_step(params, cfg, cache, token[None], opts)
     s = cache["sample"]
     nxt, new_key = sample_logits(logits[0], s["key"], s["temp"],
@@ -219,11 +297,23 @@ def sample_batched_step(params: Params, cfg: ModelConfig, cache: Cache,
     (the engine's historical behavior).  Returns ``(next_tokens (slots,),
     positions (slots,), new cache)``."""
     def one(c: Cache, tok: jax.Array):
-        nxt, c2 = sample_step(params, cfg, c, tok, opts)
-        return (nxt, c2["pos"]), c2
+        logits, c2 = decode_step(params, cfg, c, tok[None], opts)
+        return logits[0], c2
 
-    (nxt, pos), new_cache = jax.vmap(one)(cache, tokens)
-    return nxt, pos, new_cache
+    logits, new_cache = jax.vmap(one)(cache, tokens)
+    nxt, new_cache["sample"] = _sample_slots(logits, cache["sample"],
+                                             cfg.vocab_size)
+    return nxt, new_cache["pos"], new_cache
+
+
+def _sample_slots(logits: jax.Array, sample: Cache, vocab: int
+                  ) -> Tuple[jax.Array, Cache]:
+    """:func:`sample_rows` over a slot cache's ``"sample"`` subtree:
+    ``(tokens, the subtree with every key advanced)``."""
+    nxt, keys = sample_rows(logits, sample["key"], sample["temp"],
+                            sample["top_k"], vocab)
+    return nxt, {"key": keys, "temp": sample["temp"],
+                 "top_k": sample["top_k"]}
 
 
 # ===================================================== batched admission ====
@@ -241,7 +331,7 @@ def batched_prefill_admit(params: Params, cfg: ModelConfig, stacked: Cache,
     burst up to a k-bucket by *prepending* rows that target the first real
     row's slot — the real row then overwrites the padding's garbage.
     Returns ``((k,) first tokens, new stacked cache)``; each row's first
-    token is drawn by the same :func:`sample_logits` the decode step uses
+    token is drawn by the same :func:`sample_rows` the decode step uses
     (argmax when its temperature is 0)."""
     k, bucket = tokens.shape
     # the scratch cache is sized to the prompt *bucket*, not max_seq:
@@ -250,9 +340,8 @@ def batched_prefill_admit(params: Params, cfg: ModelConfig, stacked: Cache,
     # the zero padding is identical to what a max_seq prefill writes
     cache = init_cache(cfg, k, min(bucket, max_seq), opts)
     logits, cache = prefill(params, cfg, tokens, cache, opts)
-    first, new_keys = jax.vmap(
-        lambda lg, ky, t, tk: sample_logits(lg, ky, t, tk, cfg.vocab_size)
-    )(logits[:, -1], keys, temps, top_ks)
+    first, new_keys = sample_rows(logits[:, -1], keys, temps, top_ks,
+                                  cfg.vocab_size)
     out = stacked
     model_side = {key: v for key, v in stacked.items() if key != "sample"}
     for i in range(k):
@@ -276,7 +365,7 @@ def batched_prefill_admit(params: Params, cfg: ModelConfig, stacked: Cache,
 # a (slots, max_seq // block_size) int32 array of pool indices passed to
 # the jitted step as *runtime data* (constant shape, so occupancy changes
 # never recompile).  The paged step gathers each slot's blocks into a
-# dense (1, max_seq) view and runs the *same* ``sample_step`` computation
+# dense (1, max_seq) view and runs the *same* ``decode_step`` computation
 # the dense engine runs: positions beyond ``pos`` read garbage from
 # not-yet-written / trash blocks, but ``decode_attention`` replaces
 # masked scores with NEG_INF, so their contribution is exactly 0 and the
@@ -337,7 +426,8 @@ def paged_sample_batched_step(params: Params, cfg: ModelConfig,
 
     ``tables`` is ``(slots, max_seq // block_size)`` int32.  Per slot:
     gather its blocks into a dense view, run the exact dense
-    ``sample_step``, slice the newly written KV row back out.  One
+    ``decode_step``, slice the newly written KV row back out; the rows
+    are sampled together, as ``sample_batched_step`` samples them.  One
     batched scatter then writes every slot's row into its tail block —
     active slots always own their tail block (buckets are block-aligned
     and thawed blocks are private), so no two real writes collide;
@@ -367,18 +457,20 @@ def paged_sample_batched_step(params: Params, cfg: ModelConfig,
         dense = dict(c)
         dense["k"], dense["v"] = dense_view(pk, psk), dense_view(pv, psv)
         wpos = c["pos"]                         # this step writes row wpos
-        nxt, c2 = sample_step(params, cfg, dense, tok, opts)
+        logits, c2 = decode_step(params, cfg, dense, tok[None], opts)
         row_k = jax.lax.dynamic_slice_in_dim(c2["k"], wpos, 1, axis=2)
         row_v = jax.lax.dynamic_slice_in_dim(c2["v"], wpos, 1, axis=2)
         slot_side = {k: v for k, v in c2.items() if k not in ("k", "v")}
         blk = tbl[wpos // bs]
-        return (nxt, c2["pos"], slot_side, row_k[:, 0, 0], row_v[:, 0, 0],
+        return (logits[0], slot_side, row_k[:, 0, 0], row_v[:, 0, 0],
                 blk, wpos % bs)
 
-    nxt, pos, new_cache, rk, rv, blks, offs = jax.vmap(one)(
+    logits, new_cache, rk, rv, blks, offs = jax.vmap(one)(
         slot_cache, tokens, tables)
+    nxt, new_cache["sample"] = _sample_slots(logits, slot_cache["sample"],
+                                             cfg.vocab_size)
     new_pool = _scatter_kv_rows(pool, rk, rv, blks, offs)
-    return nxt, pos, new_cache, new_pool
+    return nxt, new_cache["pos"], new_cache, new_pool
 
 
 def _scatter_kv_rows(pool: Cache, rk: jax.Array, rv: jax.Array,
@@ -454,16 +546,12 @@ def paged_kernel_sample_batched_step(params: Params, cfg: ModelConfig,
 
     Drop-in twin of :func:`paged_sample_batched_step` (same signature,
     same return contract) selected by ``opts.paged_kernel``:
-    :func:`paged_kernel_decode_logits`, then per-slot sampling."""
+    :func:`paged_kernel_decode_logits`, then :func:`sample_rows`."""
     logits, new_pool, new_state = paged_kernel_decode_logits(
         params, cfg, slot_cache, pool, tokens, tables, opts)
-    s = slot_cache["sample"]
-    nxt, new_keys = jax.vmap(
-        lambda lg, ky, t, tk: sample_logits(lg, ky, t, tk, cfg.vocab_size)
-    )(logits, s["key"], s["temp"], s["top_k"])
     new_cache = dict(slot_cache, **new_state)
-    new_cache["sample"] = {"key": new_keys, "temp": s["temp"],
-                           "top_k": s["top_k"]}
+    nxt, new_cache["sample"] = _sample_slots(logits, slot_cache["sample"],
+                                             cfg.vocab_size)
     new_cache["pos"] = slot_cache["pos"] + 1
     return nxt, new_cache["pos"], new_cache, new_pool
 
@@ -629,9 +717,7 @@ def paged_prefill_admit(params: Params, cfg: ModelConfig, slot_cache: Cache,
                     c, [(0, t - n) for t, n in zip(s.shape[1:], c.shape)]),
                 model_side, row))
     last = logits[:, -1]
-    first, new_keys = jax.vmap(
-        lambda lg, ky, t, tk: sample_logits(lg, ky, t, tk, cfg.vocab_size)
-    )(last, keys, temps, top_ks)
+    first, new_keys = sample_rows(last, keys, temps, top_ks, cfg.vocab_size)
 
     def blockify(a):                     # (n_attn, k, bucket, kvh, hd)
         a = jnp.moveaxis(a, 0, 1).reshape(k, n_attn, nblk, bs, kvh, hd)

@@ -21,22 +21,31 @@ device's :attr:`DeviceSpec.compile_domain` here.
 Program set per key:
 
 * ``decode``       — one batched sampling step over the slot-stacked
-                     cache (``sample_batched_step`` under ``vmap``), with
-                     the cache **donated** so KV/SSM buffers are updated
-                     in place instead of copied every token; slots whose
-                     temperature is 0 argmax exactly as before
+                     cache (``sample_batched_step``: ``decode_step``
+                     under ``vmap``, then ``sample_rows`` over the
+                     slots), with the cache **donated** so KV/SSM
+                     buffers are updated in place instead of copied
+                     every token; slots whose temperature is 0 argmax
+                     exactly as before, and a batch with no sampling
+                     slot argmaxes and nothing else
 * ``decode_greedy`` — the pure-argmax batched step; the engine selects it
-                     on ticks where no active slot samples, so all-greedy
-                     workloads never pay the sampling machinery
+                     on ticks where no active slot samples.  Redundant
+                     since ``sample_rows`` branches on the device: kept,
+                     bit-identical to ``decode`` at temperature 0
 * ``decode_ref``   — the batch=1 reference decode returning raw logits
                      (kept for equivalence tests and benchmarks)
 * ``sample_ref``   — the batch=1 sampling decode (the per-slot loop
-                     path); ``decode`` is precisely ``vmap`` of this
+                     path); ``decode`` is its batched twin, token for
+                     token
 * ``sample_first`` — draws a prefill's first token from its last-position
                      logits row (per-request admission path)
 * ``admit_slot``   — writes a fresh prefill + its sampling state into one
                      slot of the stacked cache (stacked side donated;
                      slot index traced, so one program covers every slot)
+* ``cool_slots``   — zeroes the temperature of the slots a ``(slots,)``
+                     mask names (cache donated): a freed slot that
+                     sampled must not hold the batch on a costlier
+                     sampler branch
 * ``prefill(bucket)`` — per-prompt-bucket batch=1 prefill jits, lazy
 * ``prefill_batch(bucket, k)`` — ONE-call burst admission: prefill a
                      ``(k, bucket)`` stack of same-bucket prompts and
@@ -72,7 +81,8 @@ import jax
 
 from repro.models.configs import ModelConfig
 from repro.models.model import (admit_slot, batched_prefill_admit,
-                                decode_step, greedy_batched_step,
+                                cool_slots, decode_step,
+                                greedy_batched_step,
                                 paged_copy_block,
                                 paged_kernel_sample_batched_step,
                                 paged_prefill_admit,
@@ -122,6 +132,8 @@ class ServePrograms:
             lambda stacked, c, i, k, t, tk: admit_slot(stacked, c, i, k, t,
                                                        tk),
             donate_argnums=(0,))
+        self.cool_slots: Callable = _jit(
+            "cool_slots", cool_slots, donate_argnums=(0,))
         self._prefills: Dict[int, Callable] = {}
         self._prefill_batches: Dict[Tuple[int, int], Callable] = {}
         self._paged_decodes: Dict[Tuple[int, int], Callable] = {}

@@ -96,8 +96,9 @@ from repro.kernels.act_quant import kv_dequant_rows
 from repro.models.configs import ModelConfig
 from repro.models.hybrid_moe import STATE_LEAVES
 from repro.models.layers import Params, dtype_of
-from repro.models.model import (init_cache, init_paged_pool,
-                                init_paged_slot_cache, init_slot_cache)
+from repro.models.model import (SAMPLER_BRANCHES, init_cache,
+                                init_paged_pool, init_paged_slot_cache,
+                                init_slot_cache)
 from repro.models.runtime import DEFAULT_OPTIONS, RuntimeOptions
 from repro.obs import NULL_RECORDER, MetricsRegistry, counting
 
@@ -105,7 +106,8 @@ from .compile_cache import GLOBAL_COMPILE_CACHE, CompileCache, ServePrograms
 from .paging import (DEFAULT_BLOCK_SIZE, TRASH_BLOCK, BlockPool,
                      FrozenRequest, PrefixCache, PrefixEntry,
                      block_hash_chain, blocks_needed)
-from .sampling import DEFAULT_SAMPLING, SamplingOpts, request_key
+from .sampling import (DEFAULT_SAMPLING, SamplingOpts, request_key,
+                       sampler_branch)
 
 DECODE_MODES = ("batched", "per_slot", "paged")
 PREFILL_MODES = ("batched", "per_request")
@@ -342,6 +344,12 @@ class ServingEngine:
         self._prefix_refused = self.metrics.counter(
             "engine.prefix_refused_recurrent")
         self._state_gauge = self.metrics.gauge("engine.state_bytes")
+        # decode ticks and prefill calls by the sampler branch their
+        # batch takes (``sampler_branch``: the host knows every row's
+        # policy, so counting reads nothing off the device)
+        self._sampler_counts = {
+            b: self.metrics.counter(f"engine.sampler.{b}")
+            for b in SAMPLER_BRANCHES}
         self._queue: Deque[Request] = deque()
         self._active: List[Optional[Request]] = [None] * slots
         self.generation = 0
@@ -450,6 +458,9 @@ class ServingEngine:
 
     def _reset_caches(self) -> None:
         self._state_leaves, self._state_slot_bytes = (), 0
+        # slots whose device temperature may be > 0: a freed one is
+        # cooled (``_cool_free_slots``) before the next batched decode
+        self._slot_hot = np.zeros(self.slots, bool)
         if self.decode_mode == "batched":
             self._cache = init_slot_cache(self.cfg, self.slots, self.max_seq,
                                           self.opts)
@@ -521,6 +532,26 @@ class ServingEngine:
     def _sampling_of(self, req: Request) -> SamplingOpts:
         return req.sampling if req.sampling is not None else self.sampling
 
+    def _count_sampler(self, policies) -> str:
+        """Count one batched sampler call under the branch its rows'
+        ``policies`` take on the device; returns that branch."""
+        branch = sampler_branch(policies, self.cfg.vocab_size)
+        self._sampler_counts[branch].inc()
+        return branch
+
+    def _cool_free_slots(self) -> None:
+        """Zero the device temperature of free slots that last held a
+        sampling request, so a batch of greedy requests stays on the
+        sampler's argmax branch.  A no-op while no slot ever sampled."""
+        if not self._slot_hot.any():
+            return
+        stale = self._slot_hot & np.fromiter(
+            (r is None for r in self._active), bool, self.slots)
+        if stale.any():
+            self._cache = self._programs.cool_slots(self._cache,
+                                                    jnp.asarray(stale))
+            self._slot_hot &= ~stale
+
     # ------------------------------------------------------------ stepping --
     def _gather_burst(self, limit: int):
         """Pop the head request plus every same-bucket waiter behind it
@@ -574,6 +605,7 @@ class ServingEngine:
         self.stats.tokens_out += 1
         if self._sampling_of(req).temperature > 0:
             self.stats.sampled_tokens += 1
+            self._slot_hot[slot] = True  # admission wrote its temperature
         rec = self.recorder
         if rec.enabled:
             # one first_token instant per *admission* (a swap re-admission
@@ -660,6 +692,7 @@ class ServingEngine:
             with self._span("engine.wait"):
                 first = jax.device_get(first)
             self.stats.prefill_calls += 1
+            self._count_sampler(self._sampling_of(r) for r in batch)
             stamp = time.perf_counter()
         for i, req in enumerate(batch):
             slot = slots_for[i]
@@ -761,6 +794,7 @@ class ServingEngine:
                 self.params, cache, jnp.asarray(toks))
             self.stats.prefill_calls += 1
             s = self._sampling_of(req)
+            self._count_sampler((s,))
             key = jnp.asarray(request_key(s.seed, req.rid,
                                           len(req.generated)))
             temp = jnp.float32(s.temperature)
@@ -913,19 +947,21 @@ class ServingEngine:
             return 0
         with self._span("engine.dispatch"):
             tokens = np.zeros(self.slots, np.int32)
-            sampling = False
+            policies = {}
             for slot, req in enumerate(self._active):
                 if req is not None:
                     tokens[slot] = req.generated[-1]
-                    sampling = sampling or \
-                        self._sampling_of(req).temperature > 0
-            # all-greedy ticks take the pure-argmax program: no per-slot
-            # argsort/categorical work selected away by a where — the
-            # default greedy engine keeps its historical hot-path cost.
-            # Outputs are bit-identical either way, so mixed workloads can
-            # alternate.
-            step_fn = (self._programs.decode if sampling
-                       else self._programs.decode_greedy)
+                    s = self._sampling_of(req)
+                    policies[id(s)] = s
+            self._cool_free_slots()
+            branch = self._count_sampler(policies.values())
+            # all-greedy ticks take the pure-argmax program (``decode``
+            # would take its argmax branch on the device just the same;
+            # the twin predates that).  Outputs are bit-identical either
+            # way, so mixed workloads can alternate.
+            step_fn = (self._programs.decode_greedy
+                       if branch == SAMPLER_BRANCHES[0]
+                       else self._programs.decode)
             nxt, pos, self._cache = step_fn(
                 self.params, self._cache, jnp.asarray(tokens))
         return self._bookkeep_decode(nxt, pos)
@@ -1025,9 +1061,14 @@ class ServingEngine:
             self._ensure_tail_blocks()
         with self._span("engine.dispatch"):
             tokens = np.zeros(self.slots, np.int32)
+            policies = {}
             for slot, req in enumerate(self._active):
                 if req is not None:
                     tokens[slot] = req.generated[-1]
+                    s = self._sampling_of(req)
+                    policies[id(s)] = s
+            self._cool_free_slots()
+            self._count_sampler(policies.values())
             # block tables are runtime data: constant (slots, max_seq/bs)
             # shape, so occupancy/sharing churn reuses one compiled program
             nxt, pos, self._cache, self._pool = self._paged_decode_fn()(
@@ -1308,6 +1349,8 @@ class ServingEngine:
             self._upload_frozen(fz, slot, ids)
         req.frozen = None
         self._active[slot] = req
+        if fz.sample["temp"] > 0:
+            self._slot_hot[slot] = True
         self.stats.thaws += 1
         if self.recorder.enabled:
             stamp = time.perf_counter()

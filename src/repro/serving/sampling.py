@@ -11,15 +11,22 @@ with heterogeneous per-slot policies still share one decode program.
 
 ``temperature == 0`` short-circuits (on device, via ``jnp.where``) to
 the exact argmax the pre-sampling engine computed, so greedy token
-streams are bit-identical to the historical greedy decode.
+streams are bit-identical to the historical greedy decode.  A batch with
+no sampling row argmaxes and does nothing else
+(:func:`repro.models.model.sample_rows` branches on the device);
+:func:`sampler_branch` names, on the host, the branch a batch takes.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Iterable
 
 import numpy as np
 
-__all__ = ["SamplingOpts", "DEFAULT_SAMPLING", "request_key"]
+from repro.models.model import SAMPLER_BRANCHES
+
+__all__ = ["SamplingOpts", "DEFAULT_SAMPLING", "request_key",
+           "sampler_branch"]
 
 
 @dataclass(frozen=True)
@@ -51,3 +58,14 @@ def request_key(seed: int, rid: int, consumed: int = 0) -> np.ndarray:
     continuation advances the stream instead of replaying it."""
     hi = (int(seed) ^ (int(consumed) * 2654435761)) & 0xFFFFFFFF
     return np.array([hi, int(rid) & 0xFFFFFFFF], dtype=np.uint32)
+
+
+def sampler_branch(policies: Iterable[SamplingOpts], vocab: int) -> str:
+    """The branch of :func:`~repro.models.model.sample_rows` a batch of
+    rows under ``policies`` takes — one of ``SAMPLER_BRANCHES`` — from
+    the same predicates the device evaluates (temperatures as the
+    float32 the slot holds)."""
+    sampling = [p for p in policies if np.float32(p.temperature) > 0]
+    if any(0 < p.top_k < vocab for p in sampling):
+        return SAMPLER_BRANCHES[2]
+    return SAMPLER_BRANCHES[1] if sampling else SAMPLER_BRANCHES[0]

@@ -7,6 +7,8 @@ memory) — which interpret mode cannot show.  The topology is described
 inside a fixture, never at import, so every test worker collects the
 same tests and only the one given this file loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -119,6 +121,83 @@ def test_paged_decode_step_compiles(one_chip, kv_dtype):
     # stable names: the step's module, and the kernel inside it
     assert "jit_paged_decode" in text
     assert KERNEL_NAME in text
+
+
+# a computation's header, any reference to a computation, a conditional's
+# branches, and the result shape of a sort or scatter instruction
+_HEADER = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s*\(")
+_REF = re.compile(r"%([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}"
+                       r"|(?:true|false)_computation=%([\w.\-]+)")
+_SORT_OR_SCATTER = re.compile(r"=\s*(.*?)\s(?:sort|scatter)\(")
+
+
+def _wide_ops_by_place(text, widths):
+    """The ``sort`` and ``scatter`` instructions of compiled HLO ``text``
+    with a dimension in ``widths``, split into those inside a
+    conditional's branch computations (or what they call) and the rest."""
+    body, cur = {}, None
+    for line in text.splitlines():
+        m = _HEADER.match(line)
+        if m and line.rstrip().endswith("{"):
+            cur = m.group(1)
+            body[cur] = []
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            body[cur].append(line)
+    todo = [name for lines in body.values() for line in lines
+            for group, one in _BRANCHES.findall(line)
+            for name in _REF.findall(group) + ([one] if one else [])]
+    in_branch = set()
+    while todo:
+        name = todo.pop()
+        if name in body and name not in in_branch:
+            in_branch.add(name)
+            todo += [r for line in body[name] for r in _REF.findall(line)]
+    inside, outside = [], []
+    for name, lines in body.items():
+        for line in lines:
+            m = _SORT_OR_SCATTER.search(line)
+            dims = {int(d) for shape in re.findall(r"\[([\d,]+)\]",
+                                                   m.group(1) if m else "")
+                    for d in shape.split(",") if d}
+            if dims & widths:
+                (inside if name in in_branch else outside).append(
+                    line.strip()[:120])
+    return inside, outside
+
+
+@pytest.mark.parametrize("paged_kernel", [True, False])
+def test_paged_decode_sorts_vocab_only_in_a_branch(one_chip, paged_kernel):
+    """The paged decode program's sampler sorts the vocabulary only in the
+    top-k branch of a conditional: an argmax-only batch never runs a sort
+    or scatter as wide as the logits (a ``select`` in place of the
+    ``cond`` would run every branch on every tick)."""
+    opts = DEFAULT_OPTIONS.replace(paged_kernel=paged_kernel)
+    max_seq = PAPER.max_seq_len
+    nb = SLOTS * (max_seq // BLOCK) + 1
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(PAPER, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_paged_slot_cache(PAPER, SLOTS, max_seq, opts)))
+    pool = on_chip(jax.eval_shape(
+        lambda: init_paged_pool(PAPER, nb, BLOCK, opts)))
+    tokens = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((SLOTS, max_seq // BLOCK), jnp.int32,
+                                  sharding=one_chip)
+    step, _ = ServePrograms(PAPER, opts, max_seq).paged_decode(nb, BLOCK)
+    text = step.lower(params, cache, pool, tokens, tables).compile().as_text()
+    vocab = PAPER.vocab_size
+    inside, outside = _wide_ops_by_place(text, {vocab, SLOTS * vocab})
+    assert outside == []
+    assert inside                          # the top-k branch's sort
 
 
 # Granite-4.0-H-Small as the chip benchmark serves it: the last stage's 10
