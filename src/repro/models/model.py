@@ -7,7 +7,7 @@ that is threaded through ``lax.scan`` over the stacked layers.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -18,18 +18,21 @@ from . import moe as moe_mod
 from . import ssm as ssm_mod
 from ..kernels import ops as kernel_ops
 from ..kernels.act_quant import kv_dequant_rows, kv_quant_rows
-from .configs import ATTN, HYBRID_MOE, LOCAL, MAMBA, ModelConfig
+from .configs import ATTN, HYBRID_MOE, MAMBA, ModelConfig
 from .layers import (Params, dtype_of, embed_lookup, ffn_apply, matmul_w,
                      rms_norm, unembed)
 from .runtime import DEFAULT_OPTIONS, RuntimeOptions
 from .transformer import (_pattern_period, apply_stack, forward, init_params,
-                          lm_loss)
+                          layer_window, lm_loss, walk_layers)
 
 Cache = Dict[str, Any]
 
+# the leaves of a dense cache that carry one entry per layer of the stack
+_LAYER_LEAVES = ("k", "v", "ssm", "conv", "cross_k", "cross_v")
+
 __all__ = ["init_params", "forward", "lm_loss", "init_cache", "prefill",
            "decode_step", "Cache", "init_slot_cache", "write_cache_slot",
-           "greedy_batched_step", "sample_rows", "sample_logits",
+           "sample_rows", "sample_logits",
            "sample_step", "SAMPLER_BRANCHES", "sampler_branch_index",
            "sample_batched_step", "admit_slot", "cool_slots",
            "batched_prefill_admit",
@@ -148,27 +151,6 @@ def cool_slots(stacked: Cache, mask: jax.Array) -> Cache:
     s = stacked["sample"]
     return dict(stacked, sample=dict(
         s, temp=jnp.where(mask, jnp.zeros_like(s["temp"]), s["temp"])))
-
-
-def greedy_batched_step(params: Params, cfg: ModelConfig, cache: Cache,
-                        tokens: jax.Array,
-                        opts: RuntimeOptions = DEFAULT_OPTIONS):
-    """One greedy decode step over a slot-stacked cache.
-
-    tokens: (slots,) int32 — the last emitted token of each slot.  Returns
-    ``(next_tokens (slots,), positions (slots,), new cache)``.  The argmax
-    runs on device, so a serving tick needs a single bulk device→host
-    transfer of ``2 * slots`` scalars instead of one sync per slot.  Each
-    vmapped instance is exactly the batch=1 ``decode_step`` computation, so
-    greedy tokens are bit-identical to the per-slot reference path.
-    """
-    def one(c: Cache, tok: jax.Array):
-        logits, c2 = decode_step(params, cfg, c, tok[None], opts)
-        nxt = jnp.argmax(logits[0, : cfg.vocab_size]).astype(jnp.int32)
-        return (nxt, c2["pos"]), c2
-
-    (nxt, pos), new_cache = jax.vmap(one)(cache, tokens)
-    return nxt, pos, new_cache
 
 
 # ================================================================ sampling ==
@@ -489,55 +471,6 @@ def _scatter_kv_rows(pool: Cache, rk: jax.Array, rv: jax.Array,
     return new_pool
 
 
-def _attn_decode_paged(layer: Params, x: jax.Array, kb, vb, ks, vs,
-                       tables, pos, sin, cos, cfg: ModelConfig,
-                       opts: RuntimeOptions, *, window: int, cross_kv=None):
-    """One-token attention block reading KV straight off the block table.
-
-    Slot-batched twin of :func:`_attn_decode`: x is ``(slots, D)``,
-    ``kb``/``vb`` are ONE layer's pool blocks ``(num_blocks, bs, kvh,
-    hd)`` (``ks``/``vs`` the matching int8 scales or ``None``), ``pos``
-    is per-slot.  Attention runs through :func:`kernel_ops.paged_attention`
-    (Pallas on TPU, ``ref.py`` oracle elsewhere); the new token's KV is
-    *returned* — ``(slots, kvh, hd)`` each — for one batched scatter at
-    the end of the step instead of being written into the pool here."""
-    b, d = x.shape
-    hd = cfg.resolved_head_dim
-    h = rms_norm(x, layer["ln1"], cfg.norm_eps)
-    a = layer["attn"]
-    q = matmul_w(h, a["wq"]).reshape(b, cfg.num_heads, hd)
-    k = matmul_w(h, a["wk"]).reshape(b, cfg.num_kv_heads, hd)
-    v = matmul_w(h, a["wv"]).reshape(b, cfg.num_kv_heads, hd)
-    if "bq" in a:
-        q = q + a["bq"].reshape(cfg.num_heads, hd)
-        k = k + a["bk"].reshape(cfg.num_kv_heads, hd)
-        v = v + a["bv"].reshape(cfg.num_kv_heads, hd)
-    q = _apply_rot1(q, sin, cos)
-    k = _apply_rot1(k, sin, cos)
-    w = window or opts.decode_window
-    out = kernel_ops.paged_attention(q, kb, vb, tables, pos, k, v, ks, vs,
-                                     window=w)
-    x = x + matmul_w(out.reshape(b, cfg.num_heads * hd), a["wo"]).astype(x.dtype)
-
-    if cross_kv is not None and "cross" in layer:
-        hq = rms_norm(x, layer["ln_cross"], cfg.norm_eps)
-        c = layer["cross"]
-        qc = (hq @ c["wq"]).reshape(b, cfg.num_heads, hd)
-        ck, cv = cross_kv
-        out = attn_mod.decode_attention(qc, ck.astype(x.dtype),
-                                        cv.astype(x.dtype),
-                                        jnp.int32(ck.shape[1] - 1), window=0)
-        x = x + (out.reshape(b, cfg.num_heads * hd) @ c["wo"]).astype(x.dtype)
-
-    h2 = rms_norm(x, layer["ln2"], cfg.norm_eps)
-    if cfg.arch_type == "moe":
-        y = moe_mod.moe_apply_decode(layer["moe"], h2, cfg)
-    else:
-        y = ffn_apply(layer["ffn"], h2, gated=cfg.gated_ffn,
-                      activation=cfg.activation)
-    return x + y.astype(x.dtype), k, v
-
-
 def paged_kernel_sample_batched_step(params: Params, cfg: ModelConfig,
                                      slot_cache: Cache, pool: Cache,
                                      tokens: jax.Array, tables: jax.Array,
@@ -593,79 +526,28 @@ def paged_kernel_decode_logits(params: Params, cfg: ModelConfig,
     act_dt = dtype_of(cfg.activation_dtype)
     params = cast_params(params, act_dt)
     x = embed_lookup(params["embed"], tokens).astype(act_dt)  # (slots, D)
-    pk, pv = pool["k"], pool["v"]
-    _, n_attn, bs, kvh, hd = pk.shape
-    has_scales = "k_scale" in pool
-    pk_l = jnp.moveaxis(pk, 1, 0)       # (n_attn, num_blocks, bs, kvh, hd)
-    pv_l = jnp.moveaxis(pv, 1, 0)
-    ks_l = jnp.moveaxis(pool["k_scale"], 1, 0) if has_scales else None
-    vs_l = jnp.moveaxis(pool["v_scale"], 1, 0) if has_scales else None
-    sin, cos = rotary_embedding(pos[:, None], hd, cfg.rope_theta)
+    hd = pool["k"].shape[-1]
+    # (n_attn, num_blocks, ...) views of the pool's k, v and int8 scales
+    planes = tuple(jnp.moveaxis(pool[name], 1, 0) if name in pool else None
+                   for name in ("k", "v", "k_scale", "v_scale"))
+    rope = rotary_embedding(pos[:, None], hd, cfg.rope_theta)
+    cross = None
+    if cfg.is_encoder_decoder:
+        # cross KV is a slot leaf (slots, n_layers, 1, enc_seq, kvh, hd);
+        # walk it layer-major, dropping the batch=1 axis
+        cross = tuple(jnp.moveaxis(slot_cache[name][:, :, 0], 0, 1)
+                      for name in ("cross_k", "cross_v"))
 
-    kinds, _ = _pattern_period(cfg)
-    period = len(kinds)
-    has_cross = cfg.is_encoder_decoder
-    n = cfg.num_layers
-    n_full = (n // period) * period
+    def layer(x, kind, sl):
+        weights, kb, vb, ksb, vsb, ckv = sl
+        return _attn_decode(weights, x, rope,
+                            _paged_kv(kb, vb, ksb, vsb, tables, pos), cfg,
+                            opts, window=layer_window(cfg, kind),
+                            cross_kv=ckv)
 
-    def run_layer(x, layer, j_kind, kb, vb, ksb, vsb, ckv):
-        w = cfg.sliding_window if j_kind == LOCAL else 0
-        return _attn_decode_paged(layer, x, kb, vb, ksb, vsb, tables, pos,
-                                  sin, cos, cfg, opts, window=w,
-                                  cross_kv=ckv)
-
-    def layer_step(carry, xs):
-        x = carry
-        if has_cross:
-            layer_pp, kbp, vbp, ksp, vsp, ck, cv = xs
-        else:
-            layer_pp, kbp, vbp, ksp, vsp = xs
-            ck = cv = None
-        rks, rvs = [], []
-        for j, kind in enumerate(kinds):
-            layer = jax.tree_util.tree_map(lambda a: a[j], layer_pp)
-            ckv = (ck[j], cv[j]) if has_cross else None
-            x, k1, v1 = run_layer(x, layer, kind, kbp[j], vbp[j],
-                                  None if ksp is None else ksp[j],
-                                  None if vsp is None else vsp[j], ckv)
-            rks.append(k1)
-            rvs.append(v1)
-        return x, (jnp.stack(rks), jnp.stack(rvs))
-
-    row_k = row_v = None
-    if n_full:
-        def group(a):
-            return a[:n_full].reshape(n_full // period, period, *a.shape[1:])
-
-        grouped = jax.tree_util.tree_map(group, params["layers"])
-        xs = (grouped, group(pk_l), group(pv_l),
-              None if ks_l is None else group(ks_l),
-              None if vs_l is None else group(vs_l))
-        if has_cross:
-            # cross KV is a slot leaf (slots, n_layers, 1, enc_seq, kvh, hd);
-            # rearrange layer-major for the scan, dropping the batch=1 axis
-            ckg = group(jnp.moveaxis(slot_cache["cross_k"][:, :, 0], 0, 1))
-            cvg = group(jnp.moveaxis(slot_cache["cross_v"][:, :, 0], 0, 1))
-            xs = xs + (ckg, cvg)
-        # None scale entries are empty pytrees — scan passes them through
-        x, (rk_o, rv_o) = jax.lax.scan(layer_step, x, xs)
-        row_k = rk_o.reshape(n_full, *rk_o.shape[2:])   # (n_full, slots, ...)
-        row_v = rv_o.reshape(n_full, *rv_o.shape[2:])
-    rows_k_tail, rows_v_tail = [], []
-    for j in range(n_full, n):
-        layer = jax.tree_util.tree_map(lambda a: a[j], params["layers"])
-        kind = kinds[(j - n_full) % period]
-        ckv = ((slot_cache["cross_k"][:, j, 0],
-                slot_cache["cross_v"][:, j, 0]) if has_cross else None)
-        x, k1, v1 = run_layer(x, layer, kind, pk_l[j], pv_l[j],
-                              None if ks_l is None else ks_l[j],
-                              None if vs_l is None else vs_l[j], ckv)
-        rows_k_tail.append(k1)
-        rows_v_tail.append(v1)
-    if rows_k_tail:
-        tail_k, tail_v = jnp.stack(rows_k_tail), jnp.stack(rows_v_tail)
-        row_k = tail_k if row_k is None else jnp.concatenate([row_k, tail_k])
-        row_v = tail_v if row_v is None else jnp.concatenate([row_v, tail_v])
+    x, (row_k, row_v), _ = walk_layers(
+        cfg, x, (params["layers"],) + planes + (cross,), layer,
+        num_layers=cfg.num_layers)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params["embed"], x)
@@ -767,21 +649,23 @@ def paged_copy_block(pool: Cache, src: jax.Array, dst: jax.Array) -> Cache:
 
 
 # =========================================================== decode blocks ==
-def _decode_rotary(pos: jax.Array, head_dim: int, theta: float):
-    from .layers import rotary_embedding
-    return rotary_embedding(pos[None, None], head_dim, theta)  # (1,1,half)
-
-
 def _apply_rot1(x: jax.Array, sin, cos):
     """x: (B, H, hd) one-token rotary."""
     from .layers import apply_rotary
     return apply_rotary(x[:, None], sin, cos)[:, 0]
 
 
-def _attn_decode(layer: Params, x: jax.Array, k_cache, v_cache, pos,
+def _attn_decode(layer: Params, x: jax.Array, rope, attend: Callable,
                  cfg: ModelConfig, opts: RuntimeOptions, *, window: int,
                  cross_kv=None):
-    """One-token attention block.  x: (B, D)."""
+    """One-token attention block.  x: (B, D); ``rope`` the ``(sin, cos)``
+    of each row's position.
+
+    ``attend(q, k, v, window) -> (out (B, H, hd), kv)`` reads this layer's
+    KV with the new token's rotated ``k``/``v`` ``(B, kvh, hd)`` in it:
+    :func:`_dense_kv` writes them into a dense cache and returns it,
+    :func:`_paged_kv` reads the pool through the block table and returns
+    the new row for one batched scatter.  Returns ``(x, kv)``."""
     b, d = x.shape
     hd = cfg.resolved_head_dim
     h = rms_norm(x, layer["ln1"], cfg.norm_eps)
@@ -793,12 +677,9 @@ def _attn_decode(layer: Params, x: jax.Array, k_cache, v_cache, pos,
         q = q + a["bq"].reshape(cfg.num_heads, hd)
         k = k + a["bk"].reshape(cfg.num_kv_heads, hd)
         v = v + a["bv"].reshape(cfg.num_kv_heads, hd)
-    sin, cos = _decode_rotary(pos, hd, cfg.rope_theta)
-    q = _apply_rot1(q, sin, cos)
-    k = _apply_rot1(k, sin, cos)
-    k_cache, v_cache = attn_mod.update_kv_cache(k_cache, v_cache, k, v, pos)
-    w = window or opts.decode_window
-    out = attn_mod.decode_attention(q, k_cache, v_cache, pos, window=w)
+    q = _apply_rot1(q, *rope)
+    k = _apply_rot1(k, *rope)
+    out, kv = attend(q, k, v, window or opts.decode_window)
     x = x + matmul_w(out.reshape(b, cfg.num_heads * hd), a["wo"]).astype(x.dtype)
 
     if cross_kv is not None and "cross" in layer:
@@ -818,7 +699,31 @@ def _attn_decode(layer: Params, x: jax.Array, k_cache, v_cache, pos,
     else:
         y = ffn_apply(layer["ffn"], h2, gated=cfg.gated_ffn,
                       activation=cfg.activation)
-    return x + y.astype(x.dtype), k_cache, v_cache
+    return x + y.astype(x.dtype), kv
+
+
+def _dense_kv(k_cache, v_cache, pos) -> Callable:
+    """The ``attend`` of :func:`_attn_decode` over one layer's dense cache
+    ``(B, max_seq, kvh, hd)`` at the shared position ``pos``: writes the
+    new row, attends, returns the updated ``(k_cache, v_cache)``."""
+    def attend(q, k, v, window):
+        kc, vc = attn_mod.update_kv_cache(k_cache, v_cache, k, v, pos)
+        return attn_mod.decode_attention(q, kc, vc, pos, window=window), (kc, vc)
+    return attend
+
+
+def _paged_kv(kb, vb, ks, vs, tables, pos) -> Callable:
+    """The ``attend`` of :func:`_attn_decode` over one layer's pool blocks
+    ``(num_blocks, bs, kvh, hd)`` (``ks``/``vs`` the int8 scales or
+    ``None``) through the block tables, each slot at its own ``pos``:
+    :func:`kernel_ops.paged_attention` (Pallas on TPU, the ``ref.py``
+    oracle elsewhere).  Returns the new ``(k, v)`` row, which the step
+    scatters into the pool once for every layer."""
+    def attend(q, k, v, window):
+        out = kernel_ops.paged_attention(q, kb, vb, tables, pos, k, v, ks, vs,
+                                         window=window)
+        return out, (k, v)
+    return attend
 
 
 def _mamba_decode(layer: Params, x: jax.Array, ssm_state, conv_state,
@@ -837,122 +742,42 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Cache,
 
     token: (B,) int32.  Returns (logits (B, vocab), updated cache).
     """
-    from .layers import cast_params
+    from .layers import cast_params, rotary_embedding
     act_dt = dtype_of(cfg.activation_dtype)
     params = cast_params(params, act_dt)
     x = embed_lookup(params["embed"], token).astype(act_dt)  # (B, D)
     pos = cache["pos"]
-    kinds, shared_after = _pattern_period(cfg)
-    period = len(kinds)
+    _, shared_after = _pattern_period(cfg)
+    rope = rotary_embedding(pos[None, None], cfg.resolved_head_dim,
+                            cfg.rope_theta)                   # (1, 1, half)
     new_cache = dict(cache)
+    # per-layer leaves, walked beside the weights
+    state = {name: cache[name] for name in _LAYER_LEAVES if name in cache}
 
-    if cfg.arch_type in ("ssm", "hybrid"):
-        n = cfg.num_layers
-        n_full = (n // period) * period
+    def layer(x, kind, sl):
+        weights, st = sl
+        if kind == MAMBA:
+            x, s1, c1 = _mamba_decode(weights, x, st["ssm"], st["conv"], cfg)
+            return x, {"ssm": s1, "conv": c1.astype(cache["conv"].dtype)}
+        ckv = (st["cross_k"], st["cross_v"]) if "cross_k" in st else None
+        x, (k1, v1) = _attn_decode(weights, x, rope,
+                                   _dense_kv(st["k"], st["v"], pos), cfg, opts,
+                                   window=layer_window(cfg, kind),
+                                   cross_kv=ckv)
+        return x, {"k": k1, "v": v1}
 
-        has_shared = shared_after and "shared_attn" in params \
-            and "shared_k" in cache
-
-        def period_step(carry, xs):
-            x = carry
-            if has_shared:
-                layer_pp, ssm_pp, conv_pp, sk, sv = xs
-            else:
-                layer_pp, ssm_pp, conv_pp = xs
-                sk = sv = None
-            new_ssm, new_conv = [], []
-            for j in range(period):
-                layer = jax.tree_util.tree_map(lambda a: a[j], layer_pp)
-                x, s1, c1 = _mamba_decode(layer, x, ssm_pp[j], conv_pp[j], cfg)
-                new_ssm.append(s1)
-                new_conv.append(c1)
-            ys = (jnp.stack(new_ssm), jnp.stack(new_conv))
-            if has_shared:
-                x, sk, sv = _attn_decode(params["shared_attn"], x, sk, sv,
-                                         pos, cfg, opts, window=0)
-                ys = ys + (sk, sv)
-            return x, ys
-
-        if n_full:
-            grouped = jax.tree_util.tree_map(
-                lambda a: a[:n_full].reshape(n_full // period, period,
-                                             *a.shape[1:]), params["layers"])
-            ssm_g = cache["ssm"][:n_full].reshape(n_full // period, period,
-                                                  *cache["ssm"].shape[1:])
-            conv_g = cache["conv"][:n_full].reshape(n_full // period, period,
-                                                    *cache["conv"].shape[1:])
-            xs = (grouped, ssm_g, conv_g)
-            if has_shared:
-                xs = xs + (cache["shared_k"], cache["shared_v"])
-            x, ys = jax.lax.scan(period_step, x, xs)
-            ssm_o, conv_o = ys[0], ys[1]
-            new_cache["ssm"] = new_cache["ssm"].at[:n_full].set(
-                ssm_o.reshape(n_full, *ssm_o.shape[2:]))
-            new_cache["conv"] = new_cache["conv"].at[:n_full].set(
-                conv_o.reshape(n_full, *conv_o.shape[2:])
-                .astype(new_cache["conv"].dtype))
-            if has_shared:
-                new_cache["shared_k"], new_cache["shared_v"] = ys[2], ys[3]
-        for j in range(n_full, n):
-            layer = jax.tree_util.tree_map(lambda a: a[j], params["layers"])
-            x, s1, c1 = _mamba_decode(layer, x, cache["ssm"][j],
-                                      cache["conv"][j], cfg)
-            new_cache["ssm"] = new_cache["ssm"].at[j].set(s1)
-            new_cache["conv"] = new_cache["conv"].at[j].set(
-                c1.astype(new_cache["conv"].dtype))
-    else:
-        # attention stacks (dense / moe / local-global / enc-dec / vlm)
-        cross = None
-        has_cross = cfg.is_encoder_decoder
-
-        def layer_step(carry, xs):
-            x = carry
-            if has_cross:
-                layer_pp, kc, vc, ck, cv = xs
-            else:
-                layer_pp, kc, vc = xs
-                ck = cv = None
-            new_k, new_v = [], []
-            for j, kind in enumerate(kinds):
-                layer = jax.tree_util.tree_map(lambda a: a[j], layer_pp)
-                w = cfg.sliding_window if kind == LOCAL else 0
-                ckv = (ck[j], cv[j]) if has_cross else None
-                x, k1, v1 = _attn_decode(layer, x, kc[j], vc[j], pos, cfg,
-                                         opts, window=w, cross_kv=ckv)
-                new_k.append(k1)
-                new_v.append(v1)
-            return x, (jnp.stack(new_k), jnp.stack(new_v))
-
-        n = cfg.num_layers
-        n_full = (n // period) * period
-        if n_full:
-            grouped = jax.tree_util.tree_map(
-                lambda a: a[:n_full].reshape(n_full // period, period,
-                                             *a.shape[1:]), params["layers"])
-            kg = cache["k"][:n_full].reshape(n_full // period, period,
-                                             *cache["k"].shape[1:])
-            vg = cache["v"][:n_full].reshape(n_full // period, period,
-                                             *cache["v"].shape[1:])
-            xs = (grouped, kg, vg)
-            if has_cross:
-                ckg = cache["cross_k"][:n_full].reshape(
-                    n_full // period, period, *cache["cross_k"].shape[1:])
-                cvg = cache["cross_v"][:n_full].reshape(
-                    n_full // period, period, *cache["cross_v"].shape[1:])
-                xs = (grouped, kg, vg, ckg, cvg)
-            x, (k_o, v_o) = jax.lax.scan(layer_step, x, xs)
-            new_cache["k"] = k_o.reshape(n_full, *k_o.shape[2:])
-            new_cache["v"] = v_o.reshape(n_full, *v_o.shape[2:])
-        for j in range(n_full, n):
-            layer = jax.tree_util.tree_map(lambda a: a[j], params["layers"])
-            kind = kinds[(j - n_full) % period]
-            w = cfg.sliding_window if kind == LOCAL else 0
-            ckv = ((cache["cross_k"][j], cache["cross_v"][j])
-                   if has_cross else None)
-            x, k1, v1 = _attn_decode(layer, x, cache["k"][j], cache["v"][j],
-                                     pos, cfg, opts, window=w, cross_kv=ckv)
-            new_cache["k"] = new_cache["k"].at[j].set(k1)
-            new_cache["v"] = new_cache["v"].at[j].set(v1)
+    shared = period_xs = None
+    if shared_after and "shared_attn" in params and "shared_k" in cache:
+        def shared(x, skv):
+            return _attn_decode(params["shared_attn"], x, rope,
+                                _dense_kv(*skv, pos), cfg, opts, window=0)
+        period_xs = (cache["shared_k"], cache["shared_v"])
+    x, outs, shared_kv = walk_layers(
+        cfg, x, (params["layers"], state), layer, num_layers=cfg.num_layers,
+        epilogue=shared, period_xs=period_xs)
+    new_cache.update(outs)
+    if shared_kv is not None:
+        new_cache["shared_k"], new_cache["shared_v"] = shared_kv
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params["embed"], x)
@@ -1001,101 +826,43 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
                              opts.replace(attn_impl="full"), causal=False)
         cross_src = rms_norm(enc, params["encoder_norm"], cfg.norm_eps)
 
-    kinds, shared_after = _pattern_period(cfg)
-    period = len(kinds)
-    n = cfg.num_layers
-    n_full = (n // period) * period
+    _, shared_after = _pattern_period(cfg)
     kv_dt = dtype_of(opts.kv_cache_dtype)
+    has_cross = cfg.is_encoder_decoder and cross_src is not None
 
     def pad_kv(kk):
         return jnp.pad(kk.astype(kv_dt),
                        ((0, 0), (0, max_seq - s), (0, 0), (0, 0)))
 
-    if cfg.arch_type in ("ssm", "hybrid"):
-        def period_body(x, layer_pp):
-            sts, cvs = [], []
-            for j in range(period):
-                layer = jax.tree_util.tree_map(lambda a: a[j], layer_pp)
-                h = rms_norm(x, layer["ln"], cfg.norm_eps)
-                y, st, cv = ssm_mod.mamba_prefill_states(layer["mamba"], h,
-                                                         cfg)
-                x = x + y.astype(x.dtype)
-                sts.append(st)
-                cvs.append(cv.astype(kv_dt))
-            shared_kv = None
-            if shared_after and "shared_attn" in params:
-                x, kk, vv = _attn_prefill_kv(params["shared_attn"], x, cfg,
-                                             opts, window=0)
-                shared_kv = (pad_kv(kk), pad_kv(vv))
-            return x, (jnp.stack(sts), jnp.stack(cvs), shared_kv)
+    def layer(x, kind, weights):
+        if kind == MAMBA:
+            h = rms_norm(x, weights["ln"], cfg.norm_eps)
+            y, st, cv = ssm_mod.mamba_prefill_states(weights["mamba"], h, cfg)
+            return x + y.astype(x.dtype), {"ssm": st, "conv": cv.astype(kv_dt)}
+        x, kk, vv = _attn_prefill_kv(weights, x, cfg, opts,
+                                     window=layer_window(cfg, kind),
+                                     cross_src=cross_src)
+        out = {"k": pad_kv(kk), "v": pad_kv(vv)}
+        if has_cross:
+            c = weights["cross"]
+            se = cross_src.shape[1]
+            out["cross_k"] = (cross_src @ c["wk"]).reshape(
+                b, se, cfg.num_kv_heads, hd).astype(kv_dt)
+            out["cross_v"] = (cross_src @ c["wv"]).reshape(
+                b, se, cfg.num_kv_heads, hd).astype(kv_dt)
+        return x, out
 
-        if n_full:
-            grouped = jax.tree_util.tree_map(
-                lambda a: a[:n_full].reshape(n_full // period, period,
-                                             *a.shape[1:]), params["layers"])
+    def shared(x, _):
+        x, kk, vv = _attn_prefill_kv(params["shared_attn"], x, cfg, opts,
+                                     window=0)
+        return x, (pad_kv(kk), pad_kv(vv))
 
-            def scan_body(x, pp):
-                x, (sts, cvs, skv) = period_body(x, pp)
-                ys = (sts, cvs) + ((skv[0], skv[1]) if skv is not None else ())
-                return x, ys
-
-            x, ys = jax.lax.scan(scan_body, x, grouped)
-            sts, cvs = ys[0], ys[1]
-            new_cache["ssm"] = new_cache["ssm"].at[:n_full].set(
-                sts.reshape(n_full, *sts.shape[2:]))
-            new_cache["conv"] = new_cache["conv"].at[:n_full].set(
-                cvs.reshape(n_full, *cvs.shape[2:]))
-            if len(ys) > 2:
-                new_cache["shared_k"], new_cache["shared_v"] = ys[2], ys[3]
-        for j in range(n_full, n):
-            layer = jax.tree_util.tree_map(lambda a: a[j], params["layers"])
-            h = rms_norm(x, layer["ln"], cfg.norm_eps)
-            y, st, cv = ssm_mod.mamba_prefill_states(layer["mamba"], h, cfg)
-            x = x + y.astype(x.dtype)
-            new_cache["ssm"] = new_cache["ssm"].at[j].set(st)
-            new_cache["conv"] = new_cache["conv"].at[j].set(cv.astype(kv_dt))
-    else:
-        has_cross = cfg.is_encoder_decoder and cross_src is not None
-
-        def period_body(x, layer_pp):
-            kks, vvs, cks, cvs = [], [], [], []
-            for j, kind in enumerate(kinds):
-                layer = jax.tree_util.tree_map(lambda a: a[j], layer_pp)
-                w = cfg.sliding_window if kind == LOCAL else 0
-                x, kk, vv = _attn_prefill_kv(layer, x, cfg, opts, window=w,
-                                             cross_src=cross_src)
-                kks.append(pad_kv(kk))
-                vvs.append(pad_kv(vv))
-                if has_cross:
-                    c = layer["cross"]
-                    se = cross_src.shape[1]
-                    cks.append((cross_src @ c["wk"]).reshape(
-                        b, se, cfg.num_kv_heads, hd).astype(kv_dt))
-                    cvs.append((cross_src @ c["wv"]).reshape(
-                        b, se, cfg.num_kv_heads, hd).astype(kv_dt))
-            ys = (jnp.stack(kks), jnp.stack(vvs))
-            if has_cross:
-                ys = ys + (jnp.stack(cks), jnp.stack(cvs))
-            return x, ys
-
-        if n_full:
-            grouped = jax.tree_util.tree_map(
-                lambda a: a[:n_full].reshape(n_full // period, period,
-                                             *a.shape[1:]), params["layers"])
-            x, ys = jax.lax.scan(period_body, x, grouped)
-            new_cache["k"] = ys[0].reshape(n_full, *ys[0].shape[2:])
-            new_cache["v"] = ys[1].reshape(n_full, *ys[1].shape[2:])
-            if has_cross:
-                new_cache["cross_k"] = ys[2].reshape(n_full, *ys[2].shape[2:])
-                new_cache["cross_v"] = ys[3].reshape(n_full, *ys[3].shape[2:])
-        for j in range(n_full, n):
-            layer = jax.tree_util.tree_map(lambda a: a[j], params["layers"])
-            kind = kinds[(j - n_full) % period]
-            w = cfg.sliding_window if kind == LOCAL else 0
-            x, kk, vv = _attn_prefill_kv(layer, x, cfg, opts, window=w,
-                                         cross_src=cross_src)
-            new_cache["k"] = new_cache["k"].at[j].set(pad_kv(kk))
-            new_cache["v"] = new_cache["v"].at[j].set(pad_kv(vv))
+    x, outs, shared_kv = walk_layers(
+        cfg, x, params["layers"], layer, num_layers=cfg.num_layers,
+        epilogue=shared if shared_after and "shared_attn" in params else None)
+    new_cache.update(outs)
+    if shared_kv is not None:
+        new_cache["shared_k"], new_cache["shared_v"] = shared_kv
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params["embed"], x)

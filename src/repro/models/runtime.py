@@ -22,7 +22,6 @@ class RuntimeOptions:
     kv_cache_dtype: str = "bfloat16"
     moe_capacity_factor: float = 1.0
     logit_chunk: int = 0           # chunk the LM loss over sequence (0 = off)
-    scan_layers: bool = True
     # §Perf: sequence-parallel activation sharding between blocks — the
     # residual stream is constrained to (batch, seq->axis, none) so TP
     # partial-sum all-reduces become reduce-scatter (+ per-block gather)

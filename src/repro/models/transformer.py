@@ -9,7 +9,7 @@ attention) are handled by scanning over pattern *periods* and unrolling the
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -163,6 +163,85 @@ def _pattern_period(cfg: ModelConfig) -> Tuple[Tuple[str, ...], bool]:
     return (ATTN,), False
 
 
+def layer_window(cfg: ModelConfig, kind: str) -> int:
+    """The attention window of a layer of ``kind`` (0 = global)."""
+    return cfg.sliding_window if kind == LOCAL else 0
+
+
+def walk_layers(cfg: ModelConfig, carry, stack, body: Callable, *,
+                num_layers: Optional[int] = None,
+                epilogue: Optional[Callable] = None, period_xs=None,
+                wrap: Optional[Callable] = None):
+    """Walk a stacked layer pytree in pattern periods (:func:`_pattern_period`).
+
+    ``stack`` is any pytree whose leaves carry a leading per-layer axis:
+    the layer weights and whatever the caller walks beside them (KV,
+    SSM/conv state, pool planes, cross KV); ``None`` leaves pass through.
+    ``body(carry, kind, layer_slice) -> (carry, out)`` runs one layer.
+    ``epilogue(carry, period_slice) -> (carry, out)``, if given, runs after
+    each whole period on that period's slice of ``period_xs`` (one entry
+    per whole period): zamba2's shared attention.  ``wrap`` transforms the
+    whole-period function (rematerialization).
+
+    More than one whole period is ``lax.scan``-ed, the period unrolled
+    inside, so compile time is O(1) in depth; a single one is unrolled.
+    The layers that do not fill a period (zamba2's 38 % 6 == 2) run
+    after, unrolled, through the same ``body``.  Only the first
+    ``num_layers`` layers are walked when given (elastic depth).  Returns
+    ``(carry, per-layer outs stacked in layer order, per-period epilogue
+    outs)``."""
+    kinds, _ = _pattern_period(cfg)
+    period = len(kinds)
+    total = jax.tree_util.tree_leaves(stack)[0].shape[0]
+    n = total if num_layers is None else min(num_layers, total)
+    groups = n // period
+    n_full = groups * period
+
+    def stacked(outs):
+        return jax.tree_util.tree_map(lambda *o: jnp.stack(o), *outs)
+
+    def run_period(carry, layers, px):
+        outs = []
+        for j, kind in enumerate(kinds):
+            carry, o = body(carry, kind,
+                            jax.tree_util.tree_map(lambda a: a[j], layers))
+            outs.append(o)
+        outs = stacked(outs)
+        ep = None
+        if epilogue is not None:
+            carry, ep = epilogue(carry, px)
+        return carry, (outs, ep)
+
+    if wrap is not None:
+        run_period = wrap(run_period)
+
+    outs = eps = None
+    if groups:
+        grouped = jax.tree_util.tree_map(
+            lambda a: a[:n_full].reshape(groups, period, *a.shape[1:]), stack)
+        if groups > 1:
+            carry, (outs, eps) = jax.lax.scan(
+                lambda c, xs: run_period(c, *xs), carry, (grouped, period_xs))
+            outs = jax.tree_util.tree_map(
+                lambda a: a.reshape(n_full, *a.shape[2:]), outs)
+        else:
+            def first(tree):
+                return jax.tree_util.tree_map(lambda a: a[0], tree)
+            carry, (outs, eps) = run_period(carry, first(grouped),
+                                            first(period_xs))
+            eps = jax.tree_util.tree_map(lambda a: a[None], eps)
+    tail = []
+    for j in range(n_full, n):
+        carry, o = body(carry, kinds[j - n_full],
+                        jax.tree_util.tree_map(lambda a: a[j], stack))
+        tail.append(o)
+    if tail:
+        tail = stacked(tail)
+        outs = tail if outs is None else jax.tree_util.tree_map(
+            lambda a, b: jnp.concatenate([a, b]), outs, tail)
+    return carry, outs, eps
+
+
 def _maybe_seq_shard(x: jax.Array, opts: RuntimeOptions) -> jax.Array:
     """§Perf lever: constrain the residual stream to sequence-parallel
     sharding at block boundaries (Megatron-SP on the TPU mesh)."""
@@ -194,57 +273,30 @@ def apply_stack(stack: Params, x: jax.Array, cfg: ModelConfig,
     ``num_layers`` (static) < full depth realizes the elastic depth-scaling
     operator η5: only the first n layers' stacked weights are used.
     """
-    kinds, shared_after = _pattern_period(cfg)
-    period = len(kinds)
-    total = jax.tree_util.tree_leaves(stack)[0].shape[0]
-    n = total if num_layers is None else min(num_layers, total)
-    n_full = (n // period) * period
-    aux0 = jnp.zeros((), jnp.float32)
+    _, shared_after = _pattern_period(cfg)
 
-    def one_layer(kind: str, layer: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        if kind == MAMBA:
-            return mamba_block(layer, x, cfg), aux0
-        window = cfg.sliding_window if kind == LOCAL else 0
-        return transformer_block(layer, x, cfg, opts, window=window,
-                                 causal=causal, cross_src=cross_src)
-
-    def period_body(x, period_params):
-        aux = aux0
+    def one_layer(carry, kind: str, layer: Params):
+        x, aux = carry
         x = _maybe_seq_shard(x, opts)
-        for j, kind in enumerate(kinds):
-            layer = jax.tree_util.tree_map(lambda a: a[j], period_params)
-            x, a = one_layer(kind, layer, x)
-            aux = aux + a
-        if shared_after and shared is not None:
-            x, a = transformer_block(shared, x, cfg, opts, window=0,
-                                     causal=causal)
-            aux = aux + a
-        return x, aux
+        if kind == MAMBA:
+            return (mamba_block(layer, x, cfg), aux), None
+        x, a = transformer_block(layer, x, cfg, opts,
+                                 window=layer_window(cfg, kind),
+                                 causal=causal, cross_src=cross_src)
+        return (x, aux + a), None
 
-    period_body = _remat_wrap(period_body, opts)
+    def shared_block(carry, _):
+        x, aux = carry
+        x, a = transformer_block(shared, x, cfg, opts, window=0,
+                                 causal=causal)
+        return (x, aux + a), None
 
-    aux_total = aux0
-    if n_full:
-        grouped = jax.tree_util.tree_map(
-            lambda a: a[:n_full].reshape(n_full // period, period, *a.shape[1:]),
-            stack)
-        if opts.scan_layers and n_full // period > 1:
-            def scan_body(carry, period_params):
-                x, aux = carry
-                x, a = period_body(x, period_params)
-                return (x, aux + a), None
-            (x, aux_total), _ = jax.lax.scan(scan_body, (x, aux_total), grouped)
-        else:
-            for i in range(n_full // period):
-                pp = jax.tree_util.tree_map(lambda a: a[i], grouped)
-                x, a = period_body(x, pp)
-                aux_total = aux_total + a
-    # leftover layers (pattern remainder, e.g. zamba2's 38 % 6 == 2)
-    for j in range(n_full, n):
-        layer = jax.tree_util.tree_map(lambda a: a[j], stack)
-        x, a = one_layer(kinds[(j - n_full) % period], layer, x)
-        aux_total = aux_total + a
-    return x, aux_total
+    (x, aux), _, _ = walk_layers(
+        cfg, (x, jnp.zeros((), jnp.float32)), stack, one_layer,
+        num_layers=num_layers,
+        epilogue=shared_block if shared_after and shared is not None else None,
+        wrap=functools.partial(_remat_wrap, opts=opts))
+    return x, aux
 
 
 # ------------------------------------------------------------- forward -----

@@ -28,10 +28,6 @@ Program set per key:
                      every token; slots whose temperature is 0 argmax
                      exactly as before, and a batch with no sampling
                      slot argmaxes and nothing else
-* ``decode_greedy`` — the pure-argmax batched step; the engine selects it
-                     on ticks where no active slot samples.  Redundant
-                     since ``sample_rows`` branches on the device: kept,
-                     bit-identical to ``decode`` at temperature 0
 * ``decode_ref``   — the batch=1 reference decode returning raw logits
                      (kept for equivalence tests and benchmarks)
 * ``sample_ref``   — the batch=1 sampling decode (the per-slot loop
@@ -82,7 +78,6 @@ import jax
 from repro.models.configs import ModelConfig
 from repro.models.model import (admit_slot, batched_prefill_admit,
                                 cool_slots, decode_step,
-                                greedy_batched_step,
                                 paged_copy_block,
                                 paged_kernel_sample_batched_step,
                                 paged_prefill_admit,
@@ -112,13 +107,6 @@ class ServePrograms:
         # so aliasing input→output storage avoids a full cache copy per step
         self.decode: Callable = _jit(
             "decode", lambda p, c, t: sample_batched_step(p, cfg, c, t, opts),
-            donate_argnums=(1,))
-        # all-greedy ticks skip the sampling machinery entirely (the
-        # engine picks this program when no active slot has temp > 0;
-        # outputs are bit-identical to `decode` at temperature 0)
-        self.decode_greedy: Callable = _jit(
-            "decode_greedy",
-            lambda p, c, t: greedy_batched_step(p, cfg, c, t, opts),
             donate_argnums=(1,))
         self.decode_ref: Callable = _jit(
             "decode_ref", lambda p, c, t: decode_step(p, cfg, c, t, opts))

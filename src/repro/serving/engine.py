@@ -532,12 +532,11 @@ class ServingEngine:
     def _sampling_of(self, req: Request) -> SamplingOpts:
         return req.sampling if req.sampling is not None else self.sampling
 
-    def _count_sampler(self, policies) -> str:
+    def _count_sampler(self, policies) -> None:
         """Count one batched sampler call under the branch its rows'
-        ``policies`` take on the device; returns that branch."""
-        branch = sampler_branch(policies, self.cfg.vocab_size)
-        self._sampler_counts[branch].inc()
-        return branch
+        ``policies`` take on the device."""
+        self._sampler_counts[sampler_branch(policies,
+                                            self.cfg.vocab_size)].inc()
 
     def _cool_free_slots(self) -> None:
         """Zero the device temperature of free slots that last held a
@@ -954,15 +953,10 @@ class ServingEngine:
                     s = self._sampling_of(req)
                     policies[id(s)] = s
             self._cool_free_slots()
-            branch = self._count_sampler(policies.values())
-            # all-greedy ticks take the pure-argmax program (``decode``
-            # would take its argmax branch on the device just the same;
-            # the twin predates that).  Outputs are bit-identical either
-            # way, so mixed workloads can alternate.
-            step_fn = (self._programs.decode_greedy
-                       if branch == SAMPLER_BRANCHES[0]
-                       else self._programs.decode)
-            nxt, pos, self._cache = step_fn(
+            # an all-greedy batch takes the sampler's argmax branch on
+            # the device
+            self._count_sampler(policies.values())
+            nxt, pos, self._cache = self._programs.decode(
                 self.params, self._cache, jnp.asarray(tokens))
         return self._bookkeep_decode(nxt, pos)
 

@@ -73,11 +73,23 @@ def test_decode_smoke(arch):
     assert int(cache["pos"]) == 9
 
 
-@pytest.mark.parametrize("arch", list_archs())
-def test_decode_matches_forward(arch):
+# stacks whose pattern period is longer than one layer, deep enough for
+# two whole periods (scanned) and a remainder (unrolled after them); the
+# window is cut below the prompt so local and global layers differ
+DEEP = [pytest.param("gemma3-12b", 13, {"sliding_window": 4},
+                     id="gemma3-12b-13l"),
+        pytest.param("zamba2-1.2b", 5, {"shared_attn_period": 2},
+                     id="zamba2-1.2b-5l")]
+
+
+@pytest.mark.parametrize(
+    "arch,num_layers,updates",
+    [pytest.param(a, 2, {}, id=a) for a in list_archs()] + DEEP)
+def test_decode_matches_forward(arch, num_layers, updates):
     """Decode with cache must agree with full forward at the last position
     (capacity set high enough that MoE drops nothing)."""
-    cfg = get_config(arch).reduced()
+    cfg = get_config(arch).reduced(num_layers=num_layers).with_updates(
+        **updates)
     opts = RuntimeOptions(moe_capacity_factor=8.0)
     key = jax.random.PRNGKey(3)
     params = init_params(cfg, key)
@@ -90,3 +102,39 @@ def test_decode_matches_forward(arch):
     rel = float(jnp.max(jnp.abs(ref - lg.astype(jnp.float32)))) / (
         float(jnp.max(jnp.abs(ref))) + 1e-9)
     assert rel < 0.06, f"{arch}: decode diverges from forward (rel={rel})"
+
+
+def test_decode_scans_whole_periods():
+    """Whole pattern periods are scanned, not unrolled: gemma3's decode
+    step at 4 periods + 1 layer holds as many dots as at 2 periods + 1."""
+    def dots(num_layers):
+        cfg = get_config("gemma3-12b").reduced(num_layers=num_layers)
+        params = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0)))
+        cache = jax.eval_shape(lambda: init_cache(cfg, 2, 32, OPTS))
+        tok = jax.ShapeDtypeStruct((2,), jnp.int32)
+        text = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t, OPTS)
+                       ).lower(params, cache, tok).as_text()
+        return text.count("stablehlo.dot_general")
+
+    assert dots(25) == dots(13) > 0
+
+
+def test_stack_matches_plain_layer_loop():
+    """The period walk (two scanned periods, one layer after them) equals
+    a plain loop over gemma3's layers, each layer's kind by its index:
+    5 local then 1 global."""
+    from repro.models.transformer import apply_stack, transformer_block
+    cfg = get_config("gemma3-12b").reduced(num_layers=13).with_updates(
+        sliding_window=4, param_dtype="float32", activation_dtype="float32")
+    key = jax.random.PRNGKey(4)
+    layers = init_params(cfg, key)["layers"]
+    x = jax.random.normal(key, (2, 12, cfg.d_model))
+    out, _ = apply_stack(layers, x, cfg, OPTS)
+    ref = x
+    for i in range(cfg.num_layers):
+        layer = jax.tree_util.tree_map(lambda a: a[i], layers)
+        window = 0 if i % 6 == 5 else cfg.sliding_window
+        ref, _ = transformer_block(layer, ref, cfg, OPTS, window=window)
+    assert float(jnp.max(jnp.abs(out - ref))) <= 1e-4 * float(
+        jnp.max(jnp.abs(ref)))

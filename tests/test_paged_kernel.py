@@ -80,9 +80,9 @@ def _requests(mix, rid_base=0):
             for i, (n, budget, _, temp) in enumerate(mix)]
 
 
-def _engine(**kw):
+def _engine(cfg=CFG, params=PARAMS, **kw):
     kw.setdefault("slots", 2)
-    return ServingEngine(CFG, PARAMS, max_seq=MAX_SEQ, compile_cache=CC,
+    return ServingEngine(cfg, params, max_seq=MAX_SEQ, compile_cache=CC,
                          **kw)
 
 
@@ -107,20 +107,34 @@ def _run(mix, *, rid_base=0, **kw):
 _DENSE = {}
 
 
-def _dense_baseline(mix):
-    key = tuple(mix)
+def _dense_baseline(mix, model=None):
+    key = (tuple(mix), model)
     if key not in _DENSE:
-        _DENSE[key] = _run(mix, decode_mode="batched")[0]
+        _DENSE[key] = _run(mix, decode_mode="batched", **_model(model))[0]
     return _DENSE[key]
 
 
+def _model(name):
+    """Engine keywords for a model other than ``CFG``: gemma3 at two
+    whole 6-layer periods plus one layer, its window cut below the
+    prompts so local and global layers differ."""
+    if name is None:
+        return {}
+    cfg = get_config(name).reduced(num_layers=13).with_updates(
+        sliding_window=8)
+    return {"cfg": cfg, "params": init_params(cfg, jax.random.PRNGKey(0))}
+
+
 # ----------------------------------------------- kernel ≡ dense batched --
-@pytest.mark.parametrize("block_size", [4, 8, 16])
-@pytest.mark.parametrize("mix", MIX_CORPUS, ids=range(len(MIX_CORPUS)))
-def test_kernel_paged_matches_dense_batched(mix, block_size):
+@pytest.mark.parametrize(
+    "mix,block_size,model",
+    [pytest.param(mix, bs, None, id=f"{i}-{bs}")
+     for i, mix in enumerate(MIX_CORPUS) for bs in (4, 8, 16)]
+    + [pytest.param(MIX_CORPUS[2], 8, "gemma3-12b", id="gemma3-13l-2-8")])
+def test_kernel_paged_matches_dense_batched(mix, block_size, model):
     streams, eng = _run(mix, decode_mode="paged", block_size=block_size,
-                        opts=KERNEL)
-    assert streams == _dense_baseline(mix)
+                        opts=KERNEL, **_model(model))
+    assert streams == _dense_baseline(mix, model)
     assert (eng.block_pool.tables == TRASH_BLOCK).all()
 
 
