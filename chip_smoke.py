@@ -86,11 +86,14 @@ def check_device() -> dict:
 
 
 def kernel_cases(cfg: ModelConfig, *, slots: int = 8, max_seq: int = 2048,
-                 block_size: int = 16) -> dict:
+                 block_size: int = 16, layers: int = 3) -> dict:
     """Random operands of ``ops.paged_attention`` at ``cfg``'s attention
     geometry: slots ragged from empty to full (pos ``0 .. max_seq``), each
-    over blocks of its own.  ``{"bf16": args, "int8": args}``, one per
-    pool dtype, ``args`` in the op's order."""
+    over blocks of its own, in a block-major pool of ``layers`` layers of
+    different KV, read at the last (the layers before it are drawn after
+    every other operand, so the layer read does not depend on their
+    count).  ``{"bf16": args, "int8": args}``, one per pool dtype,
+    ``args`` in the op's order."""
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     mb = max_seq // block_size
     nb = slots * mb + 1
@@ -100,27 +103,32 @@ def kernel_cases(cfg: ModelConfig, *, slots: int = 8, max_seq: int = 2048,
         return jnp.asarray(rng.standard_normal(shape), jnp.float32)
 
     q = normal(slots, h, hd).astype(jnp.bfloat16)
-    kf, vf = normal(nb, block_size, kvh, hd), normal(nb, block_size, kvh, hd)
+    plane = (nb, 1, block_size, kvh, hd)
+    kf, vf = normal(*plane), normal(*plane)
     kn = normal(slots, kvh, hd).astype(jnp.bfloat16)
     vn = normal(slots, kvh, hd).astype(jnp.bfloat16)
     tables = jnp.asarray(1 + rng.permutation(nb - 1).reshape(slots, mb),
                          jnp.int32)
+    before = (nb, layers - 1) + plane[2:]
+    kf = jnp.concatenate([normal(*before), kf], axis=1)
+    vf = jnp.concatenate([normal(*before), vf], axis=1)
     pos = jnp.asarray(np.linspace(0, max_seq, slots), jnp.int32)
     kq, ks = kv_quant_rows(kf)
     vq, vs = kv_quant_rows(vf)
+    layer = jnp.int32(layers - 1)
     return {"bf16": (q, kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16),
-                     tables, pos, kn, vn, None, None),
-            "int8": (q, kq, vq, tables, pos, kn, vn, ks, vs)}
+                     tables, pos, kn, vn, layer, None, None),
+            "int8": (q, kq, vq, tables, pos, kn, vn, layer, ks, vs)}
 
 
 def oracle(args) -> np.ndarray:
     """The f32 ``ref.py`` answer for ``ops.paged_attention``'s operands."""
-    q, kb, vb, tables, pos, kn, vn, ksc, vsc = args
+    q, kb, vb, tables, pos, kn, vn, layer, ksc, vsc = args
     f32 = jnp.float32
     with jax.default_matmul_precision("float32"):
         return np.asarray(ref.paged_decode_attn_ref(
             q.astype(f32), kb, vb, tables, pos, kn.astype(f32),
-            vn.astype(f32), k_scale=ksc, v_scale=vsc))
+            vn.astype(f32), layer, k_scale=ksc, v_scale=vsc))
 
 
 def slot_rel_err(out: np.ndarray, want: np.ndarray) -> float:
@@ -313,6 +321,9 @@ def main() -> None:
     print(f"cache: {cache}, {warm} entries at start", flush=True)
     cfg = get_config("paper-backbone")
     kernel_phase(cfg)
+    # a lane-aligned head dim: the kernel reads each block out of the
+    # whole pool (paper-backbone's 32 lanes read a padded copy of a plane)
+    kernel_phase(cfg.with_updates(head_dim=128))
     serve_phase(cfg)
     parity_phase(cfg)
     print(json.dumps({"ok": True, "device": device}))

@@ -80,23 +80,28 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
                                              "interpret"))
 def paged_attention(q: jax.Array, k_blocks: jax.Array, v_blocks: jax.Array,
                     tables: jax.Array, pos: jax.Array, k_new: jax.Array,
-                    v_new: jax.Array, k_scale: jax.Array | None = None,
+                    v_new: jax.Array, layer: jax.Array,
+                    k_scale: jax.Array | None = None,
                     v_scale: jax.Array | None = None, window: int = 0,
                     scale: float | None = None,
                     interpret: bool = False) -> jax.Array:
-    """Single-query decode attention straight off a BlockPool table.
+    """Single-query decode attention of layer ``layer`` straight off a
+    BlockPool table.
 
-    q: (slots, H, hd); k/v_blocks: (num_blocks, bs, kvh, hd);
-    tables: (slots, mb) int32 runtime data; pos: (slots,) resident tokens;
-    k/v_new: (slots, kvh, hd) current-token KV (not yet scattered);
-    k/v_scale: optional (num_blocks, bs) per-row int8 scales; ``scale``:
-    the softmax scale (default 1/sqrt(hd)).
+    q: (slots, H, hd); k/v_blocks: (num_blocks, n_attn, bs, kvh, hd), the
+    whole block-major pool; tables: (slots, mb) int32 runtime data; pos:
+    (slots,) resident tokens; k/v_new: (slots, kvh, hd) current-token KV
+    (not yet scattered); layer: int32 scalar, runtime data (the kernel's
+    third scalar-prefetch operand); k/v_scale: optional
+    (num_blocks, n_attn, bs) per-row int8 scales; ``scale``: the softmax
+    scale (default 1/sqrt(hd)).  Neither the kernel nor the oracle slices
+    a layer's plane out of the pool: each reads ``pool[blk, layer]``.
 
     Chosen by the platform the program is lowered for, with no flag that
     could pick the oracle on a chip: a TPU program gets the Pallas
     kernel, any other gets the ``ref.py`` oracle.  ``interpret=True``
     (tests only) runs the kernel in the Pallas interpreter anywhere."""
-    args = (q, k_blocks, v_blocks, tables, pos, k_new, v_new)
+    args = (q, k_blocks, v_blocks, tables, pos, k_new, v_new, layer)
     kw = dict(k_scale=k_scale, v_scale=v_scale, window=window, scale=scale)
     if interpret:
         return paged_decode_attention(*args, interpret=True, **kw)

@@ -114,35 +114,39 @@ def flash_attn_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def paged_decode_attn_ref(q: jax.Array, k_blocks: jax.Array,
                           v_blocks: jax.Array, tables: jax.Array,
                           pos: jax.Array, k_new: jax.Array,
-                          v_new: jax.Array, *,
+                          v_new: jax.Array, layer: jax.Array | int, *,
                           k_scale: jax.Array | None = None,
                           v_scale: jax.Array | None = None,
                           window: int = 0,
                           scale: float | None = None) -> jax.Array:
-    """Single-query GQA attention over a paged KV pool (oracle).
+    """Single-query GQA attention over layer ``layer`` of a paged KV pool
+    (oracle).
 
-    q: (slots, H, hd); k/v_blocks: (num_blocks, bs, kvh, hd) — ONE layer's
-    pool slice; tables: (slots, mb) int32 block ids; pos: (slots,) — the
-    number of tokens already in the pool (pool columns < pos are valid);
-    k_new/v_new: (slots, kvh, hd) — the current token's KV, folded in as an
-    always-valid extra key (it has NOT been scattered into the pool yet).
-    Optional k/v_scale: (num_blocks, bs) f32 per-row int8 scales.
+    q: (slots, H, hd); k/v_blocks: (num_blocks, n_attn, bs, kvh, hd) — the
+    whole block-major pool; tables: (slots, mb) int32 block ids; pos:
+    (slots,) — the number of tokens already in the pool (pool columns <
+    pos are valid); k_new/v_new: (slots, kvh, hd) — the current token's
+    KV, folded in as an always-valid extra key (it has NOT been scattered
+    into the pool yet); layer: int32 scalar, the layer read, as
+    ``pool[blk, layer]`` (no layer's plane is sliced out first).
+    Optional k/v_scale: (num_blocks, n_attn, bs) f32 per-row int8 scales.
     ``window`` keeps pool columns > pos - window (the new token is position
     ``pos``, so with window w the valid set is (pos-w, pos]).  ``scale``
     is the softmax scale (default 1/sqrt(hd)).
     Returns (slots, H, hd) in q.dtype."""
     slots, h, hd = q.shape
-    nb, bs, kvh, _ = k_blocks.shape
+    _, _, bs, kvh, _ = k_blocks.shape
     mb = tables.shape[1]
     g = h // kvh
     scale = 1.0 / np.sqrt(hd) if scale is None else scale
 
     def one(qi, tbl, p, kn, vn):
-        kf = k_blocks[tbl].astype(jnp.float32).reshape(mb * bs, kvh, hd)
-        vf = v_blocks[tbl].astype(jnp.float32).reshape(mb * bs, kvh, hd)
+        rows = (mb * bs, kvh, hd)
+        kf = k_blocks[tbl, layer].astype(jnp.float32).reshape(rows)
+        vf = v_blocks[tbl, layer].astype(jnp.float32).reshape(rows)
         if k_scale is not None:
-            kf = kf * k_scale[tbl].reshape(mb * bs, 1, 1)
-            vf = vf * v_scale[tbl].reshape(mb * bs, 1, 1)
+            kf = kf * k_scale[tbl, layer].reshape(mb * bs, 1, 1)
+            vf = vf * v_scale[tbl, layer].reshape(mb * bs, 1, 1)
         cols = jnp.arange(mb * bs)
         valid = cols < p
         if window:

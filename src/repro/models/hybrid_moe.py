@@ -210,7 +210,6 @@ def paged_decode(params: Params, cfg: ModelConfig, slot_cache, pool,
     slots = x.shape[0]
     di, p = cfg.ssm_d_inner, cfg.ssm_head_dim
     n, gr = cfg.ssm_state_dim, cfg.ssm_ngroups
-    has_scales = "k_scale" in pool
 
     def mamba_layer(carry, j):
         x, ssm, conv = carry
@@ -252,9 +251,8 @@ def paged_decode(params: Params, cfg: ModelConfig, slot_cache, pool,
             h = rms_norm(x, layer["ln1"], cfg.norm_eps)
             q, k, v = _qkv(layer["attn"], h, cfg)
             o = kernel_ops.paged_attention(
-                q, pool["k"][:, j], pool["v"][:, j], tables, pos, k, v,
-                pool["k_scale"][:, j] if has_scales else None,
-                pool["v_scale"][:, j] if has_scales else None,
+                q, pool["k"], pool["v"], tables, pos, k, v, j,
+                pool.get("k_scale"), pool.get("v_scale"),
                 window=opts.decode_window, scale=cfg.resolved_attn_scale)
             o = o.reshape(slots, -1) @ layer["attn"]["wo"]
             x = _ffn(layer, _residual(x, o, cfg), cfg)

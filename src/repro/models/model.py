@@ -507,12 +507,10 @@ def paged_kernel_decode_logits(params: Params, cfg: ModelConfig,
     per-slot rotary phases — rather than ``vmap`` of a batch-1 step.
     Tables and positions stay runtime data, so occupancy/fragmentation
     never recompiles; int8 pools pass their per-row scales straight into
-    the kernel's block loop (dequant on chip, never in HBM).
-
-    §Perf: the pool is viewed layer-major (``moveaxis(pool, 1, 0)``) so
-    ``lax.scan`` can carry one layer's blocks per iteration — XLA fuses
-    the transpose into the scan gather, but a layer-major pool layout
-    would make it free."""
+    the kernel's block loop (dequant on chip, never in HBM).  The walk
+    carries each attention layer's index beside its weights, and the
+    kernel reads ``pool[blk, layer]`` out of the whole block-major pool:
+    nothing slices a layer's plane out of the pool or transposes it."""
     from .layers import (cast_params, mask_padded_logits_raw,
                          rotary_embedding)
     pos = slot_cache["pos"]                                   # (slots,)
@@ -527,9 +525,7 @@ def paged_kernel_decode_logits(params: Params, cfg: ModelConfig,
     params = cast_params(params, act_dt)
     x = embed_lookup(params["embed"], tokens).astype(act_dt)  # (slots, D)
     hd = pool["k"].shape[-1]
-    # (n_attn, num_blocks, ...) views of the pool's k, v and int8 scales
-    planes = tuple(jnp.moveaxis(pool[name], 1, 0) if name in pool else None
-                   for name in ("k", "v", "k_scale", "v_scale"))
+    attn_ids = jnp.arange(pool["k"].shape[1], dtype=jnp.int32)
     rope = rotary_embedding(pos[:, None], hd, cfg.rope_theta)
     cross = None
     if cfg.is_encoder_decoder:
@@ -539,14 +535,14 @@ def paged_kernel_decode_logits(params: Params, cfg: ModelConfig,
                       for name in ("cross_k", "cross_v"))
 
     def layer(x, kind, sl):
-        weights, kb, vb, ksb, vsb, ckv = sl
+        weights, j, ckv = sl
         return _attn_decode(weights, x, rope,
-                            _paged_kv(kb, vb, ksb, vsb, tables, pos), cfg,
+                            _paged_kv(pool, j, tables, pos), cfg,
                             opts, window=layer_window(cfg, kind),
                             cross_kv=ckv)
 
     x, (row_k, row_v), _ = walk_layers(
-        cfg, x, (params["layers"],) + planes + (cross,), layer,
+        cfg, x, (params["layers"], attn_ids, cross), layer,
         num_layers=cfg.num_layers)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -712,16 +708,20 @@ def _dense_kv(k_cache, v_cache, pos) -> Callable:
     return attend
 
 
-def _paged_kv(kb, vb, ks, vs, tables, pos) -> Callable:
-    """The ``attend`` of :func:`_attn_decode` over one layer's pool blocks
-    ``(num_blocks, bs, kvh, hd)`` (``ks``/``vs`` the int8 scales or
-    ``None``) through the block tables, each slot at its own ``pos``:
-    :func:`kernel_ops.paged_attention` (Pallas on TPU, the ``ref.py``
-    oracle elsewhere).  Returns the new ``(k, v)`` row, which the step
-    scatters into the pool once for every layer."""
+def _paged_kv(pool, layer, tables, pos) -> Callable:
+    """The ``attend`` of :func:`_attn_decode` over attention layer
+    ``layer`` (an int32 scalar, runtime data) of the whole block-major
+    pool ``(num_blocks, n_attn, bs, kvh, hd)`` (with ``k_scale``/
+    ``v_scale`` ``(num_blocks, n_attn, bs)`` in an int8 pool) through the
+    block tables, each slot at its own ``pos``:
+    :func:`kernel_ops.paged_attention` (Pallas on TPU, the layer index a
+    scalar-prefetch operand; the ``ref.py`` oracle elsewhere).  Returns
+    the new ``(k, v)`` row, which the step scatters into the pool once
+    for every layer."""
     def attend(q, k, v, window):
-        out = kernel_ops.paged_attention(q, kb, vb, tables, pos, k, v, ks, vs,
-                                         window=window)
+        out = kernel_ops.paged_attention(
+            q, pool["k"], pool["v"], tables, pos, k, v, layer,
+            pool.get("k_scale"), pool.get("v_scale"), window=window)
         return out, (k, v)
     return attend
 

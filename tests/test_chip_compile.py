@@ -7,6 +7,8 @@ memory) — which interpret mode cannot show.  The topology is described
 inside a fixture, never at import, so every test worker collects the
 same tests and only the one given this file loads the TPU library.
 """
+import dataclasses
+import math
 import re
 
 import jax
@@ -28,6 +30,7 @@ PAPER = get_config("paper-backbone")
 BLOCK = 16
 SLOTS = 8
 MARK = "tpu_custom_call"
+LAYERS = 4                  # attention layers in the kernel's whole pool
 
 # (heads, kv heads, head dim, blocks per slot, slots): paper-backbone at
 # its registered max_seq_len, one GQA geometry of a larger served model,
@@ -62,12 +65,12 @@ def _kernel_args(one_chip, geometry, kv):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     kv_dt = jnp.int8 if kv == "int8" else jnp.bfloat16
-    args = [s((slots, h, hd), jnp.bfloat16),
-            s((nb, BLOCK, kvh, hd), kv_dt), s((nb, BLOCK, kvh, hd), kv_dt),
+    pool = (nb, LAYERS, BLOCK, kvh, hd)
+    args = [s((slots, h, hd), jnp.bfloat16), s(pool, kv_dt), s(pool, kv_dt),
             s((slots, mb), jnp.int32), s((slots,), jnp.int32),
             s((slots, kvh, hd), jnp.bfloat16),
-            s((slots, kvh, hd), jnp.bfloat16)]
-    scales = ([s((nb, BLOCK), jnp.float32)] * 2 if kv == "int8"
+            s((slots, kvh, hd), jnp.bfloat16), s((), jnp.int32)]
+    scales = ([s((nb, LAYERS, BLOCK), jnp.float32)] * 2 if kv == "int8"
               else [None, None])
     return args + scales
 
@@ -75,8 +78,8 @@ def _kernel_args(one_chip, geometry, kv):
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("geometry", list(GEOMETRIES))
 def test_paged_decode_kernel_compiles(one_chip, geometry, kv):
-    def kernel(q, kb, vb, tb, pos, kn, vn, ks, vs):
-        return paged_decode_attention(q, kb, vb, tb, pos, kn, vn,
+    def kernel(q, kb, vb, tb, pos, kn, vn, layer, ks, vs):
+        return paged_decode_attention(q, kb, vb, tb, pos, kn, vn, layer,
                                       k_scale=ks, v_scale=vs)
 
     compiled = jax.jit(kernel).lower(
@@ -132,10 +135,8 @@ _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}"
 _SORT_OR_SCATTER = re.compile(r"=\s*(.*?)\s(?:sort|scatter)\(")
 
 
-def _wide_ops_by_place(text, widths):
-    """The ``sort`` and ``scatter`` instructions of compiled HLO ``text``
-    with a dimension in ``widths``, split into those inside a
-    conditional's branch computations (or what they call) and the rest."""
+def _computations(text):
+    """Each computation of compiled HLO ``text`` by name: its lines."""
     body, cur = {}, None
     for line in text.splitlines():
         m = _HEADER.match(line)
@@ -146,6 +147,14 @@ def _wide_ops_by_place(text, widths):
             cur = None
         elif cur is not None:
             body[cur].append(line)
+    return body
+
+
+def _wide_ops_by_place(text, widths):
+    """The ``sort`` and ``scatter`` instructions of compiled HLO ``text``
+    with a dimension in ``widths``, split into those inside a
+    conditional's branch computations (or what they call) and the rest."""
+    body = _computations(text)
     todo = [name for lines in body.values() for line in lines
             for group, one in _BRANCHES.findall(line)
             for name in _REF.findall(group) + ([one] if one else [])]
@@ -198,6 +207,81 @@ def test_paged_decode_sorts_vocab_only_in_a_branch(one_chip, paged_kernel):
     inside, outside = _wide_ops_by_place(text, {vocab, SLOTS * vocab})
     assert outside == []
     assert inside                          # the top-k branch's sort
+
+
+# paper-backbone with a lane-aligned head dim: 8 attention layers whose
+# pool the kernel reads block by block (a head dim off the 128 lanes is
+# read from a zero-padded copy of its layer's plane instead)
+PAPER_LANES = dataclasses.replace(PAPER, head_dim=128)
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*?)\s([\w\-]+)\(")
+_CALLS = re.compile(r"calls=%([\w.\-]+)")
+
+
+def _pool_sized_ops(text, num_blocks, row):
+    """The instructions of compiled HLO ``text`` outside any fusion whose
+    result holds an array of ``num_blocks`` rows of ``row`` elements or
+    more (one layer's K or V plane or more; an int8 pool's row scales
+    are smaller), less the program's parameters, what only passes an
+    array on (tuples, their elements, bitcasts, a loop's carry) and the
+    fusions that scatter into the pool in place."""
+    body = _computations(text)
+    fused = {name for lines in body.values() for line in lines
+             if " fusion(" in line for name in _CALLS.findall(line)}
+    passes_on = {"parameter", "get-tuple-element", "tuple", "bitcast",
+                 "while"}
+
+    def pool_sized(shape):
+        dims = [int(d) for d in shape.split(",")]
+        return dims[0] == num_blocks and math.prod(dims[1:]) >= row
+
+    found = []
+    for name, lines in body.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = _INSTR.match(line)
+            if not m or m.group(3) in passes_on or not any(
+                    pool_sized(shape)
+                    for shape in re.findall(r"\[([\d,]+)\]", m.group(2))):
+                continue
+            if m.group(3) == "fusion" and any(
+                    "scatter(" in callee_line
+                    for callee in _CALLS.findall(line)
+                    for callee_line in body.get(callee, ())):
+                continue
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("kv_dtype", ["auto", "int8"])
+def test_paged_decode_step_reads_pool_in_place(one_chip, kv_dtype):
+    """The paged decode program hands the kernel the whole block-major pool
+    and each layer's index: no instruction outside the kernel transposes
+    the pool layer-major or slices a layer's plane out of it.  Only the
+    in-place scatter of the step's new rows has the pool's block count."""
+    opts = DEFAULT_OPTIONS.replace(paged_kernel=True, kv_dtype=kv_dtype)
+    cfg, max_seq = PAPER_LANES, PAPER_LANES.max_seq_len
+    nb = SLOTS * (max_seq // BLOCK) + 1
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_paged_slot_cache(cfg, SLOTS, max_seq, opts)))
+    pool = on_chip(jax.eval_shape(
+        lambda: init_paged_pool(cfg, nb, BLOCK, opts)))
+    assert pool["k"].shape[:2] == (nb, cfg.num_layers)
+    tokens = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((SLOTS, max_seq // BLOCK), jnp.int32,
+                                  sharding=one_chip)
+    step, _ = ServePrograms(cfg, opts, max_seq).paged_decode(nb, BLOCK)
+    text = step.lower(params, cache, pool, tokens, tables).compile().as_text()
+    assert KERNEL_NAME in text
+    assert _pool_sized_ops(text, nb, BLOCK * cfg.kv_dim) == []
 
 
 # Granite-4.0-H-Small as the chip benchmark serves it: the last stage's 10

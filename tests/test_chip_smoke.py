@@ -77,7 +77,7 @@ def _drop_block(args, slot, j):
     t, p = np.asarray(tables).copy(), np.asarray(pos).copy()
     j %= t.shape[1]
     t[slot, j:-1] = t[slot, j + 1:]
-    p[slot] -= kb.shape[1]
+    p[slot] -= kb.shape[2]
     return (q, kb, vb, jnp.asarray(t), jnp.asarray(p), *rest)
 
 
@@ -98,10 +98,18 @@ def test_kernel_check_rejects_a_dropped_block(paper_cases, pool, drop):
 def test_kernel_check_rejects_shifted_int8_scales(paper_cases):
     """K's row scales of one block read one row off."""
     args, want = paper_cases["int8"]
-    q, kb, vb, tables, pos, kn, vn, ks, vs = args
+    q, kb, vb, tables, pos, kn, vn, layer, ks, vs = args
     blk = int(tables[-1, 50])
-    ks = jnp.asarray(ks).at[blk].set(jnp.roll(ks[blk], 1))
-    broken = smoke.oracle((q, kb, vb, tables, pos, kn, vn, ks, vs))
+    ks = jnp.asarray(ks).at[blk, layer].set(jnp.roll(ks[blk, layer], 1))
+    broken = smoke.oracle((q, kb, vb, tables, pos, kn, vn, layer, ks, vs))
+    assert smoke.slot_rel_err(broken, want) > smoke.KERNEL_RTOL
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_kernel_check_rejects_another_layer(paper_cases, pool):
+    """A kernel that read the pool's first layer in place of the last."""
+    args, want = paper_cases[pool]
+    broken = smoke.oracle(args[:7] + (0,) + args[8:])
     assert smoke.slot_rel_err(broken, want) > smoke.KERNEL_RTOL
 
 

@@ -281,14 +281,21 @@ def test_act_quant4_range_is_symmetric():
 
 
 # ------------------------------------------------------ paged decode attn --
-def _paged_case(seed, slots, H, kvh, hd, bs, mb, kv_dtype, pos_spec):
-    """Build one paged-decode problem; pos_spec picks the ragged lengths."""
+POOL_LAYERS = 3          # attention layers in a test pool, each its own KV
+
+
+def _paged_case(seed, slots, H, kvh, hd, bs, mb, kv_dtype, pos_spec,
+                layer=0):
+    """Build one paged-decode problem over a block-major pool of
+    ``POOL_LAYERS`` layers of different KV, read at ``layer``; pos_spec
+    picks the ragged lengths."""
     from repro.kernels.act_quant import kv_quant_rows
     rng = np.random.default_rng(seed)
     nb = mb * slots + 2
+    pool = (nb, POOL_LAYERS, bs, kvh, hd)
     q = jnp.asarray(rng.standard_normal((slots, H, hd)), jnp.float32)
-    kb = jnp.asarray(rng.standard_normal((nb, bs, kvh, hd)), jnp.float32)
-    vb = jnp.asarray(rng.standard_normal((nb, bs, kvh, hd)), jnp.float32)
+    kb = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    vb = jnp.asarray(rng.standard_normal(pool), jnp.float32)
     tables = jnp.asarray(rng.integers(0, nb, (slots, mb)), jnp.int32)
     kn = jnp.asarray(rng.standard_normal((slots, kvh, hd)), jnp.float32)
     vn = jnp.asarray(rng.standard_normal((slots, kvh, hd)), jnp.float32)
@@ -305,7 +312,21 @@ def _paged_case(seed, slots, H, kvh, hd, bs, mb, kv_dtype, pos_spec):
         kwargs = dict(k_scale=ks, v_scale=vs)
     elif kv_dtype == "bfloat16":
         kb, vb = kb.astype(jnp.bfloat16), vb.astype(jnp.bfloat16)
-    return (q, kb, vb, tables, pos, kn, vn), kwargs
+    return (q, kb, vb, tables, pos, kn, vn, layer), kwargs
+
+
+def _layer_alone(args, kw):
+    """The same problem over a one-layer pool holding only the plane
+    ``layer`` reads, read at 0: the oracle's answer that does not depend
+    on how a layer index is read."""
+    *head, layer = args
+    q, kb, vb, *tail = head
+
+    def plane(a):
+        return a[:, layer:layer + 1]
+
+    return ((q, plane(kb), plane(vb), *tail, 0),
+            {name: plane(a) for name, a in kw.items()})
 
 
 # (bs, mb): one grid step; then several steps of R blocks — R = 8
@@ -315,27 +336,39 @@ def _paged_case(seed, slots, H, kvh, hd, bs, mb, kv_dtype, pos_spec):
                                    (16, 14), (16, 11)])
 @pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
 @pytest.mark.parametrize("pos_spec", ["ragged", "zero", "full_tail"])
-def test_paged_decode_matches_ref(bs, mb, kv_dtype, pos_spec):
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_decode_matches_ref(bs, mb, kv_dtype, pos_spec, layer):
+    """The kernel reads layer ``layer`` out of the whole pool (a head dim
+    of 128 lanes, as every served model has): equal to the oracle over
+    that layer's plane alone, and to the oracle reading the same layer
+    index out of the whole pool."""
     from repro.kernels import paged_decode_attention
-    args, kw = _paged_case(bs * mb, slots=3, H=4, kvh=2, hd=16,
+    args, kw = _paged_case(bs * mb, slots=3, H=4, kvh=2, hd=128,
                            bs=bs, mb=mb, kv_dtype=kv_dtype,
-                           pos_spec=pos_spec)
-    o_k = paged_decode_attention(*args, interpret=True, **kw)
-    o_r = ref.paged_decode_attn_ref(*args, **kw)
-    np.testing.assert_allclose(np.asarray(o_k, np.float32),
-                               np.asarray(o_r, np.float32),
-                               atol=2e-5, rtol=1e-4)
+                           pos_spec=pos_spec, layer=layer)
+    o_k = np.asarray(paged_decode_attention(*args, interpret=True, **kw),
+                     np.float32)
+    for r_args, r_kw in ((args, kw), _layer_alone(args, kw)):
+        o_r = ref.paged_decode_attn_ref(*r_args, **r_kw)
+        np.testing.assert_allclose(o_k, np.asarray(o_r, np.float32),
+                                   atol=2e-5, rtol=1e-4)
 
 
 @pytest.mark.parametrize("window", [1, 3, 100])
-def test_paged_decode_window_matches_ref(window):
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("hd", [16, 128])
+def test_paged_decode_window_matches_ref(window, layer, hd):
+    """A sliding window, on the whole pool (128 lanes) and on a padded
+    copy of the layer's plane (16 lanes, off the lane tiling)."""
     from repro.kernels import paged_decode_attention
-    args, _ = _paged_case(17, slots=4, H=8, kvh=4, hd=16, bs=4, mb=4,
-                          kv_dtype="float32", pos_spec="ragged")
-    o_k = paged_decode_attention(*args, window=window, interpret=True)
-    o_r = ref.paged_decode_attn_ref(*args, window=window)
-    np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
-                               atol=2e-5, rtol=1e-4)
+    args, _ = _paged_case(17, slots=4, H=8, kvh=4, hd=hd, bs=4, mb=4,
+                          kv_dtype="float32", pos_spec="ragged", layer=layer)
+    o_k = np.asarray(paged_decode_attention(*args, window=window,
+                                            interpret=True))
+    for r_args, _ in ((args, {}), _layer_alone(args, {})):
+        o_r = ref.paged_decode_attn_ref(*r_args, window=window)
+        np.testing.assert_allclose(o_k, np.asarray(o_r), atol=2e-5,
+                                   rtol=1e-4)
 
 
 @pytest.mark.parametrize("mb,bs,per_step", [
@@ -348,9 +381,10 @@ def test_paged_decode_blocks_per_step(mb, bs, per_step):
     assert blocks_per_step(mb, bs) == per_step
 
 
-def _dead_blocks_nan_case(bs, mb, kv_dtype, window):
-    """One slot per boundary position, each over blocks of its own, with
-    the trash block and every whole block outside the slot's live rows
+def _dead_blocks_nan_case(bs, mb, kv_dtype, window, layer=1):
+    """One slot per boundary position, each over blocks of its own, read
+    at ``layer`` of a pool of ``POOL_LAYERS``, with every other layer, the
+    trash block and every whole block outside the slot's live rows
     ``[lo, pos)`` filled with NaN (K, V and int8 scales).  Returns the
     poisoned and the clean problem."""
     from repro.kernels.act_quant import kv_quant_rows
@@ -358,11 +392,12 @@ def _dead_blocks_nan_case(bs, mb, kv_dtype, window):
     rows = blocks_per_step(mb, bs) * bs
     pos = sorted({0, bs - 1, bs, bs + 1, rows - 1, rows, rows + 1,
                   mb * bs - 1} & set(range(mb * bs)))
-    slots, kvh, hd, group = len(pos), 2, 16, 2
+    slots, kvh, hd, group = len(pos), 2, 128, 2
     rng = np.random.default_rng(bs * mb + window)
     nb = slots * mb + 1                      # block 0 is the trash block
-    kb = rng.standard_normal((nb, bs, kvh, hd)).astype(np.float32)
-    vb = rng.standard_normal((nb, bs, kvh, hd)).astype(np.float32)
+    pool = (nb, POOL_LAYERS, bs, kvh, hd)
+    kb = rng.standard_normal(pool).astype(np.float32)
+    vb = rng.standard_normal(pool).astype(np.float32)
     tables = 1 + np.arange(slots * mb, dtype=np.int32).reshape(slots, mb)
     dead = [0]
     for s, p in enumerate(pos):
@@ -376,21 +411,26 @@ def _dead_blocks_nan_case(bs, mb, kv_dtype, window):
                         jnp.float32)]
     tail = [jnp.asarray(tables), jnp.asarray(pos, jnp.int32),
             jnp.asarray(rng.standard_normal((slots, kvh, hd)), jnp.float32),
-            jnp.asarray(rng.standard_normal((slots, kvh, hd)), jnp.float32)]
+            jnp.asarray(rng.standard_normal((slots, kvh, hd)), jnp.float32),
+            layer]
+    others = [j for j in range(POOL_LAYERS) if j != layer]
+
+    def poison(a):
+        a = a.copy()
+        a[dead, layer] = np.nan
+        a[:, others] = np.nan
+        return jnp.asarray(a)
+
     kw = {}
     if kv_dtype == "int8":
         kq, ks = map(np.asarray, kv_quant_rows(jnp.asarray(kb)))
         vq, vs = map(np.asarray, kv_quant_rows(jnp.asarray(vb)))
         clean = (args + [jnp.asarray(kq), jnp.asarray(vq)] + tail,
                  dict(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs)))
-        ks, vs = ks.copy(), vs.copy()
-        ks[dead] = vs[dead] = np.nan
-        kw = dict(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
+        kw = dict(k_scale=poison(ks), v_scale=poison(vs))
         return (clean[0], kw), clean
     clean = (args + [jnp.asarray(kb), jnp.asarray(vb)] + tail, kw)
-    kb, vb = kb.copy(), vb.copy()
-    kb[dead] = vb[dead] = np.nan
-    return (args + [jnp.asarray(kb), jnp.asarray(vb)] + tail, kw), clean
+    return (args + [poison(kb), poison(vb)] + tail, kw), clean
 
 
 @pytest.mark.parametrize("bs,mb", [(16, 16), (8, 48), (16, 14), (16, 11),
@@ -399,11 +439,11 @@ def _dead_blocks_nan_case(bs, mb, kv_dtype, window):
 @pytest.mark.parametrize("window", [0, 40])
 def test_paged_decode_never_reads_dead_blocks(bs, mb, kv_dtype, window):
     """A block that holds no live row is neither fetched into the sweep
-    nor masked in it: NaN there (and in the trash block) leaves every
-    output finite and equal to the oracle on the clean pool.  Positions
-    sit at 0, around a block boundary, around a step boundary of R * bs
-    rows and at the last row; with a window the leading blocks are dead
-    too."""
+    nor masked in it: NaN there (and in the trash block, and in every
+    layer of the pool but the one read) leaves every output finite and
+    equal to the oracle on the clean pool.  Positions sit at 0, around a
+    block boundary, around a step boundary of R * bs rows and at the last
+    row; with a window the leading blocks are dead too."""
     from repro.kernels import paged_decode_attention
     (args, kw), (clean, clean_kw) = _dead_blocks_nan_case(bs, mb, kv_dtype,
                                                           window)
@@ -422,7 +462,7 @@ def test_paged_decode_pos_zero_is_new_token_only():
     from repro.kernels import paged_decode_attention
     args, _ = _paged_case(23, slots=2, H=4, kvh=2, hd=16, bs=4, mb=3,
                           kv_dtype="float32", pos_spec="zero")
-    q, kb, vb, tables, pos, kn, vn = args
+    q, kb, vb, tables, pos, kn, vn, layer = args
     o = paged_decode_attention(*args, interpret=True)
     expect = jnp.repeat(vn, 2, axis=1)          # group=2 heads per kv head
     np.testing.assert_allclose(np.asarray(o), np.asarray(expect), atol=2e-6)
@@ -446,18 +486,18 @@ def test_paged_decode_matches_dense_decode():
     tables = jnp.asarray(
         [[1 + s * mb + j for j in range(mb)] for s in range(slots)],
         jnp.int32)
-    kb = jnp.zeros((nb, bs, kvh, hd), jnp.float32)
-    vb = jnp.zeros((nb, bs, kvh, hd), jnp.float32)
-    kb = kb.at[tables.reshape(-1)].set(
+    kb = jnp.zeros((nb, 1, bs, kvh, hd), jnp.float32)
+    vb = jnp.zeros((nb, 1, bs, kvh, hd), jnp.float32)
+    kb = kb.at[tables.reshape(-1), 0].set(
         kd.reshape(slots * mb, bs, kvh, hd))
-    vb = vb.at[tables.reshape(-1)].set(
+    vb = vb.at[tables.reshape(-1), 0].set(
         vd.reshape(slots * mb, bs, kvh, hd))
     # the dense path sees the new token *scattered at pos*; the kernel
     # folds the same rows in as k_new/v_new
     kn = jnp.stack([kd[s, pos[s]] for s in range(slots)])
     vn = jnp.stack([vd[s, pos[s]] for s in range(slots)])
     for w in (0, 3):
-        o_p = paged_decode_attention(q, kb, vb, tables, pos, kn, vn,
+        o_p = paged_decode_attention(q, kb, vb, tables, pos, kn, vn, 0,
                                      window=w, interpret=True)
         o_d = jnp.stack([
             decode_attention(q[s:s + 1], kd[s:s + 1], vd[s:s + 1],
@@ -474,11 +514,11 @@ def test_paged_decode_int8_error_bound():
     from repro.kernels.act_quant import kv_quant_rows
     args, _ = _paged_case(29, slots=4, H=8, kvh=2, hd=32, bs=8, mb=3,
                           kv_dtype="float32", pos_spec="ragged")
-    q, kb, vb, tables, pos, kn, vn = args
+    q, kb, vb, tables, pos, kn, vn, layer = args
     o_f32 = paged_decode_attention(*args, interpret=True)
     kq, ks = kv_quant_rows(kb)
     vq, vs = kv_quant_rows(vb)
-    o_i8 = paged_decode_attention(q, kq, vq, tables, pos, kn, vn,
+    o_i8 = paged_decode_attention(q, kq, vq, tables, pos, kn, vn, layer,
                                   k_scale=ks, v_scale=vs, interpret=True)
     assert float(jnp.max(jnp.abs(o_i8 - o_f32))) < 0.05
 
